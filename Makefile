@@ -221,10 +221,11 @@ bench-all:
 bench-attention:
 	$(PY) benchmarks/attention_bench.py
 
-# the driver's multi-chip contract check (self-provisions 8 CPU devices)
+# the CPU sim-mesh check (self-provisions 8 virtual CPU devices; the
+# chip evidence is chip_smoke.py)
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('DRYRUN OK')"
 
 # compile-check every module (no external linter in this environment)
 lint:
-	$(PY) -m compileall -q tensorframes_tpu benchmarks examples tests bench.py __graft_entry__.py
+	$(PY) -m compileall -q tensorframes_tpu benchmarks examples tests bench.py __graft_entry__.py chip_smoke.py

@@ -138,5 +138,7 @@ def run_all():
 
 
 if __name__ == "__main__":
+    from tensorframes_tpu.utils.profiling import device_stamp
+
     for row in run_all():
-        print(json.dumps(row))
+        print(json.dumps(device_stamp() | row))

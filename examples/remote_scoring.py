@@ -73,7 +73,7 @@ def main():
         scores = out.column("score").to_numpy()
         ranks = out.column("rank_in_partition").to_numpy()
         # rtol sized for the MXU's default bf16-pass f32 matmuls
-        # (~2e-3 rel vs the numpy f64 oracle — docs/perf.md)
+        # (~2e-3 rel vs the numpy f64 oracle)
         np.testing.assert_allclose(scores, parts[i] @ w, rtol=5e-3, atol=1e-3)
         # the rank column proves the whole partition formed one block
         assert sorted(ranks) == list(range(rows_per_part))

@@ -170,7 +170,7 @@ class ScoringServer:
     concurrent connections are served by a bounded thread pool, and the
     engine's program caches are shared across them, so every partition
     after the first reuses the compiled XLA program. ``precompile`` +
-    the persistent compile cache (docs/perf.md "Cold start") make the
+    the persistent compile cache (``tft.enable_compilation_cache``) make the
     first one cheap too."""
 
     def __init__(
@@ -314,6 +314,12 @@ class ScoringServer:
             self._engine_started_here = False
         if self._sock is not None:
             try:
+                # close() alone leaves a thread blocked in accept()
+                # asleep on Linux; shutdown() wakes it with an OSError
+                try:
+                    self._sock.shutdown(socket.SHUT_RDWR)
+                except OSError:  # never connected / already down
+                    pass
                 self._sock.close()
             finally:
                 self._sock = None
@@ -1344,7 +1350,7 @@ class ScoringServer:
             # may not use it: 429, not the all-full 503. Retry-After is
             # the refusing token bucket's refill time, clamped to the
             # same [1, 30] window the adaptive 503 hint uses — UNLESS
-            # the refusal was relayed from a member, in which case the
+            # the refusal was passed on from a member, in which case the
             # member's own Retry-After header rides the exception
             # (retry_after_hint) and is echoed verbatim: the member
             # knows its bucket, the router's would be a guess.
@@ -1368,7 +1374,7 @@ class ScoringServer:
             # help right now — answer fast instead of parking the
             # connection against a full queue or a dead engine. The
             # Retry-After adapts to the backlog (depth x p50 ITL), or is
-            # the member's verbatim hint when the refusal was relayed.
+            # the member's verbatim hint when the refusal came from one.
             if wal_entry is not None:
                 wal.forget(rid_box["rid"], e)
             hint = getattr(e, "retry_after_hint", None)

@@ -300,7 +300,7 @@ class DriftMonitor:
 
 
 def h2d_p50(**kw) -> Detector:
-    """Host→device transfer p50 — a shifted link (new tunnel, congested
+    """Host→device transfer p50 — a shifted link (another host, a congested
     fabric) invalidates the transfer chunk/stream tuning."""
     return Detector(
         name="h2d_p50", series="frame.h2d_seconds.p50", **kw,

@@ -84,8 +84,9 @@ _g_identity = _gauge(
 
 _lock = threading.Lock()
 _role = "driver"
-_identity_pid: Optional[int] = None  # pid the identity gauge was set for
-_device_kind: Optional[str] = None
+#: label set the identity gauge currently publishes at 1.0 (None before
+#: the first set_identity)
+_published: Optional[Dict[str, str]] = None
 _last_export = 0.0  # monotonic, throttles autoexport
 
 
@@ -108,42 +109,37 @@ def _package_version() -> str:
         return "unknown"
 
 
-def _detect_device_kind() -> str:
-    """Device kind of the default backend, cached; ``"unknown"`` when
-    jax has no initialized/initializable backend (a bare exporter
-    process must not be forced through backend init just to label
-    itself)."""
-    global _device_kind
-    if _device_kind is None:
-        try:
-            import jax
+def _device_kind() -> str:
+    """Device kind of the default backend when this process has
+    initialized one, else ``"unknown"`` — a process that holds no engine
+    (a router, a score-only driver) must not initialize a backend, and
+    with it take the chip, just to label itself. A serve replica's
+    engine has placed its weights before the server starts, so its
+    label is the real kind."""
+    from .programs import initialized_device
 
-            _device_kind = str(jax.devices()[0].device_kind)
-        except Exception:
-            _device_kind = "unknown"
-    return _device_kind
+    dev = initialized_device()
+    return "unknown" if dev is None else str(dev.device_kind)
 
 
 def set_identity(role: str) -> Dict[str, Any]:
     """Declare this process's role and (re)publish the identity gauge.
 
-    Idempotent; a role CHANGE zeroes the former role's series first (the
-    gauge has no per-series removal, and two role series at 1.0 would
+    Idempotent; when any label changed (the role, or the device kind
+    once a backend came up) the former series is zeroed first (the
+    gauge has no per-series removal, and two series at 1.0 would
     double-count the process in any fleet sum)."""
-    global _role, _identity_pid
+    global _role, _published
     with _lock:
-        old = _role
         _role = str(role)
-        if old != _role and _identity_pid is not None:
-            _g_identity.set(
-                0.0, proc=proc_id(), pid=str(_identity_pid), role=old,
-                version=_package_version(), device=_detect_device_kind(),
-            )
-        _identity_pid = os.getpid()
-        _g_identity.set(
-            1.0, proc=proc_id(), pid=str(_identity_pid), role=_role,
-            version=_package_version(), device=_detect_device_kind(),
+        labels = dict(
+            proc=proc_id(), pid=str(os.getpid()), role=_role,
+            version=_package_version(), device=_device_kind(),
         )
+        if _published is not None and _published != labels:
+            _g_identity.set(0.0, **_published)
+        _g_identity.set(1.0, **labels)
+        _published = labels
     return identity()
 
 
@@ -155,7 +151,7 @@ def identity() -> Dict[str, Any]:
         "pid": os.getpid(),
         "role": _role,
         "version": _package_version(),
-        "device": _detect_device_kind(),
+        "device": _device_kind(),
         "host": socket.gethostname(),
     }
 

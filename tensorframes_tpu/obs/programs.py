@@ -26,7 +26,8 @@ thrown away. This registry keeps them:
   dispatched time, arithmetic intensity (FLOPs/byte), and utilization
   against the device's peak (:func:`peak_flops` — known TPU
   generations, or the ``TFT_PEAK_FLOPS`` / ``TFT_PEAK_BYTES_PER_S``
-  overrides; ``None`` on hosts with no table entry, e.g. CPU). It is
+  overrides; ``None`` off TPU, an error for a TPU with no table
+  entry). It is
   what ``GET /statusz`` serves and ``explain(analyze=True)`` renders;
 - :func:`persist` appends the records as JSONL next to the batch-job
   journal root (``<job root>/programs.jsonl``, or
@@ -53,6 +54,7 @@ __all__ = [
     "autopersist",
     "costs_path",
     "estimate_costs",
+    "initialized_device",
     "instrument",
     "jaxpr_costs",
     "peak_bytes_per_s",
@@ -248,6 +250,16 @@ def _fmt_num(v: Optional[float]) -> str:
 # ---------------------------------------------------------------------------
 
 
+_estimate_warned = False
+
+
+def _warn_estimate_once(what: str) -> None:
+    global _estimate_warned
+    if not _estimate_warned:
+        _estimate_warned = True
+        logger.warning("%s (logged once)", what, exc_info=True)
+
+
 def estimate_costs(
     fn, *args, **kwargs
 ) -> Tuple[Optional[float], Optional[float], Optional[str]]:
@@ -255,30 +267,34 @@ def estimate_costs(
 
     Tries XLA's analysis off the jit's ``lower()`` artifact first (no
     compile — lowering only), then falls back to walking the jaxpr
-    (:func:`jaxpr_costs`). ``(None, None, None)`` when both fail — cost
-    accounting must never break a dispatch."""
+    (:func:`jaxpr_costs`). Both read only the arguments' shapes and
+    dtypes, so they work on arguments the call being described has
+    already donated. ``(None, None, None)`` when both fail — cost
+    accounting must never break a dispatch; the first failure is
+    logged, because a registry that silently holds no costs reads as a
+    program that costs nothing."""
+    ca = {}
     try:
-        lowered = fn.lower(*args, **kwargs)
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # per-device list on older APIs
-            ca = ca[0] if ca else {}
-        flops = ca.get("flops")
-        nbytes = ca.get("bytes accessed")
-        if flops is not None or nbytes is not None:
-            return (
-                float(flops) if flops is not None else None,
-                float(nbytes) if nbytes is not None else None,
-                "xla",
-            )
-    except Exception:
-        pass
+        ca = fn.lower(*args, **kwargs).cost_analysis() or {}
+    except Exception:  # a backend may offer no analysis for a program
+        _warn_estimate_once("XLA cost analysis failed; walking the jaxpr")
+    flops = ca.get("flops")
+    nbytes = ca.get("bytes accessed")
+    if flops is not None or nbytes is not None:
+        return (
+            float(flops) if flops is not None else None,
+            float(nbytes) if nbytes is not None else None,
+            "xla",
+        )
     try:
         import jax
 
-        closed = jax.make_jaxpr(fn)(*args, **kwargs)
-        flops, nbytes = jaxpr_costs(closed)
+        flops, nbytes = jaxpr_costs(jax.make_jaxpr(fn)(*args, **kwargs))
         return flops, nbytes, "jaxpr"
-    except Exception:
+    except Exception:  # the walk meets whatever primitives fn holds
+        _warn_estimate_once(
+            "jaxpr cost walk failed; the record keeps no FLOP/byte costs"
+        )
         return None, None, None
 
 
@@ -379,61 +395,68 @@ def jaxpr_costs(closed_jaxpr) -> Tuple[float, float]:
 # device peaks (roofline denominators)
 # ---------------------------------------------------------------------------
 
-#: per-chip dense matmul peaks (bf16, FLOP/s) by device-kind prefix —
-#: the roofline denominator when no TFT_PEAK_FLOPS override is set.
-#: Hosts not listed (CPU, unknown TPUs) report utilization = n/a.
-_TPU_PEAK_FLOPS = (
-    ("TPU v6", 918e12),
-    ("TPU v5p", 459e12),
-    ("TPU v5", 197e12),  # v5e / "TPU v5 lite"
-    ("TPU v4", 275e12),
-    ("TPU v3", 123e12),
-    ("TPU v2", 45e12),
-)
-_TPU_PEAK_BYTES = (
-    ("TPU v6", 1640e9),
-    ("TPU v5p", 2765e9),
-    ("TPU v5", 819e9),
-    ("TPU v4", 1228e9),
-    ("TPU v3", 900e9),
-    ("TPU v2", 700e9),
-)
+#: per-chip peaks by the EXACT ``device_kind`` string jax reports:
+#: (dense bf16 matmul FLOP/s, HBM bytes/s) — the roofline denominators
+#: when no TFT_PEAK_FLOPS / TFT_PEAK_BYTES_PER_S override is set. Exact
+#: keys, because kinds share prefixes ("TPU v5" is the v5p, "TPU v5 lite"
+#: the v5e) and a neighbour's peak is worse than none.
+_TPU_PEAKS = {
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v2": (45e12, 700e9),
+}
 
 
-def _device_kind() -> str:
-    try:
-        import jax
+def initialized_device():
+    """Device 0 of the default backend, or ``None`` while this process
+    has not initialized one. Never initializes: a chip belongs to one
+    process, and one that only routes, scrapes or labels itself must
+    not take it from the process that computes."""
+    from jax._src import xla_bridge
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return ""
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+
+    return jax.devices()[0]
 
 
-def _peak(env: str, tbl) -> Optional[float]:
+def _peak(env: str, column: int) -> Optional[float]:
     override = os.environ.get(env, "")
     if override:
         try:
             return float(override)
         except ValueError:
             logger.warning("malformed %s=%r ignored", env, override)
-    kind = _device_kind()
-    for prefix, v in tbl:
-        if kind.startswith(prefix):
-            return v
-    return None
+    dev = initialized_device()
+    if dev is None or dev.platform != "tpu":
+        return None
+    try:
+        return _TPU_PEAKS[dev.device_kind][column]
+    except KeyError:
+        raise LookupError(
+            f"no peak on record for TPU device_kind {dev.device_kind!r}: "
+            f"add it to obs.programs._TPU_PEAKS or set {env}"
+        ) from None
 
 
 def peak_flops() -> Optional[float]:
     """This host's peak FLOP/s for roofline utilization:
-    ``TFT_PEAK_FLOPS`` override, else the known-TPU table, else ``None``
-    (utilization renders as n/a — honest on CPU hosts)."""
-    return _peak("TFT_PEAK_FLOPS", _TPU_PEAK_FLOPS)
+    ``TFT_PEAK_FLOPS`` override, else the known-TPU table. ``None`` off
+    TPU (utilization renders as n/a — honest on CPU hosts) and before
+    this process has initialized a backend; a TPU whose ``device_kind``
+    is not in the table raises ``LookupError``."""
+    return _peak("TFT_PEAK_FLOPS", 0)
 
 
 def peak_bytes_per_s() -> Optional[float]:
     """Peak memory bandwidth (``TFT_PEAK_BYTES_PER_S`` override, else
-    the known-TPU HBM table, else ``None``)."""
-    return _peak("TFT_PEAK_BYTES_PER_S", _TPU_PEAK_BYTES)
+    the known-TPU HBM table); ``None`` / ``LookupError`` as for
+    :func:`peak_flops`."""
+    return _peak("TFT_PEAK_BYTES_PER_S", 1)
 
 
 # ---------------------------------------------------------------------------

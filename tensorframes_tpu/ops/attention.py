@@ -64,6 +64,24 @@ def _mxu_dtype(dt):
     return dt if dt in (jnp.bfloat16, jnp.float16) else jnp.float32
 
 
+def _mxu_dot(a, b, contract, dt):
+    """One kernel matmul: operands cast to the MXU input dtype ``dt``
+    (:func:`_mxu_dtype`), contracting ``a``'s axis ``contract[0]`` with
+    ``b``'s ``contract[1]``, f32 accumulation. Low-precision operands pin
+    ``Precision.DEFAULT``: one pass already multiplies them exactly, and
+    Mosaic rejects the fp32 contract precision that an ambient
+    ``jax.default_matmul_precision("float32")`` would otherwise request
+    for bf16 operands ("Bad lhs type"). f32 operands follow the ambient
+    precision, so that context still buys true-f32 kernel math."""
+    return jax.lax.dot_general(
+        a.astype(dt),
+        b.astype(dt),
+        dimension_numbers=(((contract[0],), (contract[1],)), ((), ())),
+        precision=None if dt == jnp.float32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def online_block_update(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -93,12 +111,7 @@ def online_block_update(
     accumulation — and the whole softmax state — stays f32. f32 inputs
     compute exactly as before."""
     mxu_dt = _mxu_dtype(q.dtype)
-    s = jax.lax.dot_general(
-        q.astype(mxu_dt),
-        k.astype(mxu_dt),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * (scale * _LOG2E)
+    s = _mxu_dot(q, k, (1, 1), mxu_dt) * (scale * _LOG2E)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_BIG)
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -110,12 +123,7 @@ def online_block_update(
     alpha = jnp.exp2(m - m_new)
     l_new = alpha * l + p.sum(axis=-1, keepdims=True)
     pv_dt = _mxu_dtype(v.dtype)
-    acc_new = alpha * acc + jax.lax.dot_general(
-        p.astype(pv_dt),
-        v.astype(pv_dt),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_new = alpha * acc + _mxu_dot(p, v, (1, 0), pv_dt)
     return m_new, l_new, acc_new
 
 
@@ -138,8 +146,10 @@ def _lse_sentinel(m: jnp.ndarray, l: jnp.ndarray) -> jnp.ndarray:
 
 
 #: measured-best (block_q, block_k) per (dtype kind, head_dim bucket,
-#: min seq len) on v5e, chain-differential timed (benchmarks/
-#: attention_bench.py methodology; sweep recorded in BENCH_ALL_r04.json).
+#: min seq len) on v5e in the r04/r05 sweeps (records removed from the
+#: tree in PR 21; `git show 742ccc6:BENCH_ALL_r04.json`). Not re-timed on
+#: the current machine; every entry compiles under libtpu 0.0.34's 16 MB
+#: scoped VMEM at default matmul precision (chip_smoke.py runs them).
 #: 1024x1024 won every measured combo — bigger tiles (2048+) exceed VMEM
 #: and fail to compile, 512-wide tiles lose 3-10% to per-tile overhead:
 #:   bf16 D=128: L=8k 118.8 TF/s, L=16k 129.3, L=32k 127.5 (vs 100-117
@@ -434,14 +444,19 @@ def _ragged_paged_kernel(
     ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, page_size, scale,
 ):
-    """Grid = (slots, n_kv_heads, max_pages); the page axis is innermost
-    and sequential, so the VMEM scratch carries the online-softmax state
+    """Grid = (slots, max_pages); the page axis is innermost and
+    sequential, so the VMEM scratch carries the online-softmax state
     (``online_block_update`` — the same recurrence the flash kernel and
     the ring step fold with) across a slot's pages. One grid step streams
-    ONE page's [page_size, hd] k/v tiles through the carry: the page
-    table is a scalar-prefetch input, so the BlockSpec index maps chase
-    the indirection and only this slot's OWN pages cross HBM->VMEM — no
-    [slots, max_pages * page_size] gather is ever materialized.
+    ONE page — all KV heads of it, a ``[page_size, n_kv, hd]`` tile —
+    through the carry: the page table is a scalar-prefetch input, so the
+    BlockSpec index maps chase the indirection and only this slot's OWN
+    pages cross HBM->VMEM — no [slots, max_pages * page_size] gather is
+    ever materialized. The KV heads are a static loop INSIDE the step:
+    the pool keeps ``[.., n_kv, hd]`` as its trailing dims, and Mosaic
+    only accepts a block whose last two dims are (8, 128)-aligned or the
+    array's own, so a one-head block is not expressible — each head's
+    ``[page_size, hd]`` tile is a strided read of the resident page.
 
     Pages at or past ``lengths[s]`` are skipped entirely (``pl.when``),
     so a 1-token sequence in a ragged batch does one page of work while
@@ -453,35 +468,36 @@ def _ragged_paged_kernel(
     from jax.experimental import pallas as pl
 
     si = pl.program_id(0)
-    pi = pl.program_id(2)
-    npg = pl.num_programs(2)
+    pi = pl.program_id(1)
+    npg = pl.num_programs(1)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_BIG)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = lens_ref[si]
     base = pi * page_size
-    group = q_ref.shape[2]
+    n_kv, group = q_ref.shape[1], q_ref.shape[2]
 
     def update(with_mask):
-        q = q_ref[0, 0]        # [group, hd]
-        kj = k_ref[0, :, 0, :]  # [page_size, hd]
-        vj = v_ref[0, :, 0, :]
         mask = None
         if with_mask:
             pos = base + jax.lax.broadcasted_iota(
                 jnp.int32, (group, page_size), 1
             )
             mask = pos < length
-        m, l, acc = online_block_update(
-            q, kj, vj, m_scr[:], l_scr[:], acc_scr[:], scale, mask
-        )
-        m_scr[:] = m
-        l_scr[:] = l
-        acc_scr[:] = acc
+        for h in range(n_kv):
+            m, l, acc = online_block_update(
+                q_ref[0, h],         # [group, hd]
+                k_ref[0, :, h, :],   # [page_size, hd]
+                v_ref[0, :, h, :],
+                m_scr[h], l_scr[h], acc_scr[h], scale, mask,
+            )
+            m_scr[h] = m
+            l_scr[h] = l
+            acc_scr[h] = acc
 
     # three regimes per page, mirroring the flash kernel's causal tiles:
     # fully past the sequence (skip — the ragged win), fully visible
@@ -499,7 +515,7 @@ def _ragged_paged_kernel(
 
     @pl.when(pi == npg - 1)
     def _emit():
-        o_ref[0, 0] = _finalize(l_scr[:], acc_scr[:]).astype(o_ref.dtype)
+        o_ref[0] = _finalize(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(
@@ -543,37 +559,30 @@ def ragged_paged_attention(
     # index maps receive the scalar-prefetch refs after the grid indices:
     # the k/v maps dereference the page table, so the pipeline fetches
     # exactly the pages the table names, in table (= position) order
+    q_spec = pl.BlockSpec(
+        (1, n_kv, group, hd), lambda s, p, ptab, lens: (s, 0, 0, 0)
+    )
+    kv_spec = pl.BlockSpec(
+        (1, ps, n_kv, hd), lambda s, p, ptab, lens: (ptab[s, p], 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, n_kv, mp),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, group, hd),
-                lambda s, h, p, ptab, lens: (s, h, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, ps, 1, hd),
-                lambda s, h, p, ptab, lens: (ptab[s, p], 0, h, 0),
-            ),
-            pl.BlockSpec(
-                (1, ps, 1, hd),
-                lambda s, h, p, ptab, lens: (ptab[s, p], 0, h, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, hd), lambda s, h, p, ptab, lens: (s, h, 0, 0)
-        ),
+        grid=(slots, mp),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, hd), jnp.float32),
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),
+            pltpu.VMEM((n_kv, group, 1), jnp.float32),
+            pltpu.VMEM((n_kv, group, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, n_kv, group, hd), q.dtype),
-        compiler_params=_dim_semantics(pltpu, interpret),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(page_table, lengths, q, k_pages, v_pages)
 
@@ -687,9 +696,9 @@ def _flash_forward(q, k, v, causal, block_q, block_k, interpret):
     # measured — at its VMEM-safe tiles (two f32 score tiles cap it at
     # bq*bk <= 512k) it reached 24.1% MFU, LOSING to the single-head
     # kernel at full 1024x1024 tiles (28.7%); tile area beats head
-    # packing, so the variant was removed (flash_sweep4_r05.json). The
-    # D=64 ceiling itself is hardware: the bare matmul pair measures
-    # 59.2% of peak (flash_sweep_r05.json attention_matmul_ceiling).
+    # packing, so the variant was removed. The D=64 ceiling itself is
+    # hardware: the bare matmul pair measured 59.2% of peak (r05 sweeps,
+    # `git show 742ccc6:flash_sweep_r05.json`).
     kernel = functools.partial(
         _flash_kernel,
         block_q=block_q,
@@ -867,21 +876,11 @@ def _bwd_tile_terms(q, kj, vj, do, lse, dlt, scale, mask):
     mode (bf16 tiles run the backward at the chip's high rate, like the
     forward)."""
     mxu_dt = _mxu_dtype(q.dtype)
-    s = jax.lax.dot_general(
-        q.astype(mxu_dt),
-        kj.astype(mxu_dt),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * (scale * _LOG2E)
+    s = _mxu_dot(q, kj, (1, 1), mxu_dt) * (scale * _LOG2E)
     if mask is not None:
         s = jnp.where(mask, s, _NEG_BIG)
     p = jnp.exp2(s - lse)  # masked / empty-row entries underflow to 0
-    dp = jax.lax.dot_general(
-        do.astype(mxu_dt),
-        vj.astype(mxu_dt),
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dp = _mxu_dot(do, vj, (1, 1), mxu_dt)
     ds = p * (dp - dlt) * scale
     return p, ds, mxu_dt
 
@@ -950,12 +949,7 @@ def _flash_bwd_dq_kernel(
         _, ds, mxu_dt = _bwd_tile_terms(
             qi, kj, v_ref[0], doi, lse_ref[0], delta_ref[0], scale, mask
         )
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(mxu_dt),
-            kj.astype(mxu_dt),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dq_scr[:] += _mxu_dot(ds, kj, (1, 0), mxu_dt)
 
     if causal:
         visible, interior = _causal_tile_regimes(
@@ -992,8 +986,8 @@ def _flash_bwd_dkv_kernel(
     axis. The direct formulation needed two axis-0 contractions
     (``P^T dO``, ``dS^T Q``) whose operand relayouts held this kernel at
     73% of the matmul ceiling while the dq kernel (all-natural
-    contractions) ran at 93% (r05 per-kernel sweep,
-    flash_sweep2_r05.json). The [bq, 1] lse/delta rows transpose to
+    contractions) ran at 93% (r05 per-kernel sweep, `git show
+    742ccc6:flash_sweep2_r05.json`). The [bq, 1] lse/delta rows transpose to
     [1, bq] lane vectors once per tile — trivial next to the matmuls."""
     from jax.experimental import pallas as pl
 
@@ -1012,12 +1006,7 @@ def _flash_bwd_dkv_kernel(
         vj = v_ref[0]
         doi = do_ref[0]
         mxu_dt = _mxu_dtype(qi.dtype)
-        st = jax.lax.dot_general(
-            kj.astype(mxu_dt),
-            qi.astype(mxu_dt),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * (scale * _LOG2E)
+        st = _mxu_dot(kj, qi, (1, 1), mxu_dt) * (scale * _LOG2E)
         if with_mask:
             st = jnp.where(
                 _frontier_mask_t(iq, jk, block_q, block_k, offset),
@@ -1026,25 +1015,10 @@ def _flash_bwd_dkv_kernel(
             )
         lse_row = lse_ref[0].reshape(1, block_q)
         pt = jnp.exp2(st - lse_row)  # [bk, bq]; masked rows underflow to 0
-        dpt = jax.lax.dot_general(
-            vj.astype(mxu_dt),
-            doi.astype(mxu_dt),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dpt = _mxu_dot(vj, doi, (1, 1), mxu_dt)
         dst = pt * (dpt - delta_ref[0].reshape(1, block_q)) * scale
-        dv_scr[:] += jax.lax.dot_general(
-            pt.astype(mxu_dt),
-            doi.astype(mxu_dt),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_scr[:] += jax.lax.dot_general(
-            dst.astype(mxu_dt),
-            qi.astype(mxu_dt),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_scr[:] += _mxu_dot(pt, doi, (1, 0), mxu_dt)
+        dk_scr[:] += _mxu_dot(dst, qi, (1, 0), mxu_dt)
 
     if causal:
         visible, interior = _causal_tile_regimes(
@@ -1069,7 +1043,7 @@ def _flash_bwd_dkv_kernel(
 
 
 #: measured-best backward tiles per (dtype kind, head_dim bucket) — the
-#: r05 per-kernel sweep (benchmarks/flash_sweep2_r05.py): the dq kernel
+#: r05 per-kernel sweep (not re-timed on the current machine): the dq kernel
 #: (3 matmuls/tile, k innermost) and the dk/dv kernel (4 matmuls/tile, q
 #: innermost) run different matmul mixes and need not share the
 #: forward's optimum. Keys as in ``_BEST_BLOCKS``; values are
@@ -1078,9 +1052,13 @@ _BEST_BLOCKS_BWD = {
     # dq (3 natural matmuls, k innermost) peaks at square 1024 tiles
     # (178 TF/s real rate = 93% of ceiling); the transposed-score dkv
     # kernel prefers narrow-q/wide-k (154 TF/s at 512x2048 vs 146 at
-    # square). flash_sweep2/3_r05.json. f32 inputs DOUBLE every score
-    # intermediate: dkv at 512x2048 f32 needs 26.5 MB of scoped VMEM
-    # (measured compile failure) — the f32 rows keep square tiles.
+    # square). f32 inputs DOUBLE every score intermediate: dkv at
+    # 512x2048 f32 needs 26.5 MB of scoped VMEM (measured compile
+    # failure) — the f32 rows keep square tiles. Under an ambient
+    # jax.default_matmul_precision("float32") the f32 rows no longer fit
+    # (libtpu 0.0.34: dq at 1024x1024 and dkv at 512x1024 overrun the
+    # 16 MB limit, dkv by 1.53 MB); callers that want true-f32 kernel
+    # math pass block_q=block_k=512, which binds the backward too.
     (True, 128): ((1024, 1024), (512, 2048)),
     (True, 64): ((1024, 1024), (512, 2048)),
     (False, 128): ((1024, 1024), (512, 1024)),

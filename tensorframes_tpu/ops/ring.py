@@ -50,7 +50,6 @@ from .attention import (
 )
 from .seq_common import (
     SEQ_AXIS,
-    axis_size as _axis_size,
     check_divisible,
     pcast_varying,
     resolve_sp_mesh,
@@ -71,7 +70,7 @@ def _ring_setup(q, k, axis_name, batch_axis, block_q, block_k):
     """Shared fwd/bwd prologue: ring geometry, fitted tiles, rotation
     permutation, and the variance-marking helper — one source of truth so
     the two loops cannot drift apart."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, lq, d = q.shape
     lc = k.shape[2]
@@ -320,11 +319,9 @@ def _ring_program(
     jit cache instead of retracing."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map as _shard_map
-
     spec = P(batch_axis, None, axis_name, None)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             functools.partial(
                 ring_attention_sharded,
                 causal=causal,

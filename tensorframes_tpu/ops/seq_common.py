@@ -4,7 +4,6 @@ from __future__ import annotations
 
 __all__ = [
     "SEQ_AXIS",
-    "axis_size",
     "resolve_sp_mesh",
     "check_divisible",
     "pcast_varying",
@@ -23,16 +22,6 @@ def resolve_sp_mesh(mesh, axis_name: str):
 
         mesh = make_mesh({axis_name: len(jax.devices())})
     return mesh
-
-
-def axis_size(axis_name: str) -> int:
-    """Named-axis size from inside a shard_map body — the ops-side door
-    to ``parallel.compat.axis_size`` (lazy import: ops loads before the
-    parallel package in some import orders), shared by the ring and
-    ulysses bodies so a jax API drift is fixed in one place."""
-    from ..parallel.compat import axis_size as _axis_size
-
-    return _axis_size(axis_name)
 
 
 def pcast_varying(t, axis_name: str):

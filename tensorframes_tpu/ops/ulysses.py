@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from .attention import flash_attention
 from .seq_common import (
     SEQ_AXIS,
-    axis_size as _axis_size,
     check_divisible,
     resolve_sp_mesh,
 )
@@ -47,7 +46,7 @@ def ulysses_attention_sharded(
     """Per-shard body: call inside ``shard_map`` with q/k/v sequence chunks
     ``[B, H, L/n, D]`` sharded over ``axis_name``; returns the local output
     chunk. Heads must divide by the axis size."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[1]
     if h % n:
         raise ValueError(
@@ -78,15 +77,13 @@ def ulysses_attention_sharded(
 def _ulysses_program(mesh, causal: bool, axis_name: str, batch_axis=None):
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map as _shard_map
-
     # interpret must follow the MESH's devices, not the default backend:
     # the multichip dryrun runs this over virtual CPU devices on a box
     # whose default platform is a TPU
     interpret = mesh.devices.flat[0].platform != "tpu"
     spec = P(batch_axis, None, axis_name, None)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             functools.partial(
                 ulysses_attention_sharded,
                 causal=causal,

@@ -4,7 +4,6 @@ Replaces the reference's Spark plane (partitions/broadcast/shuffle/driver
 funnel, SURVEY §2.5) with ``shard_map`` programs and XLA collectives.
 """
 
-from .compat import has_shard_map, shard_map
 from .mesh import make_mesh, default_mesh, data_axis
 from .distributed import map_blocks, map_rows, reduce_blocks, reduce_rows, aggregate
 from .training import ShardedSGDTrainer
@@ -19,8 +18,6 @@ from .pipeline import pipeline_apply, pipeline_reference
 from . import multihost
 
 __all__ = [
-    "has_shard_map",
-    "shard_map",
     "multihost",
     "init_moe",
     "moe_apply",

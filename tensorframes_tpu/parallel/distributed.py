@@ -65,7 +65,7 @@ from ..engine.validation import (
 from ..frame import GroupedFrame, TensorFrame
 from ..schema import FrameInfo, Shape, Unknown
 from ..utils import get_config, get_logger
-from .compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from .mesh import DATA_AXIS, default_mesh
 
 __all__ = ["map_blocks", "map_rows", "reduce_blocks", "reduce_rows", "aggregate"]

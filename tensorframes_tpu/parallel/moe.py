@@ -23,8 +23,8 @@ import functools
 from typing import Dict
 
 import numpy as np
-
-from .compat import axis_size as _axis_size, shard_map as _shard_map
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as _axis_size
 
 __all__ = [
     "init_moe",

@@ -22,7 +22,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from .compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 __all__ = [
     "initialize",
@@ -574,7 +574,7 @@ def _allgather_partials(partials_df):
     reference's partial-aggregation shuffle (``DebugRowOps.scala:547-592``).
     """
     from ..frame import TensorFrame
-    from .compat import process_allgather_stacked as ag
+    from jax.experimental.multihost_utils import process_allgather as ag
 
     nproc = process_count()
     local_n = partials_df.num_rows

@@ -19,7 +19,8 @@ from __future__ import annotations
 import functools
 from typing import Any, Callable
 
-from .compat import axis_size as _axis_size, shard_map as _shard_map
+from jax import shard_map as _shard_map
+from jax.lax import axis_size as _axis_size
 
 __all__ = ["pipeline_apply", "pipeline_reference", "pipeline_train_step"]
 

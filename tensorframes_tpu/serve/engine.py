@@ -390,7 +390,11 @@ class GenerationEngine:
       ``num_pages × N`` total — aggregate KV capacity scales with the
       mesh). Requires ``n_heads``/``n_kv_heads``/``d_ff`` divisible by
       the mesh size; dense (non-MoE) blocks only
-      (docs/serving_llm.md "Tensor parallelism").
+      (docs/serving_llm.md "Tensor parallelism"). A ONE-device mesh
+      shards nothing: it pins an ordinary solo replica to that chip
+      (weights, pool and programs), which is how a
+      :class:`~tensorframes_tpu.serve.Fleet` spreads its replicas over
+      the chips of a host.
 
     A third compiled program (the ``[1, chunk]`` prefill-chunk step)
     exists only when chunked prefill or the prefix cache dispatches it:
@@ -450,6 +454,15 @@ class GenerationEngine:
                     1, int(self._tuned_geometry.get("slots", 8))
                 )
         self.max_slots = int(max_slots)
+        #: the chip a solo replica is pinned to: weights, KV pool and
+        #: step programs live there. None = jax's default device. A
+        #: one-device mesh pins; there is nothing to shard, so such a
+        #: replica runs the plain programs, not the shard_map ones —
+        #: this is how a Fleet puts replica i on chip i mod n.
+        self._device = None
+        if mesh is not None and mesh.devices.size == 1:
+            self._device = mesh.devices.flat[0]
+            mesh = None
         #: tensor parallelism (docs/serving_llm.md "Tensor parallelism",
         #: serve/tp.py): a 1-D jax Mesh makes THIS replica span its
         #: chips — weights sharded at rest, the KV pool and paged
@@ -524,6 +537,8 @@ class GenerationEngine:
             from .tp import tp_kv_specs
 
             kv_sharding = NamedSharding(mesh, tp_kv_specs(self._tp_axis))
+        elif self._device is not None:
+            kv_sharding = jax.sharding.SingleDeviceSharding(self._device)
         self.pool = PagePool(
             n_layers=len(params["blocks"]),
             n_kv_heads=n_kv,
@@ -628,6 +643,7 @@ class GenerationEngine:
                 dtype=np.dtype(
                     getattr(dp["embed"], "dtype", np.float32)
                 ),
+                sharding=kv_sharding if mesh is None else None,
             )
             self._draft_host = {
                 k: v for k, v in dp.items() if k != "n_heads"
@@ -660,16 +676,17 @@ class GenerationEngine:
                 ),
             )
         else:
-            self._params_dev = jax.device_put(host)
+            self._params_dev = jax.device_put(host, self._device)
         #: display name for telemetry — the fleet passes its replica
         #: names so the cost registry and /statusz attribute each step
         #: program to its replica; the sequence keeps registry KEYS
         #: unique even when two fleets reuse a replica name
         seq = _next_engine_seq()
         self.name = name if name is not None else f"eng{seq}"
-        # donation halves pool traffic on real chips; CPU jax warns and
-        # ignores it, so only request it where it works
-        donate = (1, 2) if jax.default_backend() == "tpu" else ()
+        # the step programs update the KV pool in place: pool.k / pool.v
+        # are donated on every backend, so whoever holds a pre-step
+        # reference to them holds a deleted array
+        donate = (1, 2)
         # each step program registers in the per-program cost registry
         # (obs/programs.py): compile wall-time + FLOP/byte estimates at
         # first dispatch, invocation count + cumulative dispatch time
@@ -740,7 +757,9 @@ class GenerationEngine:
             # proposals steer how many positions the verify covers,
             # never their values — while the VERIFY program shards on
             # KV heads exactly like decode (serve/tp.py).
-            self._draft_dev = jax.device_put(self._draft_host)
+            self._draft_dev = jax.device_put(
+                self._draft_host, self._device
+            )
             del self._draft_host
             self._verify_jit = _programs.instrument(
                 jax.jit(verify_fn, donate_argnums=donate),
@@ -763,6 +782,9 @@ class GenerationEngine:
         #: jit keys compiles on exactly this, so its length IS the number
         #: of compiled step programs
         self.program_signatures: set = set()
+        #: the step in progress dispatches a program for the first time
+        #: (set by _record_program, cleared when the step completes)
+        self._compiling = False
         self._req_counter = 0
         self._submit_lock = threading.Lock()
         self._step_lock = threading.RLock()
@@ -1231,7 +1253,14 @@ class GenerationEngine:
             else:
                 arr = np.asarray(a) if np.isscalar(a) else a
                 sig.append((tuple(arr.shape), str(arr.dtype)))
-        self.program_signatures.add(tuple(sig))
+        key = tuple(sig)
+        if key not in self.program_signatures:
+            # jit keys compiles on exactly this signature, so the
+            # dispatch that follows compiles — tens of seconds at real
+            # model widths. health() says so until the step completes:
+            # a watchdog must not read a compile as a wedge.
+            self._compiling = True
+            self.program_signatures.add(key)
 
     @property
     def num_step_programs(self) -> int:
@@ -1380,6 +1409,7 @@ class GenerationEngine:
                 # (normal, recovered, or failed — a wedged device call is
                 # the thing this must expose, and that never reaches here)
                 self._last_step_t = time.monotonic()
+                self._compiling = False
 
     def _step_locked(self) -> bool:
         poison = self._poison
@@ -1688,7 +1718,7 @@ class GenerationEngine:
         # dispatch inside a retry window, SYNCED inside it (jax dispatch
         # is async; failures.py's coverage rule): the compiled call is
         # functional and pool arrays are reassigned only on success, so a
-        # transient failure retries with an identical result. On TPU the
+        # transient failure retries with an identical result. The
         # step donates pool.k/v — a mid-execution failure there consumes
         # the donated buffers, the retry fails non-transiently, and the
         # supervisor escalates to fail-fast + restart() instead.
@@ -1740,7 +1770,7 @@ class GenerationEngine:
 
         # synced inside the retry window, like prefill (the host loop
         # needs ``nxt`` before the next step anyway, so the sync costs
-        # no pipelining); same donation caveat as prefill on TPU
+        # no pipelining); same donation caveat as prefill
         def dispatch():
             import jax
 
@@ -2293,7 +2323,7 @@ class GenerationEngine:
                 ),
             )
         else:
-            dev = jax.device_put(new_host)
+            dev = jax.device_put(new_host, self._device)
         with self._step_lock:
             self._params_dev = dev
             self._host_params = params
@@ -2313,6 +2343,9 @@ class GenerationEngine:
             "last_step_age_s": round(
                 time.monotonic() - self._last_step_t, 3
             ),
+            # the step in progress compiles a program it has not run
+            # before: its age is compile time, not a wedge
+            "compiling": self._compiling,
             "queue_depth": self.scheduler.queue_depth,
             "active_slots": sum(
                 s is not None for s in self.scheduler.slots

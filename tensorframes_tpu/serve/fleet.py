@@ -303,11 +303,14 @@ class Fleet:
     Engine-construction keywords (``max_slots``, ``page_size``,
     ``num_pages``, ``max_seq_len``, ``queue_capacity``, ``top_k``,
     ``eos_id``, ``moe_top_k``) apply to every replica — identical
-    replicas are what make replay byte-identical. Fleet knobs:
+    replicas are what make replay byte-identical. Replica *i* lives on
+    local chip *i mod n* (weights, KV pool, programs), so a four-chip
+    host runs four replicas on four chips. Fleet knobs:
 
     - ``watchdog_interval_s`` — health-poll + failover-drain cadence;
     - ``wedge_timeout_s`` — last-step watchdog age (with work pending)
-      past which a live-but-stuck replica is fenced;
+      past which a live-but-stuck replica is fenced (a step that
+      compiles its program is exempt: ``health()["compiling"]``);
     - ``probe_timeout_s`` — how long a restarted replica's probe
       generation may take before re-admission is abandoned (retried on
       a later poll);
@@ -416,24 +419,29 @@ class Fleet:
                 _Replica(str(name), eng) for name, eng in engines
             ]
         else:
-            self._replicas = [
-                _Replica(
-                    f"r{i}",
-                    GenerationEngine(
-                        model,
-                        name=f"r{i}",
-                        **{
-                            **engine_kwargs,
-                            **(
-                                replica_kwargs[i]
-                                if replica_kwargs is not None
-                                else {}
-                            ),
-                        },
+            import jax
+            from jax.sharding import Mesh
+
+            # replica i lives on local chip i mod n — weights, KV pool
+            # and step programs — so N replicas use N chips instead of
+            # stacking on chip 0. A replica given its own ``mesh``
+            # (tensor parallelism) already says where it lives.
+            chips = jax.local_devices()
+            self._replicas = []
+            for i in range(int(replicas)):
+                kw = {
+                    "mesh": Mesh(
+                        np.array([chips[i % len(chips)]]), ("tp",)
                     ),
+                    **engine_kwargs,
+                    **(replica_kwargs[i] if replica_kwargs else {}),
+                }
+                self._replicas.append(
+                    _Replica(
+                        f"r{i}",
+                        GenerationEngine(model, name=f"r{i}", **kw),
+                    )
                 )
-                for i in range(int(replicas))
-            ]
         if tiers is not None:
             # one tier label per replica, roster order — the
             # disaggregated-serving door (serve/tiers.py): ``prefill``
@@ -1720,6 +1728,11 @@ class Fleet:
                     h["last_step_age_s"] > self.wedge_timeout_s
                     and (h["queue_depth"] > 0 or h["active_slots"] > 0)
                     and bool(h["stepping_thread_alive"])
+                    # a step that compiles its program takes as long as
+                    # XLA does — past any sane wedge bound at real model
+                    # widths; the engine reports it (absent on members
+                    # that predate the field)
+                    and not h.get("compiling")
                 )
                 if not h["healthy"] or wedged:
                     self._fence(
@@ -1780,22 +1793,31 @@ class Fleet:
         _tenancy.register_fleet(self)
         return self
 
+    def _tick(self) -> None:
+        """One router iteration: health poll (fence/restart), tick
+        hooks, queued KV migrations, the failover queue. The router
+        thread loops over this; a fleet that was never started can be
+        driven by calling it between hand-run engine steps (how the
+        tier tests place a handoff at an exact point in a stream)."""
+        self._poll_replicas()
+        for hook in list(self._tick_hooks):
+            try:
+                hook()
+            except Exception:
+                logger.warning(
+                    "fleet: tick hook %r failed", hook, exc_info=True
+                )
+        self._drain_migrations()
+        self._drain_failovers()
+
     def _supervise(self) -> None:
-        """The router thread: fence/restart on health, resubmit the
-        failover queue. Logs loudly if it ever dies — a silent watchdog
-        death would turn the next replica fault back into an outage."""
+        """The router thread: :meth:`_tick` every watchdog interval (or
+        sooner when woken). Logs loudly if it ever dies — a silent
+        watchdog death would turn the next replica fault back into an
+        outage."""
         try:
             while not self._stop_evt.is_set():
-                self._poll_replicas()
-                for hook in list(self._tick_hooks):
-                    try:
-                        hook()
-                    except Exception:
-                        logger.warning(
-                            "fleet: tick hook %r failed", hook, exc_info=True
-                        )
-                self._drain_migrations()
-                self._drain_failovers()
+                self._tick()
                 self._wake.wait(self.watchdog_interval_s)
                 self._wake.clear()
         except BaseException:

@@ -72,7 +72,7 @@ class PagePool:
     head_dim]`` jax arrays — page ``num_pages`` is the trash page (see
     module docstring). The arrays are exposed as plain attributes because
     the engine's compiled step functions consume and return them
-    functionally (donated on TPU); the pool only tracks WHICH pages are
+    functionally (donated); the pool only tracks WHICH pages are
     live, never their contents."""
 
     def __init__(
@@ -97,13 +97,15 @@ class PagePool:
         self.head_dim = int(head_dim)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        #: optional jax sharding pinning the KV-HEAD axis across a
-        #: device mesh (tensor-parallel serving, ``serve/tp.py``): each
-        #: chip holds its slice of every page, so one page costs
-        #: 1/N of its solo bytes per chip and a fixed per-chip HBM
-        #: budget holds N× the pages — the aggregate-capacity unlock.
-        #: Page BOOKKEEPING (free list, refcounts, tables) is untouched:
-        #: a page is still one logical unit spanning all shards.
+        #: optional jax sharding of the pool arrays: a single-device
+        #: sharding pins a solo replica's pool to its chip; a mesh
+        #: sharding splits the KV-HEAD axis across the mesh
+        #: (tensor-parallel serving, ``serve/tp.py``) — each chip holds
+        #: its slice of every page, so one page costs 1/N of its solo
+        #: bytes per chip and a fixed per-chip HBM budget holds N× the
+        #: pages — the aggregate-capacity unlock. Page BOOKKEEPING (free
+        #: list, refcounts, tables) is untouched: a page is still one
+        #: logical unit spanning all shards.
         self.sharding = sharding
         #: index of the trash page (valid to write, never read unmasked)
         self.trash_page = self.num_pages
@@ -115,8 +117,8 @@ class PagePool:
             self.head_dim,
         )
         dtype = jnp.float32 if dtype is None else dtype
-        self.k = self.place(jnp.zeros(shape, dtype))
-        self.v = self.place(jnp.zeros(shape, dtype))
+        self.k = jnp.zeros(shape, dtype, device=sharding)
+        self.v = jnp.zeros(shape, dtype, device=sharding)
         #: named parallel page-array families addressed by the SAME page
         #: indices as ``k``/``v`` (:meth:`add_group`) — how a draft
         #: model's KV rides the pool without its own allocator: one
@@ -138,8 +140,8 @@ class PagePool:
 
     def place(self, arr):
         """Pin ``arr`` to the pool's sharding (identity when unsharded).
-        Every eager rewrite of the pool arrays — :meth:`reset`,
-        :meth:`defragment`, the engine's copy-on-write clone — runs
+        Every eager rewrite of the pool arrays — :meth:`defragment`,
+        the engine's copy-on-write clone, a migrated slot's rows — runs
         through this so the compiled step programs always receive
         already-placed inputs instead of resharding on dispatch."""
         if self.sharding is None:
@@ -266,8 +268,8 @@ class PagePool:
                 self.head_dim,
             )
             dtype = self.k.dtype
-            self.k = self.place(jnp.zeros(shape, dtype))
-            self.v = self.place(jnp.zeros(shape, dtype))
+            self.k = jnp.zeros(shape, dtype, device=self.sharding)
+            self.v = jnp.zeros(shape, dtype, device=self.sharding)
             for g in self.groups.values():
                 g.reset()
             self._free = list(range(self.num_pages - 1, -1, -1))
@@ -358,16 +360,13 @@ class PageGroup:
         dtype=None,
         sharding=None,
     ):
-        import jax.numpy as jnp
-
         self.pool = pool
         self.n_layers = int(n_layers)
         self.n_kv_heads = int(n_kv_heads)
         self.head_dim = int(head_dim)
         self.sharding = sharding
         self._dtype = pool.k.dtype if dtype is None else dtype
-        self.k = self.place(jnp.zeros(self._shape(), self._dtype))
-        self.v = self.place(jnp.zeros(self._shape(), self._dtype))
+        self.reset()
 
     def _shape(self):
         return (
@@ -393,8 +392,8 @@ class PageGroup:
         :meth:`PagePool.reset`)."""
         import jax.numpy as jnp
 
-        self.k = self.place(jnp.zeros(self._shape(), self._dtype))
-        self.v = self.place(jnp.zeros(self._shape(), self._dtype))
+        self.k = jnp.zeros(self._shape(), self._dtype, device=self.sharding)
+        self.v = jnp.zeros(self._shape(), self._dtype, device=self.sharding)
 
 
 class SequencePages:

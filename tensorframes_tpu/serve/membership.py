@@ -1726,7 +1726,19 @@ class LocalProcessProvisioner:
     the autoscaler's own ``max_members``/``cooldown_s`` guard rails
     stay in charge of WHEN); scale-down only ever retires processes
     THIS provisioner spawned, newest first, so externally-managed
-    members are untouchable from here."""
+    members are untouchable from here.
+
+    The rule on accelerators is ONE MEMBER PROCESS PER CHIP: a process
+    that initializes jax takes every chip it can see, and a second
+    process that needs one then fails or hangs at start-up. So the
+    process that owns this provisioner (the router) must hold no engine
+    and initialize no backend — scale-up is refused with a logged
+    reason if it has — each member's ``env`` must narrow it to its own
+    chip, and ``max_procs`` must not exceed the chips of the host. On a
+    one-chip host there is nothing to provision: run in-process
+    :class:`~tensorframes_tpu.serve.Fleet` replicas instead. Members
+    inherit this process's stdout/stderr, so a member that dies at
+    start-up says why."""
 
     def __init__(
         self,
@@ -1779,6 +1791,16 @@ class LocalProcessProvisioner:
         """Spawn one member subprocess; returns its name, or ``None``
         at the ``max_procs`` bound."""
         self.reap()
+        from ..obs.programs import initialized_device
+
+        dev = initialized_device()
+        if dev is not None and dev.platform != "cpu":
+            logger.warning(
+                "provisioner: scale_up refused — this process has "
+                "initialized the %s backend and holds its chips, so a "
+                "member process could not start on one", dev.platform,
+            )
+            return None
         with self._lock:
             if len(self._procs) >= self.max_procs:
                 logger.warning(

@@ -53,7 +53,7 @@ Tests drive TP=2/4 on the CPU-simulated mesh
 (``xla_force_host_platform_device_count``, the conftest default), so
 tier-1 exercises the whole plan without hardware; on real chips the
 collectives ride ICI exactly like the ``parallel/`` primitives
-(MULTICHIP_r0*.json measured the rings these gathers lower to).
+(``chip_smoke.py`` phase 4 runs a tp=4 engine across four chips).
 """
 
 from __future__ import annotations
@@ -106,15 +106,6 @@ def validate_tp_mesh(mesh, n_heads: int, n_kv: int, d_ff: int) -> str:
                 f"the KV pool and weight shards split evenly or not at "
                 f"all"
             )
-    from ..parallel.compat import has_shard_map
-
-    if not has_shard_map():
-        import jax
-
-        raise RuntimeError(
-            f"jax {jax.__version__} offers no shard_map API; "
-            f"tensor-parallel serving cannot build its step programs"
-        )
     return axes[0]
 
 
@@ -140,12 +131,11 @@ def _wrap(body, mesh, axis: str, param_specs, n_scalars: int):
     """jit-ready shard_map over one step body: params tree sharded per
     ``param_specs``, the two pool arrays on the KV-head axis, every
     other input replicated, outputs ``(k_pool, v_pool, tokens)``."""
+    import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map
-
     kv = tp_kv_specs(axis)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, kv, kv) + (P(),) * n_scalars,
@@ -460,8 +450,6 @@ def estimate_collective_seconds(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.compat import shard_map
-
     params = engine._params_dev
     n_layers = len(params["blocks"])
     n_kv = engine.pool.n_kv_heads
@@ -486,7 +474,7 @@ def estimate_collective_seconds(
         return acc + sum(jnp.sum(o[0, 0]) for o in outs)
 
     prog = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(engine._tp_param_specs, P(None, axis, None, None)),

@@ -5,7 +5,7 @@ Every perf-critical constant in the stack used to be a hand-measured
 static — the ``_BEST_BLOCKS`` tile tables, ``paged_page_size_hint``'s
 serving default, ``Config.transfer_chunk_bytes`` / ``transfer_streams``,
 ``serve_prefill_chunk_tokens``, the map-rows block-row budget — and the
-r05 bench rounds showed those go stale the moment link weather or
+r05 bench rounds showed those go stale the moment the link or the
 shapes change. This package replaces them with three cooperating
 pieces:
 

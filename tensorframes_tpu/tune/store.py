@@ -1,9 +1,10 @@
 """Persisted tuning database: winners survive restarts, fleet-wide.
 
-One JSONL file — by default ``tune.jsonl`` next to the XLA persistent
-compile cache (``~/.cache/tensorframes_tpu``), the same shared home
-that lets a fleet of processes reuse each other's compiled programs —
-holds every tuned winner, keyed by ``surface | signature | device
+One JSONL file — by default ``tune.jsonl`` inside the XLA persistent
+compile cache directory (``$JAX_COMPILATION_CACHE_DIR``, else
+``<checkout>/.jax_cache``), the same shared home that lets a fleet of
+processes reuse each other's compiled programs — holds every tuned
+winner, keyed by ``surface | signature | device
 kind``. The durability model mirrors the compile cache's:
 
 - **atomic rename writes**: a put re-reads the current file, merges the
@@ -61,38 +62,30 @@ def device_kind() -> str:
     """The accelerator kind winners are keyed under — a winner measured
     on one chip generation must not serve another. Cached for the
     process lifetime (the device cannot change under a live runtime,
-    and this sits on per-transfer lookup paths)."""
+    and this sits on per-transfer lookup paths). Initializes the
+    backend if nothing has yet: whoever asks for a tuned winner is
+    about to compute on the device, and a backend that fails to come up
+    must say so rather than tune under ``"unknown"``."""
     global _device_kind_cache
     if _device_kind_cache is None:
-        try:
-            import jax
+        import jax
 
-            _device_kind_cache = str(jax.devices()[0].device_kind)
-        except Exception:
-            return "unknown"
+        _device_kind_cache = str(jax.devices()[0].device_kind)
     return _device_kind_cache
 
 
 def store_path() -> str:
     """Where the tuning store lives: ``Config.tune_file``, else
-    ``$TFT_TUNE_FILE``, else ``tune.jsonl`` next to the XLA compile
-    cache directory (same precedence as
-    :func:`~tensorframes_tpu.utils.config.enable_compilation_cache` for
-    locating that directory)."""
-    from ..utils.config import get_config
+    ``$TFT_TUNE_FILE``, else ``tune.jsonl`` inside the compile-cache
+    directory (:func:`~tensorframes_tpu.utils.config.compilation_cache_dir`
+    — ``$JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``), so
+    whoever places the cache places the winners with it."""
+    from ..utils.config import compilation_cache_dir, get_config
 
     explicit = get_config().tune_file or os.environ.get("TFT_TUNE_FILE", "")
     if explicit:
         return explicit
-    cache_dir = (
-        os.environ.get("TFT_COMPILE_CACHE_DIR")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or os.path.join(
-            os.path.expanduser("~"), ".cache", "tensorframes_tpu",
-            "xla-cache",
-        )
-    )
-    return os.path.join(os.path.dirname(cache_dir), "tune.jsonl")
+    return os.path.join(compilation_cache_dir(), "tune.jsonl")
 
 
 def make_key(surface: str, signature: str, device: Optional[str] = None) -> str:

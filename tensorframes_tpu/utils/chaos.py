@@ -34,7 +34,7 @@ Named **injection sites** sit on the host-side dispatch paths:
   then fence-rejected)
 - ``frame.h2d`` / ``frame.d2h`` — inside every streaming-transfer
   chunk's retry window (``frame/transfer.py``): a ``transient`` here is
-  the flaky-tunnel-during-ingest drill (one chunk retries; the column
+  the flaky-link-during-ingest drill (one chunk retries; the column
   still lands byte-identical)
 - ``fleet.place`` — inside the serving fleet's placement path
   (``serve/fleet.py``): a ``transient`` here retries invisibly; a

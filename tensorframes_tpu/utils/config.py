@@ -17,6 +17,7 @@ __all__ = [
     "get_config",
     "set_config",
     "ensure_x64",
+    "compilation_cache_dir",
     "enable_compilation_cache",
 ]
 
@@ -54,14 +55,14 @@ class Config:
     #: the link saturates (guidance in docs/ingest.md).
     transfer_streams: int = 4
     #: optional WIRE cast for float32 payloads: ``"bf16"`` crosses the
-    #: link as bfloat16 (half the tunnel bytes) and upcasts back to
+    #: link as bfloat16 (half the link bytes) and upcasts back to
     #: float32 on device — schemas, programs, and device dtypes are
     #: untouched, only the values round to bf16 precision (the accuracy
     #: trade the bf16 bench mode measures; see docs/ingest.md caveats).
     #: ``""`` (default) transfers verbatim — the byte-identity mode.
     transfer_dtype: str = ""
     #: retries for transient device-runtime failures (UNAVAILABLE /
-    #: DEADLINE_EXCEEDED / dropped tunnel); see utils/failures.py. The
+    #: DEADLINE_EXCEEDED / dropped connection); see utils/failures.py. The
     #: reference rode Spark's task retry instead (SURVEY §5).
     max_retries: int = 2
     #: base of the exponential retry backoff, seconds.
@@ -213,9 +214,8 @@ class Config:
     #: predicted configs, and never more than half the full grid.
     tune_top_k: int = 4
     #: path of the persisted tuning store (JSONL). Empty means
-    #: ``$TFT_TUNE_FILE``, else ``tune.jsonl`` next to the XLA
-    #: persistent compile cache directory (the same
-    #: ``~/.cache/tensorframes_tpu`` trajectory home).
+    #: ``$TFT_TUNE_FILE``, else ``tune.jsonl`` inside the compile-cache
+    #: directory (:func:`compilation_cache_dir`).
     tune_file: str = ""
     #: shared directory for the fleet telemetry plane
     #: (``obs/export.py``): every process with a live sampler snapshots
@@ -307,41 +307,52 @@ def set_config(**kwargs) -> Config:
     return _config
 
 
+#: where the compile cache lives when ``JAX_COMPILATION_CACHE_DIR`` does
+#: not place it: one fixed, git-ignored directory at the root of the
+#: checkout this package was imported from. The path is part of nothing
+#: jax keys on, but a directory that moves between processes never hits.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
 _cache_enabled_dir: "str | None" = None
 
 
-def enable_compilation_cache(
-    path: "str | None" = None,
-    *,
-    min_compile_time_secs: float = 0.1,
-    min_entry_size_bytes: int = -1,
-) -> "str | None":
-    """Point XLA's persistent compilation cache at a disk directory.
+def compilation_cache_dir() -> str:
+    """The compile-cache directory this process uses (or would use, with
+    the cache disabled): ``$JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``<checkout>/.jax_cache``. The tuning store keeps ``tune.jsonl`` in
+    the same directory, so one variable places both."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
+
+
+def enable_compilation_cache() -> "str | None":
+    """Turn on XLA's persistent compilation cache for this process.
 
     The reference pays zero compile cost — a TF 1.x session executes its
     GraphDef immediately (``TensorFlowOps.scala:76-95``) — while every
-    fresh JAX process re-traces and re-compiles each program from scratch
-    (~100 s of warmup on the headline bench). With this cache enabled,
-    compiles are keyed on (HLO, compile options, backend) and serialized
-    executables are reloaded by later processes, so a fresh process pays
-    only deserialization (<1 s per program) instead of compilation.
+    fresh JAX process re-traces and re-compiles each program from
+    scratch. With the cache on, compiles are keyed on (HLO, compile
+    options, backend) and later processes reload the serialized
+    executables instead of compiling.
 
-    Called automatically on ``import tensorframes_tpu`` (opt out with
-    ``TFT_NO_COMPILE_CACHE=1``). Idempotent; returns the cache dir in use,
-    or ``None`` when disabled. Precedence for the directory:
+    Called on ``import tensorframes_tpu`` (opt out with
+    ``TFT_NO_COMPILE_CACHE=1``). Idempotent; returns the directory in
+    use (:func:`compilation_cache_dir`), or ``None`` when disabled.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already points at
+    that directory and this function sets no other; where it is not,
+    the cache goes to the fixed in-checkout directory.
 
-    1. explicit ``path`` argument
-    2. ``TFT_COMPILE_CACHE_DIR`` environment variable
-    3. ``JAX_COMPILATION_CACHE_DIR`` (jax's own knob — left untouched)
-    4. ``~/.cache/tensorframes_tpu/xla-cache``
-
-    ``min_compile_time_secs`` (default 0.1 s, vs jax's 1.0 s) caches even
-    small programs: engine passes dispatch many sub-second-compile thunks
-    (fold programs, vmap buckets) whose re-compiles dominate short-job
-    warmup. ``min_entry_size_bytes=-1`` removes the size floor for the
-    same reason. Entries are content-addressed, so a shared directory is
-    safe across concurrent processes.
-    """
+    Either way jax's two admission thresholds are lowered: entries are
+    kept from 0.1 s of compile time (jax's default is 1.0 s) and with no
+    size floor, because engine passes and the serving steps dispatch
+    many sub-second programs (fold programs, vmap buckets, page-pool
+    rewrites) whose re-compiles dominate a short job's start-up.
+    Entries are content-addressed, so a directory shared by concurrent
+    processes is safe."""
     global _cache_enabled_dir
     if os.environ.get("TFT_NO_COMPILE_CACHE", "") not in ("", "0"):
         return None
@@ -350,30 +361,25 @@ def enable_compilation_cache(
             return _cache_enabled_dir
         import jax
 
-        if path is None:
-            path = os.environ.get("TFT_COMPILE_CACHE_DIR")
-        if path is None and os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            # the user already configured jax directly; respect it
-            _cache_enabled_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
-            return _cache_enabled_dir
-        if path is None:
-            path = os.path.join(
-                os.path.expanduser("~"), ".cache", "tensorframes_tpu",
-                "xla-cache",
-            )
+        path = compilation_cache_dir()
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", path)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         try:
             os.makedirs(path, exist_ok=True)
-        except OSError:  # read-only HOME (hermetic CI): run uncached
-            return None
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            min_compile_time_secs,
-        )
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes",
-            min_entry_size_bytes,
-        )
+            writable = os.access(path, os.W_OK)
+        except OSError:
+            writable = False
+        if not writable:
+            # this function runs its body once per process, so this
+            # warns once: jax would otherwise skip every write silently
+            from .logging import get_logger
+
+            get_logger("config").warning(
+                "compile cache directory %s is not writable; every "
+                "process will compile from scratch", path,
+            )
         _cache_enabled_dir = path
         return path
 

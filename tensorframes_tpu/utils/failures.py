@@ -5,8 +5,8 @@ retry and lineage (SURVEY §5: "fully delegated to Spark"). There is no
 Spark here, so the engine carries its own, sized to how a PJRT/TPU runtime
 actually fails:
 
-- **transient runtime errors** (preempted tunnel, UNAVAILABLE /
-  DEADLINE_EXCEEDED from the PJRT client, dropped connection): the program
+- **transient runtime errors** (UNAVAILABLE / DEADLINE_EXCEEDED from
+  the PJRT client, a preempted or dropped connection): the program
   and its inputs are still on the host or reproducible from it, so the
   dispatch is safe to retry with backoff — the same property Spark exploits
   (pure per-task functions, ``DebugRowOps.scala:766-803``).
@@ -63,9 +63,8 @@ logger = get_logger("failures")
 from ..obs import flight as _flight  # noqa: E402
 from ..obs.metrics import counter as _counter  # noqa: E402
 
-#: one series per (op, failure reason): makes flaky-link behavior (the
-#: degraded-link rows in BENCH_ALL_r05.json) graphable instead of a stream
-#: of warnings
+#: one series per (op, failure reason): makes flaky-link behavior
+#: graphable instead of a stream of warnings
 _retries_total = _counter(
     "failures.retries_total",
     "Transient device-runtime failures retried, by op and reason",
@@ -409,7 +408,7 @@ def _backoff_delay(attempt: int, base: float) -> float:
     ``(0.05 * cap, cap]`` where ``cap = base * 2**n``.
 
     The deterministic ``base * 2**n`` schedule retried *synchronized*
-    failures in lockstep — every client that lost the same tunnel or TPU
+    failures in lockstep — every client that lost the same connection or TPU
     runtime slammed it again at the same instant, each round. Full
     jitter (the AWS-architecture result) decorrelates the herd while
     keeping the same cap per attempt. The floor is a sliver of the cap

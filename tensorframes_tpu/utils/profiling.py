@@ -12,7 +12,21 @@ import contextlib
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["trace", "Timer", "block_until_ready"]
+__all__ = ["trace", "Timer", "block_until_ready", "device_stamp"]
+
+
+def device_stamp() -> Dict[str, object]:
+    """``platform`` / ``device_kind`` / ``device_count`` as jax reports
+    them: stamped on every line a benchmark prints, so that no number
+    can be read without the device it was taken on."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def block_until_ready(tree) -> None:

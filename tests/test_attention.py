@@ -15,8 +15,6 @@ from tensorframes_tpu.ops import (
 )
 from tensorframes_tpu.parallel import make_mesh
 
-from _gates import requires_shard_map
-
 
 def qkv(rng, b=2, h=2, l=32, d=8, dtype=np.float32):
     def mk():
@@ -191,7 +189,6 @@ class TestFlashAttentionGrads:
 
 
 class TestRingAttention:
-    @requires_shard_map
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, nprng, causal):
         mesh = make_mesh({"sp": 4})
@@ -200,7 +197,6 @@ class TestRingAttention:
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    @requires_shard_map
     def test_eight_way(self, nprng):
         mesh = make_mesh({"sp": 8})
         q, k, v = qkv(nprng, l=64, d=4)
@@ -208,7 +204,6 @@ class TestRingAttention:
         ref = attention_reference(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    @requires_shard_map
     def test_matches_flash_single_chip(self, nprng):
         mesh = make_mesh({"sp": 4})
         q, k, v = qkv(nprng, l=32)
@@ -222,7 +217,6 @@ class TestRingAttention:
         with pytest.raises(ValueError, match="divide"):
             ring_attention(q, k, v, mesh=mesh)
 
-    @requires_shard_map
     @pytest.mark.parametrize("causal", [False, True])
     def test_blockwise_hops_multiple_tiles(self, nprng, causal):
         # chunk (L/n = 32) split into four 8-wide tiles per hop: the carry
@@ -235,7 +229,6 @@ class TestRingAttention:
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    @requires_shard_map
     def test_bf16_matches_f32(self, nprng):
         mesh = make_mesh({"sp": 4})
         q, k, v = qkv(nprng, l=64)
@@ -275,7 +268,6 @@ class TestRingAttentionGrads:
 
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    @requires_shard_map
     @pytest.mark.parametrize("causal", [False, True])
     def test_grads_match_oracle(self, nprng, causal):
         mesh = make_mesh({"sp": 4})
@@ -290,7 +282,6 @@ class TestRingAttentionGrads:
                 err_msg=f"d{name}",
             )
 
-    @requires_shard_map
     def test_grads_multiple_tiles_per_hop(self, nprng):
         # sub-block streaming in the BACKWARD hops too
         mesh = make_mesh({"sp": 4})
@@ -337,7 +328,6 @@ class TestUlyssesAttention:
     """All-to-all sequence parallelism: seq-sharded -> head-sharded ->
     attend full-L -> shard back (ops/ulysses.py)."""
 
-    @requires_shard_map
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, nprng, causal):
         mesh = make_mesh({"sp": 4})
@@ -346,7 +336,6 @@ class TestUlyssesAttention:
         ref = attention_reference(q, k, v, causal=causal)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    @requires_shard_map
     def test_eight_way(self, nprng):
         mesh = make_mesh({"sp": 8})
         q, k, v = qkv(nprng, h=8, l=64, d=4)
@@ -354,7 +343,6 @@ class TestUlyssesAttention:
         ref = attention_reference(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
-    @requires_shard_map
     def test_matches_ring(self, nprng):
         mesh = make_mesh({"sp": 4})
         q, k, v = qkv(nprng, h=4, l=32)
@@ -374,7 +362,6 @@ class TestUlyssesAttention:
         with pytest.raises(ValueError, match="divide"):
             ulysses_attention(q, k, v, mesh=mesh)
 
-    @requires_shard_map
     def test_transformer_ulysses_impl(self, nprng):
         from tensorframes_tpu.models import init_transformer, transformer_logits
 

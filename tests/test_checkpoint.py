@@ -21,8 +21,6 @@ from tensorframes_tpu.utils.checkpoint import (
     save_checkpoint,
 )
 
-from _gates import requires_shard_map
-
 
 def test_pytree_round_trip(tmp_path):
     tree = {
@@ -128,7 +126,6 @@ def test_transformer_fit_resume_matches_uninterrupted(tmp_path):
     np.testing.assert_allclose(first + rest, full, rtol=1e-5, atol=1e-6)
 
 
-@requires_shard_map
 def test_fit_pipelined_resume_matches_uninterrupted(tmp_path):
     """Resume through the PIPELINE layout: the restored stacked slab must
     be re-pinned to the pp axis (restored leaves come back committed to
@@ -177,7 +174,6 @@ def test_fit_tp_resume_matches_uninterrupted(tmp_path):
     np.testing.assert_allclose(first + rest, full, rtol=1e-5, atol=1e-6)
 
 
-@requires_shard_map
 def test_fit_sharded_resume_matches_uninterrupted(tmp_path):
     """Resume through the sequence-parallel (ring) plan."""
     from tensorframes_tpu.parallel import make_mesh

@@ -10,8 +10,6 @@ from tensorframes_tpu.frame import TensorFrame
 from tensorframes_tpu.models import CNNScorer, cnn_embed, cnn_logits, init_cnn
 from tensorframes_tpu.utils import get_config, set_config
 
-from _gates import requires_shard_map
-
 
 def _image_frame(scorer, n=12, parts=3, seed=0):
     rng = np.random.default_rng(seed)
@@ -58,7 +56,6 @@ class TestCNN:
         want = np.asarray(cnn_embed(scorer.params, imgs))
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
-    @requires_shard_map
     def test_score_frame_distributed(self):
         from tensorframes_tpu import parallel
 

@@ -13,8 +13,6 @@ import tensorframes_tpu as tft
 from tensorframes_tpu import parallel
 from tensorframes_tpu.frame import TensorFrame
 
-from _gates import requires_shard_map
-
 
 def _bytes_frame(n=20, dim=8, parts=3, seed=0):
     rng = np.random.default_rng(seed)
@@ -154,7 +152,6 @@ class TestMapRowsDecoders:
                 lambda data: {"s": data.sum()}, df, decoders={"nope": _decode}
             )
 
-    @requires_shard_map
     def test_distributed_decoders(self):
         df, arrays = _bytes_frame(n=64, dim=8, parts=8)
         out = parallel.map_rows(

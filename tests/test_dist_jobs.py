@@ -214,7 +214,7 @@ class TestRetryDeadline:
 
         def flaky():
             calls.append(1)
-            raise RuntimeError("UNAVAILABLE: tunnel dropped")
+            raise RuntimeError("UNAVAILABLE: connection dropped")
 
         try:
             t0 = time.monotonic()
@@ -232,7 +232,7 @@ class TestRetryDeadline:
 
         def flaky():
             calls.append(1)
-            raise RuntimeError("UNAVAILABLE: tunnel dropped")
+            raise RuntimeError("UNAVAILABLE: connection dropped")
 
         try:
             with retry_deadline(0.1):

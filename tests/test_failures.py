@@ -46,7 +46,7 @@ class TestClassification:
     def test_markers_match_case_insensitively(self):
         # PJRT renders UNAVAILABLE, grpc-python unavailable, wrappers
         # anything between — the casing must not decide retryability
-        assert is_transient(RuntimeError("unavailable: tunnel dropped"))
+        assert is_transient(RuntimeError("unavailable: connection dropped"))
         assert is_transient(RuntimeError("Deadline_Exceeded: rpc wait"))
         assert is_transient(RuntimeError("Connection Reset by peer"))
         assert is_transient(RuntimeError("SOCKET CLOSED mid-write"))
@@ -65,7 +65,7 @@ class TestClassification:
             except RuntimeError as outer:
                 return outer
 
-        assert is_transient(build("UNAVAILABLE: preempted tunnel"))
+        assert is_transient(build("UNAVAILABLE: preempted connection"))
         assert is_oom(build("RESOURCE_EXHAUSTED: hbm"))
         assert not is_transient(build("RESOURCE_EXHAUSTED: hbm"))
         assert not is_transient(build("just a bug"))
@@ -121,7 +121,7 @@ class TestRunWithRetries:
         def flaky():
             calls.append(1)
             if len(calls) < 3:
-                raise RuntimeError("UNAVAILABLE: tunnel dropped")
+                raise RuntimeError("UNAVAILABLE: connection dropped")
             return 42
 
         assert run_with_retries(flaky) == 42

@@ -354,6 +354,53 @@ def _post_generate(addr, spec) -> tuple:
     return status, payload, resp
 
 
+class TestWedgeWatchdog:
+    """``wedge_timeout_s``: a step that outlives it with work pending is
+    a wedge — unless the step is compiling a program it has not run
+    before, which at real model widths takes longer than any sane wedge
+    bound (on the chip, four GPT-2-small replicas compiling side by
+    side were all fenced at the 30 s default before this exemption)."""
+
+    def test_a_compiling_step_is_not_a_wedge(self, lm):
+        fleet = _fleet(lm, 1, wedge_timeout_s=0.15)
+        failovers0 = _counter_value("fleet.failovers_total")
+        try:
+            fleet.start()
+            # the stall lands inside the FIRST prefill dispatch — the one
+            # that compiles — and outlasts the wedge bound twice over
+            with chaos.scoped("serve.prefill=latency:ms=400:times=1"):
+                h = fleet.submit([3, 1, 4], 6)
+                np.testing.assert_array_equal(
+                    np.asarray(h.result(timeout=60)), _solo(lm, [3, 1, 4], 6)
+                )
+            assert fleet.replica_state("r0") == "active"
+            assert _counter_value("fleet.failovers_total") == failovers0
+        finally:
+            fleet.stop()
+
+    def test_a_compiled_step_that_stalls_is_fenced(self, lm):
+        fleet = _fleet(lm, 2, wedge_timeout_s=0.15)
+        try:
+            fleet.start()
+            for eng in fleet.engines:  # warm both replicas' programs
+                eng.submit([2, 7], 3).result(timeout=60)
+            assert set(fleet.program_counts().values()) == {2}
+            with chaos.scoped("serve.decode_step=latency:ms=600:times=1"):
+                h = fleet.submit([5, 9, 2], 6)
+                _wait_for(
+                    lambda: "fenced" in {
+                        fleet.replica_state(n) for n in fleet.replica_names
+                    },
+                    what="the stalled replica fenced as wedged",
+                )
+                # the stream replays on the survivor, byte-identical
+                np.testing.assert_array_equal(
+                    np.asarray(h.result(timeout=60)), _solo(lm, [5, 9, 2], 6)
+                )
+        finally:
+            fleet.stop()
+
+
 class TestFleetEndpoint:
     def test_generate_healthz_aggregate_and_fencing(self, lm):
         from tensorframes_tpu.interop.serving import ScoringServer

@@ -668,7 +668,7 @@ class TestBackoffJitter:
         def flaky():
             calls.append(1)
             if len(calls) < 4:
-                raise RuntimeError("UNAVAILABLE: tunnel dropped")
+                raise RuntimeError("UNAVAILABLE: connection dropped")
             return 1
 
         assert run_with_retries(flaky) == 1

@@ -11,8 +11,6 @@ from tensorframes_tpu.models import (
     kmeans,
 )
 
-from _gates import requires_shard_map
-
 
 def blob_data(n=300, d=5, k=3, seed=7):
     rng = np.random.default_rng(seed)
@@ -45,7 +43,6 @@ class TestKMeans:
         assert all(0 <= r.closest_centroid < 3 for r in rows)
         assert all(r.distance >= 0 for r in rows)
 
-    @requires_shard_map
     def test_distributed_matches_local(self):
         data, _, _ = blob_data(n=160)
         df = tft.TensorFrame.from_columns({"features": data}).analyze()

@@ -189,6 +189,39 @@ class TestIdentity:
         monkeypatch.setenv("TFT_PROC_ID", "replica-7")
         assert export.proc_id() == "replica-7"
 
+    def test_server_without_engine_initializes_no_backend(self):
+        """A chip belongs to one process: a process that fronts engines
+        elsewhere (a router, a score-only driver before its first
+        partition) must start, label itself and answer every status
+        endpoint without initializing a jax backend."""
+        script = r"""
+import socket
+from jax._src import xla_bridge
+from tensorframes_tpu.interop import ScoringServer
+
+srv = ScoringServer(lambda x: {"y": x * 2.0})
+host, port = srv.start()
+for path in ("/metrics", "/statusz", "/healthz", "/varz"):
+    with socket.create_connection((host, port), timeout=10) as c:
+        c.sendall(
+            f"GET {path} HTTP/1.1\r\nHost: x\r\n"
+            f"Connection: close\r\n\r\n".encode()
+        )
+        buf = b""
+        while chunk := c.recv(65536):
+            buf += chunk
+    assert buf.startswith(b"HTTP/1.1 200"), (path, buf[:80])
+    if path == "/metrics":
+        assert b'role="driver"' in buf and b'device="unknown"' in buf
+srv.stop()
+assert not xla_bridge.backends_are_initialized()
+"""
+        out = subprocess.run(
+            [sys.executable, "-c", script],
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+
 
 # ---------------------------------------------------------------------------
 # aggregate: read-side merge semantics

@@ -8,8 +8,6 @@ import pytest
 import tensorframes_tpu as tft
 import tensorframes_tpu.parallel as par
 
-from _gates import requires_shard_map
-
 
 @pytest.fixture(scope="module")
 def mesh():
@@ -27,13 +25,11 @@ def test_mesh_shapes():
 
 
 class TestDistributedMapBlocks:
-    @requires_shard_map
     def test_divisible(self, mesh):
         df = tft.TensorFrame.from_columns({"x": np.arange(16.0)})
         df2 = par.map_blocks(lambda x: {"z": x * 2.0}, df, mesh=mesh)
         assert [r.z for r in df2.collect()] == [2.0 * i for i in range(16)]
 
-    @requires_shard_map
     def test_remainder_tail(self, mesh):
         df = tft.TensorFrame.from_columns({"x": np.arange(19.0)})
         df2 = par.map_blocks(lambda x: {"z": x + 1.0}, df, mesh=mesh)
@@ -44,7 +40,6 @@ class TestDistributedMapBlocks:
         df2 = par.map_blocks(lambda x: {"z": -x}, df, mesh=mesh)
         assert [r.z for r in df2.collect()] == [0.0, -1.0, -2.0]
 
-    @requires_shard_map
     def test_trim(self, mesh):
         df = tft.TensorFrame.from_columns({"x": np.arange(16.0)})
         df2 = par.map_blocks(
@@ -54,7 +49,6 @@ class TestDistributedMapBlocks:
         # one row per shard
         assert len(rows) == 8
 
-    @requires_shard_map
     def test_vector_columns(self, mesh):
         df = tft.TensorFrame.from_columns(
             {"y": [[float(i), float(-i)] for i in range(8)]}
@@ -64,7 +58,6 @@ class TestDistributedMapBlocks:
 
 
 class TestDistributedReduce:
-    @requires_shard_map
     def test_reduce_blocks_sum(self, mesh):
         df = tft.TensorFrame.from_columns({"x": np.arange(16.0)})
         out = par.reduce_blocks(
@@ -72,7 +65,6 @@ class TestDistributedReduce:
         )
         assert float(out) == sum(range(16))
 
-    @requires_shard_map
     def test_reduce_blocks_vector_with_tail(self, mesh):
         df = tft.TensorFrame.from_columns(
             {"y": [[float(i), 1.0] for i in range(21)]}
@@ -82,7 +74,6 @@ class TestDistributedReduce:
         )
         np.testing.assert_allclose(out, [sum(range(21)), 21.0])
 
-    @requires_shard_map
     def test_reduce_blocks_min(self, mesh):
         df = tft.TensorFrame.from_columns(
             {"x": np.array([5.0, -2.0, 9.0, 0.5] * 4)}
@@ -92,7 +83,6 @@ class TestDistributedReduce:
         )
         assert float(out) == -2.0
 
-    @requires_shard_map
     def test_reduce_rows(self, mesh):
         df = tft.TensorFrame.from_columns({"x": np.arange(17.0)})
         out = par.reduce_rows(
@@ -100,7 +90,6 @@ class TestDistributedReduce:
         )
         assert float(out) == sum(range(17))
 
-    @requires_shard_map
     def test_matches_local_engine(self, mesh):
         rng = np.random.default_rng(3)
         data = rng.normal(size=(40, 3))
@@ -139,7 +128,6 @@ def test_mlp_params_update_invalidates_scoring_cache():
 
 
 class TestDistributedAggregate:
-    @requires_shard_map
     def test_two_phase_matches_local(self, mesh):
         rng = np.random.default_rng(0)
         n = 50
@@ -204,7 +192,6 @@ class TestDistributedMapRows:
     """Distributed row ops (VERDICT r01 gap: the reference runs every op
     through its distributed plane, ``DebugRowOps.scala:396-477``)."""
 
-    @requires_shard_map
     def test_dense_matches_local(self, mesh):
         x = np.random.default_rng(0).normal(size=(37, 3))
         df = tft.TensorFrame.from_columns({"v": x}).analyze()
@@ -214,7 +201,6 @@ class TestDistributedMapRows:
             [r.s for r in dist.collect()], [r.s for r in local.collect()]
         )
 
-    @requires_shard_map
     def test_scalar_cells_with_tail(self, mesh):
         # 19 rows over 8 devices: main=16 sharded, tail=3 local
         df = tft.TensorFrame.from_columns({"x": np.arange(19.0)})
@@ -228,7 +214,6 @@ class TestDistributedMapRows:
         expect = [float(np.sum(c)) for c in cells]
         assert [r.s for r in out.collect()] == expect
 
-    @requires_shard_map
     def test_multi_fetch_and_passthrough(self, mesh):
         df = tft.TensorFrame.from_columns(
             {"a": np.arange(16.0), "b": np.arange(16.0) * 2}
@@ -240,7 +225,6 @@ class TestDistributedMapRows:
         assert set(out.columns) == {"lo", "hi", "a", "b"}
         assert rows[3].lo == -3.0 and rows[3].hi == 9.0
 
-    @requires_shard_map
     def test_feed_dict_binding(self, mesh):
         df = tft.TensorFrame.from_columns({"col": np.arange(16.0)})
         out = par.map_rows(
@@ -259,7 +243,6 @@ class TestDistributedMapRows:
 
 
 class TestDistributedAggregateGeneralKeys:
-    @requires_shard_map
     def test_binary_key_matches_local(self, mesh):
         rng = np.random.default_rng(3)
         names = [b"a", b"bb", b"ccc", b"dddd"]
@@ -280,7 +263,6 @@ class TestDistributedAggregateGeneralKeys:
         l = sorted((r.name, round(r.x, 6)) for r in local.collect())
         assert d == l
 
-    @requires_shard_map
     def test_mixed_multi_key(self, mesh):
         rows = [
             {"s": [b"x", b"y"][i % 2], "k": np.int64(i % 3), "v": float(i)}

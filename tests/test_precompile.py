@@ -31,18 +31,97 @@ def _score(features):
     return {"out": features * 2.0 + 1.0}
 
 
-def test_cache_dir_configured_at_import():
-    # conftest leaves TFT_NO_COMPILE_CACHE unset, so the package import
-    # configured the persistent cache; jax must agree on the directory
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: a fresh interpreter imports the package with jax.config.update
+#: recorded, on request runs one program that compiles in well under
+#: jax's own 1.0 s admission floor, and reports what the cache ended up as
+_CACHE_PROBE = r"""
+import json, os, sys
+import jax
+updated = []
+_update = jax.config.update
+def recording_update(name, value):
+    updated.append(name)
+    return _update(name, value)
+jax.config.update = recording_update
+import tensorframes_tpu as tft
+import jax.numpy as jnp
+def chain(x):
+    for _ in range(48):
+        x = jnp.tanh(x @ x) + 1.0
+    return x
+if sys.argv[1:] == ["compile"]:
+    jax.block_until_ready(jax.jit(chain)(jnp.ones((16, 16))))
+d = tft.enable_compilation_cache()
+print(json.dumps({
+    "dir": d,
+    "jax_dir": jax.config.jax_compilation_cache_dir,
+    "updated": updated,
+    "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+    "min_bytes": jax.config.jax_persistent_cache_min_entry_size_bytes,
+    "entries": sorted(f for f in os.listdir(d) if f.endswith("-cache")),
+    "tune": tft.tune.store_path(),
+}))
+"""
+
+
+def _cache_probe(tmp_path, cache_env, *argv):
+    import json
+    import subprocess
+    import sys
+
+    env = {
+        k: v for k, v in os.environ.items()
+        if k not in ("JAX_COMPILATION_CACHE_DIR", "TFT_TUNE_FILE")
+    }
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=_REPO, **cache_env)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE, *argv], env=env, cwd=str(tmp_path),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_cache_placed_by_jax_env_var(tmp_path):
+    # JAX_COMPILATION_CACHE_DIR set: jax owns the directory (no
+    # jax_compilation_cache_dir update from this package), yet the two
+    # admission thresholds are still lowered, so a sub-second program is
+    # cached there, and the tuning store sits in the same directory
+    placed = str(tmp_path / "placed")
+    got = _cache_probe(
+        tmp_path, {"JAX_COMPILATION_CACHE_DIR": placed}, "compile"
+    )
+    assert got["dir"] == got["jax_dir"] == placed
+    assert "jax_compilation_cache_dir" not in got["updated"]
+    assert got["min_secs"] <= 0.1 and got["min_bytes"] == -1
+    assert got["entries"], "no cache entry for a sub-second program"
+    assert got["tune"] == os.path.join(placed, "tune.jsonl")
+
+
+def test_cache_defaults_to_one_fixed_checkout_path(tmp_path):
+    # unset: one fixed git-ignored directory inside the checkout, the
+    # same for every process whatever its cwd (two fresh ones agree).
+    # Nothing is compiled: the tests leave no CPU entries in the checkout
+    want = os.path.join(_REPO, ".jax_cache")
+    for cwd in (tmp_path, tmp_path / "elsewhere"):
+        cwd.mkdir(exist_ok=True)
+        got = _cache_probe(cwd, {})
+        assert got["dir"] == got["jax_dir"] == want
+        assert got["min_secs"] <= 0.1 and got["min_bytes"] == -1
+        assert got["tune"] == os.path.join(want, "tune.jsonl")
+
+
+def test_cache_enabled_in_the_test_process():
+    # conftest places the tests' cache outside the checkout through
+    # JAX_COMPILATION_CACHE_DIR; the package import must have honoured it
     import jax
 
     d = enable_compilation_cache()  # idempotent: returns the active dir
-    assert d is not None
+    assert d == os.environ["JAX_COMPILATION_CACHE_DIR"]
     assert jax.config.jax_compilation_cache_dir == d
-    assert os.path.isdir(d)
-    # engine thunks compile in well under jax's 1.0s default floor; the
-    # floor must be lowered or short-job warmup caches nothing
-    assert jax.config.jax_persistent_cache_min_compile_time_secs <= 0.1
+    assert not d.startswith(_REPO + os.sep)
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
 
 

@@ -774,6 +774,7 @@ class TestGenerateEndpoint:
             assert status == 200 and body["healthy"] is True
             for key in (
                 "last_step_age_s",
+                "compiling",
                 "queue_depth",
                 "active_slots",
                 "pages_in_use",

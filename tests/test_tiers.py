@@ -107,15 +107,41 @@ def _mixed_requests(seed, count, n_new=10):
     return reqs
 
 
+def _step_until(eng, pred, what, max_steps=200):
+    """Hand-step an engine that was never started until ``pred()``
+    holds: the stream stops exactly there, with no stepping thread to
+    run it past the point the test wants to act at."""
+    for _ in range(max_steps):
+        if pred():
+            return
+        eng.step()
+    pytest.fail(f"{what}: not reached in {max_steps} engine steps")
+
+
+def _drive(fleet, handles, max_rounds=500):
+    """Run a fleet that was never started, on this thread, in a fixed
+    order: one step per replica, then one router tick. A first-token
+    handoff queued by a step is migrated by the tick that follows it,
+    before the source can decode further — the threaded loops would
+    race each other, and on this tiny model generation usually wins."""
+    for _ in range(max_rounds):
+        if all(h.done for h in handles):
+            return
+        for eng in fleet.engines:
+            eng.step()
+        fleet._tick()
+    pytest.fail(f"streams unfinished after {max_rounds} rounds")
+
+
 def _run_and_check(fleet, lm, reqs):
-    """Submit every request concurrently, then assert byte-identity.
-    Starts the fleet when needed — the supervisor thread is what
-    drains the migration queues."""
-    if fleet._thread is None:
-        fleet.start()
+    """Submit every request, then assert byte-identity. An unstarted
+    fleet is hand-driven (:func:`_drive`); a started one runs on its
+    own threads."""
     handles = [
         fleet.submit(p, n, **kw) for p, n, kw in reqs
     ]
+    if fleet._thread is None:
+        _drive(fleet, handles)
     for h, (p, n, kw) in zip(handles, reqs):
         got = np.asarray(h.result(timeout=120))
         np.testing.assert_array_equal(
@@ -124,133 +150,113 @@ def _run_and_check(fleet, lm, reqs):
         )
 
 
+def _engine(lm, **kw):
+    kw.setdefault("max_slots", 4)
+    kw.setdefault("page_size", 4)
+    kw.setdefault("num_pages", 64)
+    kw.setdefault("max_seq_len", 48)
+    return GenerationEngine(lm, **kw)
+
+
+@pytest.fixture(scope="module")
+def src_engine(lm):
+    """The export side of the engine-level tests: never started, stepped
+    by hand. Every test detaches what it submitted, so the engine is
+    idle again when the next one takes it."""
+    return _engine(lm)
+
+
+@pytest.fixture(scope="module")
+def pd_fleet(lm):
+    """One prefill + one decode replica, never started (hand-driven),
+    shared by the tests that only submit through it."""
+    fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
+    yield fleet
+    fleet.stop()
+
+
 # ---------------------------------------------------------------------------
 # engine-level export / restore (no fleet in the loop)
 # ---------------------------------------------------------------------------
 
 
 class TestExportRestore:
-    def _engine(self, lm, **kw):
-        kw.setdefault("max_slots", 4)
-        kw.setdefault("page_size", 4)
-        kw.setdefault("num_pages", 64)
-        kw.setdefault("max_seq_len", 48)
-        eng = GenerationEngine(lm, **kw)
-        eng.start()
-        return eng
+    """Engines here are never started: the test steps them, so a slot
+    is exported at an exact token count."""
 
-    def test_unknown_request_returns_none(self, lm):
-        eng = self._engine(lm)
-        try:
-            assert eng.detach_slot(999_999) is None
-        finally:
-            eng.stop()
+    def _export_after(self, src, prompt, n, tokens, **kw):
+        h = src.submit(prompt, n, **kw)
+        _step_until(
+            src, lambda: len(h._tokens) >= tokens,
+            what=f"{tokens} token(s) before export",
+        )
+        snap = src.detach_slot(h.request_id)
+        assert snap is not None
+        return snap
 
-    def test_engine_to_engine_byte_identity(self, lm):
-        src = self._engine(lm)
-        dst = self._engine(lm)
-        try:
-            # warm the destination's ordinary programs so the assertion
-            # below isolates the attach itself (a cold engine would
-            # compile its decode program on the first continued step
-            # regardless of how the slot arrived)
-            dst.submit([1, 2], 2).result(timeout=60)
-            for kw in ({}, {"temperature": 0.6, "seed": 11}):
-                prompt, n = [5, 3, 7, 1], 10
-                # slow the source's decode so the request is still
-                # mid-stream when the export lands (the tiny model
-                # would otherwise finish all n tokens in milliseconds)
-                with chaos.scoped("serve.decode_step=latency:ms=25"):
-                    h = src.submit(prompt, n, **kw)
-                    _wait_for(
-                        lambda: len(h._tokens) >= 2,
-                        what="tokens before export",
-                    )
-                    snap = src.detach_slot(h.request_id)
-                assert snap is not None
-                assert snap.n_pages >= 1 and snap.nbytes > 0
-                before = dst.num_step_programs
-                h2 = dst.attach_slot(snap)
-                rest = h2.result(timeout=60)
-                got = np.asarray(list(snap.generated) + list(rest))
-                np.testing.assert_array_equal(
-                    got, _solo(lm, prompt, n, **kw), err_msg=f"kw={kw}"
-                )
-                # restore writes pages eagerly — no new step programs
-                assert dst.num_step_programs == before
-        finally:
-            src.stop()
-            dst.stop()
+    def test_unknown_request_returns_none(self, src_engine):
+        assert src_engine.detach_slot(999_999) is None
+
+    def test_engine_to_engine_byte_identity(self, lm, src_engine):
+        dst = _engine(lm)
+        # warm the destination's ordinary programs so the assertion
+        # below isolates the attach itself (a cold engine would
+        # compile its decode program on the first continued step
+        # regardless of how the slot arrived)
+        dst.submit([1, 2], 2)
+        dst.run_until_idle()
+        for kw in ({}, {"temperature": 0.6, "seed": 11}):
+            prompt, n = [5, 3, 7, 1], 10
+            snap = self._export_after(src_engine, prompt, n, 2, **kw)
+            assert snap.n_pages >= 1 and snap.nbytes > 0
+            assert len(snap.generated) < n  # exported mid-stream
+            before = dst.num_step_programs
+            h2 = dst.attach_slot(snap)
+            dst.run_until_idle()
+            rest = h2.result(timeout=60)
+            got = np.asarray(list(snap.generated) + list(rest))
+            np.testing.assert_array_equal(
+                got, _solo(lm, prompt, n, **kw), err_msg=f"kw={kw}"
+            )
+            # restore writes pages eagerly — no new step programs
+            assert dst.num_step_programs == before
 
     def test_still_prefilling_is_not_migratable(self, lm):
-        eng = self._engine(lm, prefill_chunk_tokens=4)
-        try:
-            h = eng.submit(list(range(1, 25)), 4)
-            # before the first generated token the slot must not export
-            snap = eng.detach_slot(h.request_id)
-            if snap is not None:
-                # raced past prefill: the export is then legal and the
-                # invariant is byte-identity, checked elsewhere
-                assert snap.generated
-            else:
-                assert np.asarray(h.result(timeout=60)).shape == (4,)
-        finally:
-            eng.stop()
+        eng = _engine(lm, prefill_chunk_tokens=4)
+        h = eng.submit(list(range(1, 25)), 4)
+        eng.step()  # one 4-token chunk of a 24-token prompt
+        assert not h._tokens
+        # before the first generated token the slot must not export
+        assert eng.detach_slot(h.request_id) is None
+        eng.run_until_idle()
+        assert np.asarray(h.result(timeout=60)).shape == (4,)
 
-    def test_geometry_mismatch_raises_and_leaves_dst_clean(self, lm):
-        src = self._engine(lm, page_size=4)
-        dst = self._engine(lm, page_size=8)
-        try:
-            with chaos.scoped("serve.decode_step=latency:ms=25"):
-                h = src.submit([2, 4, 6], 8)
-                _wait_for(lambda: len(h._tokens) >= 1, what="first token")
-                snap = src.detach_slot(h.request_id)
-            assert snap is not None
-            free_before = dst.pool.pages_free
-            with pytest.raises(TierMigrationError):
-                dst.attach_slot(snap)
-            assert dst.pool.pages_free == free_before
-        finally:
-            src.stop()
-            dst.stop()
+    def test_geometry_mismatch_raises_and_leaves_dst_clean(
+        self, lm, src_engine
+    ):
+        dst = _engine(lm, page_size=8)
+        snap = self._export_after(src_engine, [2, 4, 6], 8, 1)
+        free_before = dst.pool.pages_free
+        with pytest.raises(TierMigrationError):
+            dst.attach_slot(snap)
+        assert dst.pool.pages_free == free_before
 
-    def test_too_long_for_destination_raises(self, lm):
-        src = self._engine(lm, max_seq_len=48)
-        dst = self._engine(lm, max_seq_len=16)
-        try:
-            with chaos.scoped("serve.decode_step=latency:ms=25"):
-                h = src.submit(list(range(1, 13)), 20)
-                _wait_for(lambda: len(h._tokens) >= 1, what="first token")
-                snap = src.detach_slot(h.request_id)
-            assert snap is not None
-            with pytest.raises(TierMigrationError):
-                dst.attach_slot(snap)
-        finally:
-            src.stop()
-            dst.stop()
+    def test_too_long_for_destination_raises(self, lm, src_engine):
+        dst = _engine(lm, max_seq_len=16)
+        snap = self._export_after(src_engine, list(range(1, 13)), 20, 1)
+        with pytest.raises(TierMigrationError):
+            dst.attach_slot(snap)
 
-    def test_no_free_slot_raises_queue_full(self, lm):
-        src = self._engine(lm)
-        dst = self._engine(lm, max_slots=1)
-        occupant = None
-        try:
-            occupant = dst.submit([1, 2], 40)
-            _wait_for(
-                lambda: any(s is not None for s in dst.scheduler.slots),
-                what="occupant seated",
-            )
-            with chaos.scoped("serve.decode_step=latency:ms=25"):
-                h = src.submit([3, 3, 3], 8)
-                _wait_for(lambda: len(h._tokens) >= 1, what="first token")
-                snap = src.detach_slot(h.request_id)
-            assert snap is not None
-            free_before = dst.pool.pages_free
-            with pytest.raises(QueueFullError):
-                dst.attach_slot(snap)
-            assert dst.pool.pages_free == free_before
-        finally:
-            src.stop()
-            dst.stop()
+    def test_no_free_slot_raises_queue_full(self, lm, src_engine):
+        dst = _engine(lm, max_slots=1)
+        dst.submit([1, 2], 40)
+        dst.step()  # seats the occupant; unstepped, it stays seated
+        assert any(s is not None for s in dst.scheduler.slots)
+        snap = self._export_after(src_engine, [3, 3, 3], 8, 1)
+        free_before = dst.pool.pages_free
+        with pytest.raises(QueueFullError):
+            dst.attach_slot(snap)
+        assert dst.pool.pages_free == free_before
 
 
 # ---------------------------------------------------------------------------
@@ -259,22 +265,18 @@ class TestExportRestore:
 
 
 class TestHandoffByteIdentity:
-    def test_greedy_and_seeded_streams_survive_handoff(self, lm):
-        fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
-        try:
-            before = _counter_value(
-                "serve.kv_migrations_total", reason="handoff"
-            )
-            _run_and_check(fleet, lm, _mixed_requests(3, 6, n_new=12))
-            assert (
-                _counter_value("serve.kv_migrations_total", reason="handoff")
-                > before
-            )
-            # handoff restores compile nothing: both replicas stay at
-            # the fleet's usual program budget
-            assert all(n <= 2 for n in fleet.program_counts().values())
-        finally:
-            fleet.stop()
+    def test_greedy_and_seeded_streams_survive_handoff(self, lm, pd_fleet):
+        before = _counter_value(
+            "serve.kv_migrations_total", reason="handoff"
+        )
+        _run_and_check(pd_fleet, lm, _mixed_requests(3, 6, n_new=12))
+        assert (
+            _counter_value("serve.kv_migrations_total", reason="handoff")
+            > before
+        )
+        # handoff restores compile nothing: both replicas stay at
+        # the fleet's usual program budget
+        assert all(n <= 2 for n in pd_fleet.program_counts().values())
 
     @pytest.mark.parametrize("direction", ["tp1_to_tp2", "tp2_to_tp1"])
     def test_hetero_tp_handoff(self, lm_tp, direction):
@@ -338,14 +340,12 @@ class TestHandoffByteIdentity:
         still migrates byte-identically once its first token lands —
         and a request still COW-materializing simply keeps decoding
         where it is (export refuses, nothing breaks)."""
-        fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
+        fleet = _fleet(
+            lm, 2, tiers=("prefill", "decode"), prefix_cache=True
+        )
         try:
-            fleet.start()
             prompt = [4, 4, 8, 8, 2, 2, 6, 6]
-            warm = fleet.submit(prompt, 6)
-            np.testing.assert_array_equal(
-                np.asarray(warm.result(timeout=60)), _solo(lm, prompt, 6)
-            )
+            _run_and_check(fleet, lm, [(prompt, 6, {})])  # seeds the cache
             before = _counter_value(
                 "serve.kv_migrations_total", reason="handoff"
             )
@@ -395,20 +395,16 @@ class TestTierRouting:
         finally:
             fleet.stop()
 
-    def test_handoff_config_off_stays_put(self, lm, tier_knobs):
+    def test_handoff_config_off_stays_put(self, lm, pd_fleet, tier_knobs):
         set_config(tier_handoff=False)
-        fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
-        try:
-            before = _counter_value(
-                "serve.kv_migrations_total", reason="handoff"
-            )
-            _run_and_check(fleet, lm, _mixed_requests(9, 3))
-            assert (
-                _counter_value("serve.kv_migrations_total", reason="handoff")
-                == before
-            )
-        finally:
-            fleet.stop()
+        before = _counter_value(
+            "serve.kv_migrations_total", reason="handoff"
+        )
+        _run_and_check(pd_fleet, lm, _mixed_requests(9, 3))
+        assert (
+            _counter_value("serve.kv_migrations_total", reason="handoff")
+            == before
+        )
 
     def test_no_decode_capacity_keeps_decoding_on_prefill(self, lm):
         # every replica is prefill: the handoff finds no destination
@@ -625,44 +621,30 @@ class TestRebalance:
 
 
 class TestMigrationChaos:
-    def test_fatal_export_aborts_and_stream_continues(self, lm):
-        fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
-        try:
-            ab0 = _counter_value(
-                "serve.kv_migrations_total", reason="aborted"
-            )
-            ok0 = _counter_value(
-                "serve.kv_migrations_total", reason="handoff"
-            )
-            with chaos.scoped("tier.handoff=fatal"):
-                _run_and_check(fleet, lm, _mixed_requests(19, 4))
-            assert (
-                _counter_value("serve.kv_migrations_total", reason="aborted")
-                > ab0
-            )
-            assert (
-                _counter_value("serve.kv_migrations_total", reason="handoff")
-                == ok0
-            )
-        finally:
-            fleet.stop()
+    def test_fatal_export_aborts_and_stream_continues(self, lm, pd_fleet):
+        ab0 = _counter_value("serve.kv_migrations_total", reason="aborted")
+        ok0 = _counter_value("serve.kv_migrations_total", reason="handoff")
+        with chaos.scoped("tier.handoff=fatal"):
+            _run_and_check(pd_fleet, lm, _mixed_requests(19, 4))
+        assert (
+            _counter_value("serve.kv_migrations_total", reason="aborted")
+            > ab0
+        )
+        assert (
+            _counter_value("serve.kv_migrations_total", reason="handoff")
+            == ok0
+        )
 
     def test_transient_migrate_fault_retries_through(
-        self, lm, fast_retries
+        self, lm, pd_fleet, fast_retries
     ):
-        fleet = _fleet(lm, 2, tiers=("prefill", "decode"))
-        try:
-            ok0 = _counter_value(
-                "serve.kv_migrations_total", reason="handoff"
-            )
-            with chaos.scoped("fleet.migrate=transient:every=2"):
-                _run_and_check(fleet, lm, _mixed_requests(23, 4, n_new=12))
-            assert (
-                _counter_value("serve.kv_migrations_total", reason="handoff")
-                > ok0
-            )
-        finally:
-            fleet.stop()
+        ok0 = _counter_value("serve.kv_migrations_total", reason="handoff")
+        with chaos.scoped("fleet.migrate=transient:every=2"):
+            _run_and_check(pd_fleet, lm, _mixed_requests(23, 4, n_new=12))
+        assert (
+            _counter_value("serve.kv_migrations_total", reason="handoff")
+            > ok0
+        )
 
 
 # ---------------------------------------------------------------------------

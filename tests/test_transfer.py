@@ -222,7 +222,7 @@ class TestWireCast:
 
 @pytest.mark.chaos
 class TestTransferChaos:
-    """Transient tunnel faults during chunked transfers retry per chunk
+    """Transient link faults during chunked transfers retry per chunk
     and the landed bytes stay identical — the no-retry ingest kill of
     the monolithic era is gone."""
 

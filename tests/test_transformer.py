@@ -15,7 +15,6 @@ from tensorframes_tpu.models import (
 )
 from tensorframes_tpu.parallel import make_mesh
 
-from _gates import requires_shard_map
 
 VOCAB = 50
 
@@ -45,7 +44,6 @@ def test_flash_matches_reference(params, tokens):
     np.testing.assert_allclose(fl, ref, rtol=2e-4, atol=2e-4)
 
 
-@requires_shard_map
 @pytest.mark.slow
 def test_ring_matches_reference(params, tokens):
     mesh = make_mesh({"sp": 4})
@@ -91,7 +89,6 @@ class TestFitShardedDpSp:
     """dp x sp composition in ONE train step: batch-sharded ring attention
     plus GSPMD gradient all-reduce."""
 
-    @requires_shard_map
     def test_losses_match_single_device_fit(self):
         from tensorframes_tpu.parallel import make_mesh
 
@@ -117,7 +114,6 @@ class TestFitShardedDpSp:
         with pytest.raises(ValueError, match="sp"):
             lm.fit_sharded(toks, mesh, steps=1)
 
-    @requires_shard_map
     def test_ulysses_losses_match_single_device_fit(self):
         # ulysses trains through the flash kernel's custom VJP: the two
         # all_to_all transposes and the pallas backward compose under
@@ -557,7 +553,6 @@ class TestMoETransformer:
         losses = lm.fit(toks, steps=6, lr=0.2)
         assert losses[-1] < losses[0]
 
-    @requires_shard_map
     def test_ep_sharded_matches_local(self):
         from tensorframes_tpu.parallel import make_mesh
 
@@ -688,7 +683,6 @@ class TestGQA:
                 0, 16, d_model=32, n_heads=8, max_len=8, n_kv_heads=3
             )
 
-    @requires_shard_map
     def test_gqa_through_ring_and_ulysses(self):
         rng = np.random.default_rng(5)
         lm = TransformerLM.init(
