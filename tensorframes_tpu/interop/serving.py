@@ -1043,8 +1043,9 @@ class ScoringServer:
     def _timing_payload(handle, total_s: float) -> Dict[str, Any]:
         """The per-request timing breakdown echoed in the generate
         response: endpoint wall clock plus whatever stages the engine
-        recorded on the handle (queue wait, prefill, chunked-prefill
-        dispatches, summed decode gaps, fleet replays)."""
+        recorded on the handle (queue wait and what it waited on,
+        prefill, chunked-prefill dispatches, summed decode gaps, what a
+        preemption recomputed, fleet replays)."""
         t = dict(handle.timings) if handle is not None else {}
         out: Dict[str, Any] = {"total_s": round(total_s, 6)}
         # the speculative keys (draft/verify/rollback walls + the
@@ -1054,12 +1055,19 @@ class ScoringServer:
         for k in (
             "queue_wait_s", "prefill_s", "decode_s",
             "draft_s", "verify_s", "rollback_s",
+            "wait_slots_s", "wait_pages_s", "requeue_wait_s",
         ):
             if k in t:
                 out[k] = round(float(t[k]), 6)
         out["prefill_chunks"] = int(t.get("prefill_chunks", 0))
         out["replays"] = int(t.get("replays", 0))
-        for k in ("spec_proposed", "spec_accepted", "spec_rolled_back"):
+        # why it waited and what preemption cost it (the scheduler's
+        # stamps): a request that never waited or was never preempted
+        # carries no such key
+        for k in (
+            "spec_proposed", "spec_accepted", "spec_rolled_back",
+            "preemptions", "prefill_tokens", "recomputed_tokens",
+        ):
             if k in t:
                 out[k] = int(t[k])
         # per-request cost attribution (obs/requests.py): what this

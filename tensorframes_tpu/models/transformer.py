@@ -439,30 +439,36 @@ def transformer_step(params, tok, positions, attend, moe_top_k: int = 1):
     hd = d_model // n_heads
     bsz = tok.shape[0]
     h = embed[tok] + posemb[positions]
+    # the named scopes (here and in transformer_prefill) put the layer
+    # part into every device operation's name, so a profiler view reads
+    # attn / mlp / head instead of fusion.123; they change no operation
     for li, block in enumerate(params["blocks"]):
         n_kv = _kv_heads(block, d_model, n_heads)
         group = n_heads // n_kv
         kv_d = n_kv * hd
-        x = _ln(h, block["ln1"])
-        qkv = x @ jnp.asarray(block["qkv"])
-        q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-        att = attend(
-            li,
-            q.reshape(bsz, n_kv, group, hd),
-            k.reshape(bsz, n_kv, hd),
-            v.reshape(bsz, n_kv, hd),
-        )
-        h = h + att @ jnp.asarray(block["proj"])
-        hx = _ln(h, block["ln2"])
-        if "moe" in block:
-            h = h + moe_ffn(block["moe"], hx[:, None, :], k=moe_top_k)[
-                :, 0
-            ]
-        else:
-            h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
-                jnp.asarray(block["down"])
+        with jax.named_scope("attn"):
+            x = _ln(h, block["ln1"])
+            qkv = x @ jnp.asarray(block["qkv"])
+            q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
+            att = attend(
+                li,
+                q.reshape(bsz, n_kv, group, hd),
+                k.reshape(bsz, n_kv, hd),
+                v.reshape(bsz, n_kv, hd),
             )
-    return _ln(h, params["ln_f"]) @ embed.T
+            h = h + att @ jnp.asarray(block["proj"])
+        with jax.named_scope("mlp"):
+            hx = _ln(h, block["ln2"])
+            if "moe" in block:
+                h = h + moe_ffn(
+                    block["moe"], hx[:, None, :], k=moe_top_k
+                )[:, 0]
+            else:
+                h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
+                    jnp.asarray(block["down"])
+                )
+    with jax.named_scope("head"):
+        return _ln(h, params["ln_f"]) @ embed.T
 
 
 def transformer_prefill(params, tokens, moe_top_k: int = 1):
@@ -498,28 +504,35 @@ def transformer_prefill(params, tokens, moe_top_k: int = 1):
         n_kv = _kv_heads(block, d_model, n_heads)
         group = n_heads // n_kv
         kv_d = n_kv * hd
-        x = _ln(h, block["ln1"])
-        qkv = x @ jnp.asarray(block["qkv"])
-        q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-        # cache layout [B, n_kv, P, hd] — what the decode step reads
-        kc = k.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
-        vc = v.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
-        ks.append(kc)
-        vs.append(vc)
-        qh = q.reshape(bsz, plen, n_kv, group, hd).transpose(0, 2, 3, 1, 4)
-        s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) * scale
-        s = jnp.where(causal[None, None, None], s, neg)
-        att = jnp.einsum("bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc)
-        att = att.transpose(0, 3, 1, 2, 4).reshape(bsz, plen, d_model)
-        h = h + att @ jnp.asarray(block["proj"])
-        hx = _ln(h, block["ln2"])
-        if "moe" in block:
-            h = h + moe_ffn(block["moe"], hx, k=moe_top_k)
-        else:
-            h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
-                jnp.asarray(block["down"])
+        with jax.named_scope("attn"):
+            x = _ln(h, block["ln1"])
+            qkv = x @ jnp.asarray(block["qkv"])
+            q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
+            # cache layout [B, n_kv, P, hd] — what the decode step reads
+            kc = k.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
+            vc = v.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
+            ks.append(kc)
+            vs.append(vc)
+            qh = q.reshape(bsz, plen, n_kv, group, hd).transpose(
+                0, 2, 3, 1, 4
             )
-    logits = _ln(h, params["ln_f"]) @ embed.T
+            s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) * scale
+            s = jnp.where(causal[None, None, None], s, neg)
+            att = jnp.einsum(
+                "bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc
+            )
+            att = att.transpose(0, 3, 1, 2, 4).reshape(bsz, plen, d_model)
+            h = h + att @ jnp.asarray(block["proj"])
+        with jax.named_scope("mlp"):
+            hx = _ln(h, block["ln2"])
+            if "moe" in block:
+                h = h + moe_ffn(block["moe"], hx, k=moe_top_k)
+            else:
+                h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
+                    jnp.asarray(block["down"])
+                )
+    with jax.named_scope("head"):
+        logits = _ln(h, params["ln_f"]) @ embed.T
     return logits, jnp.stack(ks), jnp.stack(vs)
 
 

@@ -29,6 +29,7 @@ from .metrics import (
 )
 from .tracing import (
     Span,
+    SpanChain,
     TraceContext,
     current_span,
     current_trace,
@@ -63,6 +64,7 @@ __all__ = [
     "render_prometheus",
     "enabled",
     "Span",
+    "SpanChain",
     "TraceContext",
     "span",
     "event",

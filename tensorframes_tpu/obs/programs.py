@@ -12,7 +12,10 @@ thrown away. This registry keeps them:
 
 - every instrumented program registers ONE :class:`ProgramRecord` at
   build time: **compile wall-time** (the first dispatch, which pays
-  trace + XLA compile), **FLOP / byte estimates** — XLA's own
+  trace + XLA compile) and its **load split** — Python tracing, lowering
+  to MLIR, and then either the backend compile or the read from the
+  persistent compilation cache, from jax's own monitoring events
+  (:func:`_on_jax_duration`); **FLOP / byte estimates** — XLA's own
   ``Lowered.cost_analysis()`` where available, with a jaxpr-walking
   fallback (:func:`jaxpr_costs`) — and a free-form ``meta`` of
   shape/dtype features;
@@ -85,9 +88,11 @@ class ProgramRecord:
     """One compiled program's ledger entry."""
 
     __slots__ = (
-        "key", "name", "kind", "created_ts", "compile_s", "flops",
-        "bytes_accessed", "cost_source", "invocations", "dispatches",
-        "dispatch_s", "last_dispatch_ts", "meta", "_lock", "_persisted_inv",
+        "key", "name", "kind", "created_ts", "compile_s", "trace_s",
+        "lower_s", "backend_compile_s", "cache_load_s", "cache_hit",
+        "flops", "bytes_accessed", "cost_source", "invocations",
+        "dispatches", "dispatch_s", "last_dispatch_ts", "meta", "_lock",
+        "_persisted_inv",
     )
 
     def __init__(self, key: str, name: str, kind: str, **meta):
@@ -96,6 +101,18 @@ class ProgramRecord:
         self.kind = kind
         self.created_ts = time.time()
         self.compile_s: Optional[float] = None
+        #: where ``compile_s`` went, from jax's own events while this
+        #: program's call ran (:func:`_on_jax_duration`): Python tracing
+        #: to a jaxpr and lowering to MLIR — paid on a cache hit too —
+        #: then the backend stage, booked as ``backend_compile_s`` when
+        #: XLA compiled and as ``cache_load_s`` when the persistent
+        #: cache had the executable. ``None`` until jax reports one.
+        self.trace_s: Optional[float] = None
+        self.lower_s: Optional[float] = None
+        self.backend_compile_s: Optional[float] = None
+        self.cache_load_s: Optional[float] = None
+        #: whether the newest backend stage was a persistent-cache hit
+        self.cache_hit: Optional[bool] = None
         self.flops: Optional[float] = None
         self.bytes_accessed: Optional[float] = None
         self.cost_source: Optional[str] = None  # "xla" | "jaxpr"
@@ -120,6 +137,16 @@ class ProgramRecord:
                 self.compile_s = seconds
             else:  # a second signature recompiled under the same record
                 self.compile_s += seconds
+
+    def note_load(self, field: str, seconds: float) -> None:
+        """Add one compile-stage duration to ``field`` (``trace_s``,
+        ``lower_s``, ``backend_compile_s`` or ``cache_load_s``)."""
+        with self._lock:
+            setattr(self, field, (getattr(self, field) or 0.0) + seconds)
+            if field == "backend_compile_s":
+                self.cache_hit = False
+            elif field == "cache_load_s":
+                self.cache_hit = True
 
     def add_dispatch(self, seconds: float) -> None:
         with self._lock:
@@ -146,6 +173,11 @@ class ProgramRecord:
                 "name": self.name,
                 "kind": self.kind,
                 "compile_s": _round(self.compile_s),
+                "trace_s": _round(self.trace_s),
+                "lower_s": _round(self.lower_s),
+                "backend_compile_s": _round(self.backend_compile_s),
+                "cache_load_s": _round(self.cache_load_s),
+                "cache_hit": self.cache_hit,
                 "flops": self.flops,
                 "bytes": self.bytes_accessed,
                 "cost_source": self.cost_source,
@@ -222,7 +254,13 @@ def render_table() -> str:
         lines.append(
             f" {r['name']} [{r['kind']}] "
             f"compile={_fmt_s(r['compile_s'])} "
-            f"flops={_fmt_num(r['flops'])} "
+            f"(trace={_fmt_s(r['trace_s'])} lower={_fmt_s(r['lower_s'])} "
+            + (
+                f"cache_load={_fmt_s(r['cache_load_s'])}) "
+                if r["cache_hit"]
+                else f"backend={_fmt_s(r['backend_compile_s'])}) "
+            )
+            + f"flops={_fmt_num(r['flops'])} "
             f"bytes={_fmt_num(r['bytes'])} "
             f"inv={r['invocations']} "
             f"dispatch={_fmt_s(r['dispatch_s'])} "
@@ -463,6 +501,70 @@ def peak_bytes_per_s() -> Optional[float]:
 # the dispatch wrapper
 # ---------------------------------------------------------------------------
 
+#: jax's compile-stage events (``jax/_src/dispatch.py``,
+#: ``jax/_src/compiler.py``)
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: ``loading``: the record whose instrumented call is running on this
+#: thread (jax compiles on the calling thread and fires its events
+#: there); ``trace_s``: the longest trace reported since the last stage
+#: was booked; ``cache_hit``: the backend stage in progress found its
+#: executable in the persistent cache
+_tls = threading.local()
+_listening = False
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    """The one ``jax.monitoring`` listener: book a compile-stage duration
+    to the program whose call is running on this thread. Stages of any
+    other compile (an uninstrumented jit, a cost estimate's re-lowering)
+    find no record and are dropped.
+
+    Tracing reports once per jitted function, the callees inside the
+    program (``softmax``, ``where``) before the program itself, whose
+    wall holds theirs: the longest report is the program's. The backend
+    event wraps the persistent-cache lookup, so on a hit it is the cache
+    read that it timed."""
+    rec = getattr(_tls, "loading", None)
+    if rec is None:
+        return
+    if event == _TRACE_EVENT:
+        _tls.trace_s = max(getattr(_tls, "trace_s", 0.0), float(duration))
+    elif event == _LOWER_EVENT:
+        _book_trace(rec)
+        rec.note_load("lower_s", float(duration))
+    elif event == _CACHE_READ_EVENT:
+        # fires inside the backend stage, before that stage's own event
+        _tls.cache_hit = True
+    elif event == _BACKEND_EVENT:
+        hit, _tls.cache_hit = getattr(_tls, "cache_hit", False), False
+        rec.note_load(
+            "cache_load_s" if hit else "backend_compile_s", float(duration)
+        )
+
+
+def _book_trace(rec: "ProgramRecord") -> None:
+    traced, _tls.trace_s = getattr(_tls, "trace_s", 0.0), 0.0
+    if traced:
+        rec.note_load("trace_s", traced)
+
+
+def _listen() -> None:
+    """Register :func:`_on_jax_duration` once per process, at the first
+    enabled instrumented call (never at import, never under the kill
+    switch)."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
 
 class InstrumentedProgram:
     """Transparent callable around a jitted program: the first enabled
@@ -504,12 +606,19 @@ class InstrumentedProgram:
             rec = self.record = program(
                 self._key, self._name, self._kind, **self._meta
             )
+        if not _listening:
+            _listen()
+        _tls.loading = rec
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        if self._sync:
-            import jax
+        try:
+            out = self._fn(*args, **kwargs)
+            if self._sync:
+                import jax
 
-            out = jax.block_until_ready(out)
+                out = jax.block_until_ready(out)
+        finally:
+            _tls.loading = None
+            _book_trace(rec)  # a trace that no lowering followed
         dt = time.perf_counter() - t0
         try:
             size = self._fn._cache_size()

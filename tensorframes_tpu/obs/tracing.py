@@ -34,13 +34,17 @@ environment variable. Event schema (stable; documented in
 
     {"name": str, "trace_id": "32hex", "span_id": "16hex",
      "parent_id": "16hex" | null, "depth": int,
-     "ts": float epoch-seconds at entry, "dur_s": float wall,
+     "ts": float epoch-seconds at entry,
+     "t_mono": float time.monotonic() at entry, "dur_s": float wall,
      "dur_synced_s": float (only when a sync tree was attached),
      "thread": str, "attrs": {str: json-value}}
 
 Events are written when a span CLOSES, so children appear before their
 parents — consumers reconstruct the tree from ``parent_id`` and group
-requests by ``trace_id``. :func:`event` emits a point event (``dur_s``
+requests by ``trace_id``. ``t_mono`` is the system-wide monotonic clock
+at entry — the clock load generators and benchmarks stamp, so a span
+lies beside their times without a conversion (``ts`` stays for readers
+that want wall-clock time). :func:`event` emits a point event (``dur_s``
 0, written immediately) — the record a crash cannot destroy, used by the
 distributed-job lease claims so a kill -9'd worker's claim is still in
 the trace.
@@ -71,6 +75,7 @@ from .metrics import enabled
 
 __all__ = [
     "Span",
+    "SpanChain",
     "TraceContext",
     "current_span",
     "current_trace",
@@ -319,7 +324,8 @@ def set_trace_sink(sink, max_bytes: Optional[int] = None) -> None:
     ``TFT_TRACE_FILE_MAX_BYTES``; ``<= 0`` disables rotation), a
     file-like object (used as-is, not closed, never rotated), or
     ``None`` to disable. Replacing a path-opened sink closes it."""
-    global _sink, _sink_owned
+    global _sink, _sink_owned, _consumers_gen
+    _consumers_gen += 1
     with _sink_lock:
         if _sink_owned and _sink is not None:
             try:
@@ -340,6 +346,34 @@ def trace_sink():
     return _sink
 
 
+#: bumped whenever a span consumer attaches or detaches (sink,
+#: annotations, flight capture): a :class:`SpanChain` link made under
+#: another generation may have a consumer-less stretch behind it, over
+#: which ``span()`` ran nothing and the chain stood still
+_consumers_gen = 0
+
+
+class SpanChain:
+    """Back-to-back leaf spans over one thread's loop: a span opened with
+    ``span(name, chain=c)`` begins where the last span on ``c`` ended, so
+    the walls of consecutive phases add up to the wall of the stretch
+    they cover — the span machinery between two ``with`` blocks (closing
+    one event, opening the next) and whatever else ran there is inside
+    the phase that follows, not in a hole between two spans. Only the
+    sink event is backdated; a ``TraceAnnotation`` starts when entered.
+    The owner calls :meth:`reset` where the loop was not running (a
+    caller's pause between two steps)."""
+
+    __slots__ = ("t", "gen")
+
+    def __init__(self):
+        self.t: Optional[float] = None  # perf_counter at the last end
+        self.gen = -1
+
+    def reset(self) -> None:
+        self.t = None
+
+
 class Span:
     """One live span (its own context manager — the generator-based
     ``contextlib`` route costs ~2 µs per use, real money at engine-dispatch
@@ -348,10 +382,10 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "depth", "attrs",
-        "sync", "ts", "_t0", "_ann",
+        "sync", "ts", "t_mono", "_t0", "_ann", "_chain",
     )
 
-    def __init__(self, name, sync, attrs):
+    def __init__(self, name, sync, attrs, chain=None):
         self.name = name
         self.trace_id: Optional[str] = None  # resolved at __enter__
         self.span_id = _new_span_id()
@@ -360,8 +394,10 @@ class Span:
         self.attrs: Dict[str, Any] = attrs
         self.sync = sync
         self.ts = 0.0
+        self.t_mono = 0.0
         self._t0 = 0.0
         self._ann = None
+        self._chain: Optional[SpanChain] = chain
 
     def __enter__(self) -> "Span":
         stack = getattr(_tls, "stack", None)
@@ -386,11 +422,27 @@ class Span:
                 self._ann = ann_cls(self.name)
                 self._ann.__enter__()
         self.ts = time.time()
+        self.t_mono = time.monotonic()
         self._t0 = time.perf_counter()
+        chain = self._chain
+        if (
+            chain is not None
+            and chain.t is not None
+            and chain.gen == _consumers_gen
+        ):
+            back = self._t0 - chain.t
+            if back > 0.0:  # begin where the chain's last span ended
+                self.ts -= back
+                self.t_mono -= back
+                self._t0 = chain.t
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        wall = time.perf_counter() - self._t0
+        end = time.perf_counter()
+        wall = end - self._t0
+        if self._chain is not None:
+            self._chain.t = end
+            self._chain.gen = _consumers_gen
         synced = None
         if self.sync is not None:
             try:
@@ -422,8 +474,9 @@ _flight_spans_on = False
 
 
 def _set_flight_capture(on: bool) -> None:
-    global _flight_spans_on
+    global _flight_spans_on, _consumers_gen
     _flight_spans_on = bool(on)
+    _consumers_gen += 1
 
 
 def _emit(s: Span, wall: float, synced: Optional[float]) -> None:
@@ -440,6 +493,7 @@ def _emit(s: Span, wall: float, synced: Optional[float]) -> None:
         "parent_id": s.parent_id,
         "depth": s.depth,
         "ts": s.ts,
+        "t_mono": s.t_mono,
         "dur_s": wall,
         "thread": threading.current_thread().name,
         "attrs": s.attrs,
@@ -493,6 +547,7 @@ def event(name: str, **attrs) -> Optional[TraceContext]:
                 "parent_id": parent_id,
                 "depth": 0,
                 "ts": time.time(),
+                "t_mono": time.monotonic(),
                 "dur_s": 0.0,
                 "thread": threading.current_thread().name,
                 "attrs": attrs,
@@ -516,8 +571,9 @@ _annotations_on = False
 def set_annotations(on: bool) -> None:
     """Enable/disable TraceAnnotation forwarding for spans (normally
     managed by ``utils.profiling.trace()``)."""
-    global _annotations_on
+    global _annotations_on, _consumers_gen
     _annotations_on = bool(on)
+    _consumers_gen += 1
 
 
 def _annotation_cls():
@@ -550,7 +606,7 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
-def span(name: str, sync=None, **attrs):
+def span(name: str, sync=None, chain: Optional[SpanChain] = None, **attrs):
     """Open a nested span::
 
         with span("engine.map_blocks", partitions=4) as sp:
@@ -571,12 +627,16 @@ def span(name: str, sync=None, **attrs):
     allocation + clock reads). Consumers attach by setting a sink /
     opening ``utils.profiling.trace()`` / enabling
     ``flight.capture_spans`` BEFORE the work they want to see.
+
+    ``chain`` (a :class:`SpanChain`) makes this one of a loop's
+    back-to-back phase spans: it begins where the chain's last span
+    ended.
     """
     if not enabled() or (
         _sink is None and not _annotations_on and not _flight_spans_on
     ):
         return _NULL
-    return Span(name, sync, dict(attrs))
+    return Span(name, sync, dict(attrs), chain)
 
 
 if os.environ.get("TFT_TRACE_FILE"):

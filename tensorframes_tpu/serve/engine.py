@@ -78,6 +78,7 @@ from ..models.transformer import (
     transformer_verify_chunk,
 )
 from ..obs import (
+    SpanChain as _SpanChain,
     current_trace as _current_trace,
     flight as _flight,
     programs as _programs,
@@ -213,6 +214,12 @@ _m_verify_s = _histogram(
     "Wall seconds per batched multi-token verify dispatch (the "
     "[max_slots, k+1] step program)",
 )
+_m_recomputed = _counter(
+    "serve.recomputed_tokens_total",
+    "Prompt tokens put through a prefill program a second time because "
+    "a preemption had released the pages that held them (the cost of "
+    "failures.preemptions_total's events)",
+)
 _m_collective_s = _counter(
     "serve.collective_seconds",
     "ESTIMATED wall seconds spent in cross-chip collectives by the "
@@ -280,12 +287,14 @@ def _span_attend(state, ptabs, pos, pos_c, counts, ps, trash, mp,
             trash,
         )
         off = pos_c % ps
-        state[0] = state[0].at[li, page, off].set(k)
-        state[1] = state[1].at[li, page, off].set(v)
+        with jax.named_scope("kv_write"):
+            state[0] = state[0].at[li, page, off].set(k)
+            state[1] = state[1].at[li, page, off].set(v)
         n_kv, hd = k.shape[2], k.shape[3]
         t = mp * ps
-        kg = state[0][li][ptabs].reshape(slots, t, n_kv, hd)
-        vg = state[1][li][ptabs].reshape(slots, t, n_kv, hd)
+        with jax.named_scope("kv_read"):
+            kg = state[0][li][ptabs].reshape(slots, t, n_kv, hd)
+            vg = state[1][li][ptabs].reshape(slots, t, n_kv, hd)
         scale = 1.0 / float(np.sqrt(hd))
         s = jnp.einsum("sckgd,stkd->sckgt", q, kg) * scale
         visible = jnp.arange(t)[None, None, :] <= pos_c[:, :, None]
@@ -806,6 +815,14 @@ class GenerationEngine:
         #: replica kill lands at a step boundary instead of racing a
         #: step in progress
         self._poison: Optional[BaseException] = None
+        #: ``time.perf_counter()`` when the last step program's wait
+        #: returned (prefill, chunk, decode, draft or verify): the next
+        #: decode dispatch reads its ``host_gap_s`` off it
+        self._wait_returned_t: Optional[float] = None
+        #: the step loop's phase spans run back to back on this chain
+        #: (each begins where the last ended), so their walls add up to
+        #: the host time between two dispatches with nothing in between
+        self._phases = _SpanChain()
         _m_pages_capacity.set(float(num_pages))
         _m_tp_degree.set(float(self.tp_degree), engine=self.name)
         #: estimated collective wall per dispatched step (0 solo): a
@@ -939,21 +956,28 @@ class GenerationEngine:
             k_all = kc[:, 0].transpose(0, 2, 1, 3)
             v_all = vc[:, 0].transpose(0, 2, 1, 3)
             pos = jnp.arange(prompt.shape[1])
-            page = jnp.where(pos < length, ptab[pos // ps], trash)
-            off = pos % ps
-            kp = kp.at[:, page, off].set(k_all)
-            vp = vp.at[:, page, off].set(v_all)
-            last = logits[0, length - 1]
-            greedy = jnp.argmax(last, axis=-1)
-            # sampled path mirrors generate: per-step key folded at the
-            # emitting position, filter_logits truncation, categorical
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), length - 1)
-            scaled = last[None] / jnp.maximum(
-                jnp.asarray(temp, jnp.float32), 1e-6
-            )
-            filt = filter_logits(scaled, top_k=top_k, top_p=top_p)
-            sampled = jax.random.categorical(key, filt, axis=-1)[0]
-            tok = jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+            with jax.named_scope("kv_write"):
+                page = jnp.where(pos < length, ptab[pos // ps], trash)
+                off = pos % ps
+                kp = kp.at[:, page, off].set(k_all)
+                vp = vp.at[:, page, off].set(v_all)
+            with jax.named_scope("sample"):
+                last = logits[0, length - 1]
+                greedy = jnp.argmax(last, axis=-1)
+                # sampled path mirrors generate: per-step key folded at
+                # the emitting position, filter_logits truncation,
+                # categorical
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(seed), length - 1
+                )
+                scaled = last[None] / jnp.maximum(
+                    jnp.asarray(temp, jnp.float32), 1e-6
+                )
+                filt = filter_logits(scaled, top_k=top_k, top_p=top_p)
+                sampled = jax.random.categorical(key, filt, axis=-1)[0]
+                tok = jnp.where(temp > 0, sampled, greedy).astype(
+                    jnp.int32
+                )
             return kp, vp, tok
 
         return prefill
@@ -995,12 +1019,14 @@ class GenerationEngine:
                 # history through the page table under the causal mask
                 page = jnp.where(offs < valid, ptab[pos_clipped // ps], trash)
                 off = pos_clipped % ps
-                state[0] = state[0].at[li, page, off].set(k[0])
-                state[1] = state[1].at[li, page, off].set(v[0])
+                with jax.named_scope("kv_write"):
+                    state[0] = state[0].at[li, page, off].set(k[0])
+                    state[1] = state[1].at[li, page, off].set(v[0])
                 n_kv, hd = k.shape[2], k.shape[3]
                 t = mp * ps
-                kg = state[0][li][ptab].reshape(t, n_kv, hd)
-                vg = state[1][li][ptab].reshape(t, n_kv, hd)
+                with jax.named_scope("kv_read"):
+                    kg = state[0][li][ptab].reshape(t, n_kv, hd)
+                    vg = state[1][li][ptab].reshape(t, n_kv, hd)
                 scale = 1.0 / float(np.sqrt(hd))
                 s = jnp.einsum("ckgd,tkd->ckgt", q[0], kg) * scale
                 visible = jnp.arange(t)[None, :] <= pos[:, None]
@@ -1055,27 +1081,36 @@ class GenerationEngine:
                 # whole visible history through the page table — via the
                 # materialized gather (reference) or the fused ragged
                 # kernel (bandwidth scales with live tokens)
-                page = ptabs[jnp.arange(slots), positions // ps]
-                off = positions % ps
-                state[0] = state[0].at[li, page, off].set(k)
-                state[1] = state[1].at[li, page, off].set(v)
+                with jax.named_scope("kv_write"):
+                    page = ptabs[jnp.arange(slots), positions // ps]
+                    off = positions % ps
+                    state[0] = state[0].at[li, page, off].set(k)
+                    state[1] = state[1].at[li, page, off].set(v)
                 read = ragged_paged_attention if fused else paged_attention
-                ctx = read(
-                    q, state[0][li], state[1][li], ptabs, positions + 1
-                )
+                with jax.named_scope("kv_read"):
+                    ctx = read(
+                        q, state[0][li], state[1][li], ptabs, positions + 1
+                    )
                 return ctx.reshape(slots, d_model)
 
             logits = transformer_step(
                 full, toks, positions, attend, moe_top_k=moe_top_k
             )
-            greedy = jnp.argmax(logits, axis=-1)
-            keys = jax.vmap(
-                lambda s, t: jax.random.fold_in(jax.random.PRNGKey(s), t)
-            )(seeds, positions)
-            scaled = logits / jnp.maximum(temps[:, None], 1e-6)
-            filt = filter_logits(scaled, top_k=top_k, top_p=top_ps[:, None])
-            sampled = jax.vmap(jax.random.categorical)(keys, filt)
-            nxt = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1)
+                keys = jax.vmap(
+                    lambda s, t: jax.random.fold_in(
+                        jax.random.PRNGKey(s), t
+                    )
+                )(seeds, positions)
+                scaled = logits / jnp.maximum(temps[:, None], 1e-6)
+                filt = filter_logits(
+                    scaled, top_k=top_k, top_p=top_ps[:, None]
+                )
+                sampled = jax.vmap(jax.random.categorical)(keys, filt)
+                nxt = jnp.where(temps > 0, sampled, greedy).astype(
+                    jnp.int32
+                )
             return state[0], state[1], nxt
 
         return decode
@@ -1401,6 +1436,12 @@ class GenerationEngine:
         whatever still escapes fails the affected requests' handles and
         re-raises for the caller (the background loop then fails the
         rest and marks the engine unhealthy)."""
+        # whatever the caller did since its last step() is no phase of
+        # this one; the engine's own loops keep the chain (_step_once)
+        self._phases.reset()
+        return self._step_once()
+
+    def _step_once(self) -> bool:
         with self._step_lock:
             try:
                 return self._step_locked()
@@ -1419,14 +1460,26 @@ class GenerationEngine:
             # the supervisor then fails all in-flight handles promptly
             self._poison = None
             raise poison
-        expired = self.scheduler.expire(time.monotonic())
-        if expired:
-            _m_deadline_expired.inc(expired)
-            _m_handles_failed.inc(expired, reason="deadline")
-            _m_requests.inc(expired, status="failed")
+        # the host phases of a step are LEAF spans, none enclosing
+        # another and none around the whole step: a profiler capture
+        # names each device-idle gap after the span that covers most of
+        # it, and a parent would win every gap
+        with _span("serve.admit", chain=self._phases) as sp:
+            expired = self.scheduler.expire(time.monotonic())
+            if expired:
+                _m_deadline_expired.inc(expired)
+                _m_handles_failed.inc(expired, reason="deadline")
+                _m_requests.inc(expired, status="failed")
+            admitted = self.scheduler.admit()
+            if sp is not None:
+                sp.attrs.update(
+                    admitted=len(admitted),
+                    queued=self.scheduler.queue_depth,
+                    blocked_on=self.scheduler.blocked_on or "none",
+                )
         prefill_err: Optional[BaseException] = None
         stepped: set = set()
-        for idx, act in self.scheduler.admit():
+        for idx, act in admitted:
             stepped.add(idx)
             err = self._try_prefill(idx, act, first=True)
             if err is not None and prefill_err is None:
@@ -1449,10 +1502,10 @@ class GenerationEngine:
             # decode, so synchronous drivers see the device error
             self._refresh_gauges()
             raise prefill_err
-        batch = self.scheduler.active
-        if batch:
-            ready: List[Tuple[int, _Active]] = []
-            for idx, act in batch:
+        ready: List[Tuple[int, _Active]] = []
+        with _span("serve.grow", chain=self._phases) as sp:
+            preempted = self.scheduler.preemptions
+            for idx, act in self.scheduler.active:
                 if self.scheduler.slots[idx] is not act:
                     continue  # preempted as a victim already
                 if not act.generated:
@@ -1463,25 +1516,31 @@ class GenerationEngine:
             ready = [
                 (i, a) for i, a in ready if self.scheduler.slots[i] is a
             ]
-            if ready:
-                try:
-                    if self.draft_len:
-                        self._spec_batch(ready)
-                    else:
-                        self._decode_batch(ready)
-                    self._consecutive_ooms = 0
-                except Exception as e:
-                    if is_oom(e) and self._recover_oom():
-                        self._refresh_gauges()
-                        return True
-                    for i, _ in ready:
-                        if self.scheduler.slots[i] is not None:
-                            self.scheduler.finish(i, error=e)
-                            _m_requests.inc(status="failed")
-                            _m_handles_failed.inc(reason=_fail_reason(e))
-                    raise
-        self._refresh_gauges()
-        return self.scheduler.has_work()
+            if sp is not None:
+                sp.attrs.update(
+                    ready=len(ready),
+                    preempted=self.scheduler.preemptions - preempted,
+                )
+        if ready:
+            try:
+                if self.draft_len:
+                    self._spec_batch(ready)
+                else:
+                    self._decode_batch(ready)
+                self._consecutive_ooms = 0
+            except Exception as e:
+                if is_oom(e) and self._recover_oom():
+                    self._refresh_gauges()
+                    return True
+                for i, _ in ready:
+                    if self.scheduler.slots[i] is not None:
+                        self.scheduler.finish(i, error=e)
+                        _m_requests.inc(status="failed")
+                        _m_handles_failed.inc(reason=_fail_reason(e))
+                raise
+        with _span("serve.bookkeeping", chain=self._phases):
+            self._refresh_gauges()
+            return self.scheduler.has_work()
 
     def _note_oom(self) -> bool:
         """One more consecutive OOM recovery attempt; False once the
@@ -1572,10 +1631,14 @@ class GenerationEngine:
         req = act.req
         plen = len(req.prompt)
         timings = req.handle.timings
+        now = time.monotonic()
+        # admit() charged the wait up to the admission; what passed
+        # since (earlier newcomers' prefills in this step) is waiting too
+        self.scheduler.charge_wait(req, now)
         if "queue_wait_s" not in timings:
             # first admission only (preemption/replay requeues keep the
             # original submitted_at, and setdefault keeps the first wait)
-            timings["queue_wait_s"] = time.monotonic() - req.submitted_at
+            timings["queue_wait_s"] = now - req.submitted_at
         if self.prefix_cache is not None:
             _m_prefix_lookups.inc()
             if act.cached_tokens > 0:
@@ -1674,19 +1737,24 @@ class GenerationEngine:
                 )
             )
 
+        recompute = max(0, min(start + valid, req.computed) - start)
         t0 = time.perf_counter()
         with _use_trace(req.trace), _span(
             "serve.prefill_chunk",
+            chain=self._phases,
             request=req.request_id,
             start=start,
             tokens=valid,
+            recompute=recompute,
         ):
             pool.k, pool.v, tok = run_with_retries(
                 dispatch,
                 what=f"serve.prefill_chunk request {req.request_id}",
             )
+            self._wait_returned_t = time.perf_counter()
         self._charge_collectives()
         timings = req.handle.timings
+        self._charge_prefill_tokens(timings, valid, recompute)
         timings["prefill_s"] = (
             timings.get("prefill_s", 0.0) + time.perf_counter() - t0
         )
@@ -1730,43 +1798,64 @@ class GenerationEngine:
                 self._prefill_jit(self._params_dev, pool.k, pool.v, *args)
             )
 
+        recompute = min(plen, req.computed)
         t0 = time.perf_counter()
         with _use_trace(req.trace), _span(
-            "serve.prefill", request=req.request_id, prompt_len=plen
-        ):
+            "serve.prefill",
+            chain=self._phases,
+            request=req.request_id,
+            prompt_len=plen,
+        ) as sp:
+            if sp is not None:
+                sp.attrs.update(
+                    padded_len=self.max_seq_len,
+                    pad_share=1.0 - plen / self.max_seq_len,
+                    recompute=recompute,
+                )
             pool.k, pool.v, tok = run_with_retries(
                 dispatch, what=f"serve.prefill request {req.request_id}"
             )
-        self._charge_collectives()
+            self._wait_returned_t = time.perf_counter()
         timings = req.handle.timings
         timings["prefill_s"] = (
-            timings.get("prefill_s", 0.0) + time.perf_counter() - t0
+            timings.get("prefill_s", 0.0) + self._wait_returned_t - t0
         )
-        self._charge_flops(timings, self._prefill_jit)
-        act.prefill_pos = plen
-        self._register_prefix(act)
-        self._emit(idx, act, int(tok))
+        with _span("serve.readback", chain=self._phases):
+            self._charge_collectives()
+            tok = int(tok)
+        with _span("serve.emit", chain=self._phases, tokens=1) as sp:
+            self._charge_prefill_tokens(timings, plen, recompute)
+            self._charge_flops(timings, self._prefill_jit)
+            act.prefill_pos = plen
+            self._register_prefix(act)
+            self._emit(idx, act, tok)
+            if sp is not None:
+                sp.attrs["finished"] = int(
+                    self.scheduler.slots[idx] is not act
+                )
 
     def _decode_batch(self, ready: List[Tuple[int, _Active]]) -> None:
         s = self.max_slots
-        toks = np.zeros(s, np.int32)
-        positions = np.zeros(s, np.int32)
-        ptabs = np.full(
-            (s, self._max_pages), self.pool.trash_page, np.int32
-        )
-        temps = np.zeros(s, np.float32)
-        seeds = np.zeros(s, np.int32)
-        top_ps = np.ones(s, np.float32)
-        for idx, act in ready:
-            toks[idx] = act.generated[-1]
-            positions[idx] = act.length - 1  # this token's write position
-            ptabs[idx] = act.seq.table(self._max_pages)
-            temps[idx] = act.req.temperature
-            seeds[idx] = act.req.seed
-            top_ps[idx] = act.req.top_p
-        args = (toks, positions, ptabs, temps, seeds, top_ps)
         pool = self.pool
-        self._record_program("decode", self._params_dev, pool.k, *args)
+        with _span("serve.decode_args", chain=self._phases):
+            toks = np.zeros(s, np.int32)
+            positions = np.zeros(s, np.int32)
+            ptabs = np.full(
+                (s, self._max_pages), pool.trash_page, np.int32
+            )
+            temps = np.zeros(s, np.float32)
+            seeds = np.zeros(s, np.int32)
+            top_ps = np.ones(s, np.float32)
+            for idx, act in ready:
+                toks[idx] = act.generated[-1]
+                # this token's write position
+                positions[idx] = act.length - 1
+                ptabs[idx] = act.seq.table(self._max_pages)
+                temps[idx] = act.req.temperature
+                seeds[idx] = act.req.seed
+                top_ps[idx] = act.req.top_p
+            args = (toks, positions, ptabs, temps, seeds, top_ps)
+            self._record_program("decode", self._params_dev, pool.k, *args)
 
         # synced inside the retry window, like prefill (the host loop
         # needs ``nxt`` before the next step anyway, so the sync costs
@@ -1779,18 +1868,65 @@ class GenerationEngine:
                 self._decode_jit(self._params_dev, pool.k, pool.v, *args)
             )
 
-        with _span("serve.decode_step", occupancy=len(ready)):
+        with _span(
+            "serve.decode_step", chain=self._phases, occupancy=len(ready)
+        ) as sp:
+            if sp is not None:
+                self._decode_step_attrs(sp.attrs, ready)
             pool.k, pool.v, nxt = run_with_retries(
                 dispatch, what="serve.decode_step"
             )
-        self._charge_collectives()
-        nxt = np.asarray(nxt)
-        share = 1.0 / max(1, len(ready))
-        for idx, act in ready:
-            self._charge_flops(
-                act.req.handle.timings, self._decode_jit, share
+            self._wait_returned_t = time.perf_counter()
+        with _span("serve.readback", chain=self._phases):
+            self._charge_collectives()
+            nxt = np.asarray(nxt)
+        with _span(
+            "serve.emit", chain=self._phases, tokens=len(ready)
+        ) as sp:
+            share = 1.0 / max(1, len(ready))
+            for idx, act in ready:
+                self._charge_flops(
+                    act.req.handle.timings, self._decode_jit, share
+                )
+                self._emit(idx, act, int(nxt[idx]))
+            if sp is not None:
+                sp.attrs["finished"] = sum(
+                    self.scheduler.slots[i] is not a for i, a in ready
+                )
+
+    def _decode_step_attrs(
+        self, attrs: dict, ready: List[Tuple[int, _Active]]
+    ) -> None:
+        """What a live ``serve.decode_step`` span says of its batch: who
+        is in it, how long the host took since the last step program's
+        wait returned, and how many KV positions the configured read
+        touches per layer against how many are live — ``gather`` reads
+        every slot's whole page table whatever the lengths, ``fused``
+        the live pages."""
+        attrs["requests"] = [a.req.request_id for _, a in ready]
+        if self._wait_returned_t is not None:
+            attrs["host_gap_s"] = time.perf_counter() - self._wait_returned_t
+        live = sum(a.length for _, a in ready)
+        if self.attention_impl == "fused":
+            read = sum(len(a.seq.pages) for _, a in ready) * self.page_size
+        else:
+            read = self.max_slots * self._max_pages * self.page_size
+        attrs["kv_tokens_read"] = read
+        attrs["kv_tokens_live"] = live
+        attrs["kv_read_amplification"] = read / live
+
+    @staticmethod
+    def _charge_prefill_tokens(
+        timings: dict, tokens: int, recompute: int
+    ) -> None:
+        """One prefill dispatch put ``tokens`` prompt tokens through the
+        model, ``recompute`` of which a preemption had computed once."""
+        timings["prefill_tokens"] = timings.get("prefill_tokens", 0) + tokens
+        if recompute:
+            timings["recomputed_tokens"] = (
+                timings.get("recomputed_tokens", 0) + recompute
             )
-            self._emit(idx, act, int(nxt[idx]))
+            _m_recomputed.inc(recompute)
 
     # -- speculative decoding ---------------------------------------------
 
@@ -1900,10 +2036,13 @@ class GenerationEngine:
                 self._draft_jit(self._draft_dev, g.k, g.v, *args)
             )
 
-        with _span("serve.draft", occupancy=len(ready)):
+        with _span(
+            "serve.draft", chain=self._phases, occupancy=len(ready)
+        ):
             g.k, g.v, out = run_with_retries(
                 dispatch, what="serve.draft"
             )
+            self._wait_returned_t = time.perf_counter()
         # no _charge_collectives: the draft program is replicated —
         # it runs no cross-chip gathers even under a TP mesh
         for idx, act in ready:
@@ -1966,11 +2105,14 @@ class GenerationEngine:
             )
 
         t0 = time.perf_counter()
-        with _span("serve.verify", occupancy=len(ready)):
+        with _span(
+            "serve.verify", chain=self._phases, occupancy=len(ready)
+        ):
             pool.k, pool.v, u = run_with_retries(
                 dispatch, what="serve.verify"
             )
-        verify_wall = time.perf_counter() - t0
+            self._wait_returned_t = time.perf_counter()
+        verify_wall = self._wait_returned_t - t0
         _m_verify_s.observe(verify_wall)
         self._charge_collectives()
         u = np.asarray(u)
@@ -2095,6 +2237,12 @@ class GenerationEngine:
             queue_wait_s=t.get("queue_wait_s"),
             prefill_s=t.get("prefill_s"),
             decode_s=t.get("decode_s"),
+            wait_slots_s=t.get("wait_slots_s", 0.0),
+            wait_pages_s=t.get("wait_pages_s", 0.0),
+            requeue_wait_s=t.get("requeue_wait_s", 0.0),
+            preemptions=int(t.get("preemptions", 0)),
+            prefill_tokens=int(t.get("prefill_tokens", 0)),
+            recomputed_tokens=int(t.get("recomputed_tokens", 0)),
         )
 
     def _refresh_gauges(self) -> None:
@@ -2110,7 +2258,8 @@ class GenerationEngine:
     def run_until_idle(self) -> None:
         """Drive :meth:`step` until queue and slots are empty (the
         synchronous mode — tests and batch jobs)."""
-        while self.step():
+        self._phases.reset()
+        while self._step_once():
             pass
 
     def defragment(self):
@@ -2437,7 +2586,7 @@ class GenerationEngine:
         try:
             while not self._stop.is_set():
                 try:
-                    worked = self.step()
+                    worked = self._step_once()
                 except Exception as e:
                     # split, not splitlines: str(e) may be empty (bare
                     # asserts), and "".splitlines()[0] would kill the
@@ -2452,7 +2601,9 @@ class GenerationEngine:
                     self._fail_inflight(e)
                     worked = False
                 if not worked:
-                    with self.scheduler._lock:
+                    with _span(
+                        "serve.idle_wait", chain=self._phases
+                    ), self.scheduler._lock:
                         if not self.scheduler._waiting:
                             self.scheduler._lock.wait(0.02)
         except BaseException as e:  # the supervisor must never die silently

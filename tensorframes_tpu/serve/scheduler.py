@@ -77,7 +77,13 @@ class GenerationHandle:
         #: ``prefill_s`` (sum of prefill dispatch walls — replays and
         #: recompute-style preemptions accumulate), ``prefill_chunks``
         #: (chunked-prefill dispatches), ``decode_s`` (sum of
-        #: inter-emission gaps), ``replays`` (fleet failovers) — plus
+        #: inter-emission gaps), ``replays`` (fleet failovers); why and
+        #: how long it waited in the queue, first admission and every
+        #: re-admission (``wait_slots_s`` / ``wait_pages_s`` by what
+        #: stopped :meth:`Scheduler.admit`, ``requeue_wait_s`` the part
+        #: after a preemption) and what preemption cost it
+        #: (``preemptions``, ``prefill_tokens`` put through a prefill
+        #: program, ``recomputed_tokens`` of them computed before) — plus
         #: the cost-attribution keys the engine's finish hook records
         #: (``tokens``, ``kv_pages``, ``prefix_cached_tokens``,
         #: ``est_flops``, ``tenant``; ``obs/requests.py``). The
@@ -161,6 +167,18 @@ class GenRequest:
     #: 2 interactive). With the QoS plane off every request carries the
     #: default 1 and ordering degenerates to pure FIFO.
     priority: int = 1
+    #: leading prompt tokens a preemption had already computed once (the
+    #: folded-in progress, and the prompt it was prefilled with): what a
+    #: re-admitted prefill of them recomputes. 0 for a fresh request.
+    computed: int = 0
+    #: ``time.monotonic()`` up to which this request's time in the queue
+    #: is charged (:meth:`Scheduler.charge_wait`); None = ``submitted_at``
+    wait_mark: Optional[float] = None
+    #: what stopped the last :meth:`Scheduler.admit` that left this
+    #: request waiting: ``"slots"`` or ``"pages"``. A request admit never
+    #: refused waited for the step in progress to end, and the batch
+    #: admits at step boundaries only: that is charged to the slots.
+    wait_reason: str = "slots"
 
 
 class _Active:
@@ -259,6 +277,13 @@ class Scheduler:
         #: or no hook falls through to :meth:`preempt` — preemption is
         #: always the fallback, never removed.
         self.on_pressure = None
+        #: why the last :meth:`admit` left requests waiting: ``"slots"``
+        #: (every slot taken), ``"pages"`` (the pool could not supply
+        #: the head's prompt pages), None (the queue drained)
+        self.blocked_on: Optional[str] = None
+        #: preemptions so far (the engine's ``serve.grow`` span reads
+        #: the difference across one grow loop)
+        self.preemptions = 0
 
     # -- admission ---------------------------------------------------------
 
@@ -339,6 +364,9 @@ class Scheduler:
         sequences already mid-flight, see :meth:`grow`). Returns the new
         (slot, active) pairs for the engine to prefill."""
         admitted: List[Tuple[int, _Active]] = []
+        # what leaves requests waiting, if any are left at the end: the
+        # loop ran out of free slots, unless the head's pages stopped it
+        blocked: Optional[str] = "slots"
         for idx in range(self.max_slots):
             if self.slots[idx] is not None:
                 continue
@@ -391,14 +419,48 @@ class Scheduler:
                     self.pool.free([cow_src])
                 seq.release()
                 self._requeue_front(req)
+                blocked = "pages"
                 break
+            self.charge_wait(req, time.monotonic())
             act = _Active(req, seq, self._admit_counter)
             act.cached_tokens = cached
             act.cow_src = cow_src
             self._admit_counter += 1
             self.slots[idx] = act
             admitted.append((idx, act))
+        now = time.monotonic()
+        with self._lock:
+            if not self._waiting:
+                blocked = None
+            for r in self._waiting:
+                # behind a blocked head: the head's reason
+                self.charge_wait(r, now, blocked)
+        self.blocked_on = blocked
         return admitted
+
+    @staticmethod
+    def charge_wait(
+        req: GenRequest, now: float, reason: Optional[str] = None
+    ) -> None:
+        """Charge ``req``'s time in the queue since its last charge to
+        ``wait_<reason>_s`` on its handle (and to ``requeue_wait_s``
+        after a preemption). ``reason`` None keeps the reason it
+        carries: :meth:`admit` calls this for an admitted request, and
+        the engine once more when the request's prefill starts (the
+        moment ``queue_wait_s`` is taken), so that the two buckets add
+        up to at least ``queue_wait_s``."""
+        if reason is not None:
+            req.wait_reason = reason
+        mark = req.submitted_at if req.wait_mark is None else req.wait_mark
+        req.wait_mark = now
+        dt = now - mark
+        if dt <= 0.0:
+            return
+        t = req.handle.timings
+        key = "wait_pages_s" if req.wait_reason == "pages" else "wait_slots_s"
+        t[key] = t.get(key, 0.0) + dt
+        if "preemptions" in t:
+            t["requeue_wait_s"] = t.get("requeue_wait_s", 0.0) + dt
 
     def grow(self, idx: int) -> bool:
         """Reserve the page holding slot ``idx``'s next decode position,
@@ -500,6 +562,9 @@ class Scheduler:
         act.seq.release()
         self.slots[idx] = None
         req = act.req
+        t = req.handle.timings
+        t["preemptions"] = t.get("preemptions", 0) + 1
+        self.preemptions += 1
         new_req = GenRequest(
             request_id=req.request_id,
             prompt=np.concatenate(
@@ -517,6 +582,13 @@ class Scheduler:
             trace=req.trace,
             tenant=req.tenant,
             priority=req.priority,
+            # what its KV held: the prompt as far as it was prefilled,
+            # and what it had generated since
+            computed=max(
+                req.computed,
+                min(act.prefill_pos, len(req.prompt)) + len(act.generated),
+            ),
+            wait_mark=time.monotonic(),
         )
         record_preemption("serve")
         _tenancy.count_preemption(req.priority)
