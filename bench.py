@@ -1108,10 +1108,10 @@ def main_paged_attn():
         rng.normal(size=(slots, n_kv, group, hd)).astype(np.float32)
     )
     kp = jnp.asarray(
-        rng.normal(size=(pool_pages + 1, ps, n_kv, hd)).astype(np.float32)
+        rng.normal(size=(pool_pages + 1, ps, n_kv * hd)).astype(np.float32)
     )
     vp = jnp.asarray(
-        rng.normal(size=(pool_pages + 1, ps, n_kv, hd)).astype(np.float32)
+        rng.normal(size=(pool_pages + 1, ps, n_kv * hd)).astype(np.float32)
     )
     ptab = (
         np.arange(slots * mp, dtype=np.int32).reshape(slots, mp) % pool_pages

@@ -13,7 +13,10 @@ user would call, and checks what comes out by the repo's own means:
    eight ``POST /generate`` requests over the socket, then ``/metrics`` and
    ``/healthz``;
 3. kernels — the fused ragged paged-attention engine, three flash-attention
-   training steps, flash forward + grad against the reference;
+   training steps, flash forward + grad against the reference; then the KV
+   pool's layout: the decode and prefill programs compiled at 25 heads of
+   64 must hold no copy or transpose of the pool, of a layer of it or of
+   the gathered block;
 4. four chips (when the host has them) — dp frame ops, a tp=4 engine, a
    four-replica fleet, ring attention;
 5. README flow 1 on a float64 column, last, because it flips jax's x64 flag;
@@ -74,6 +77,9 @@ class Sizes:
     fit_batch, fit_len, fit_steps = 4, 1024, 3
     flash_l, flash_d, flash_heads = 2048, 128, 2
     ring_l, ring_d, ring_heads = 4096, 64, 4
+    # GPT-2 XL's widths for the pool-layout guard: 25 heads of 64
+    layout_lm = dict(d_model=1600, n_heads=25, n_layers=8, max_len=1024)
+    layout_vocab, layout_pages = 1024, 176
 
     @classmethod
     def toy(cls):
@@ -88,6 +94,8 @@ class Sizes:
         s.fit_batch, s.fit_len = 2, 128
         s.flash_l, s.flash_d = 256, 64
         s.ring_l = 512
+        s.layout_lm = dict(d_model=40, n_heads=5, n_layers=2, max_len=64)
+        s.layout_vocab, s.layout_pages = 64, 12
         return s
 
 
@@ -282,6 +290,117 @@ def phase_frame(run, S):
 
 
 # ---------------------------------------------------------------------------
+# the KV pool's layout: the step programs use the pool's buffer in place
+# ---------------------------------------------------------------------------
+
+#: opcodes that only move or retype data; a fusion made of nothing else is
+#: a relayout whatever the compiler calls it
+_MOVES = frozenset((
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+    "reshape", "copy", "transpose", "convert", "slice", "broadcast",
+))
+_INSTR = r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\((.*)$"
+
+
+def _hlo_computations(text):
+    import re
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head and " = " not in line.split("{")[0]:
+            cur = comps.setdefault(head.group(2), [])
+            if head.group(1):
+                comps["ENTRY"] = cur
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            m = re.match(_INSTR, line)
+            if m:
+                cur.append((m.group(2), m.group(1), m.group(3)))
+    return comps
+
+
+def pool_relayouts(hlo_text, counts):
+    """Instructions of an optimised HLO module's entry computation that
+    only MOVE an array whose element count is one of ``counts`` (the
+    whole pool, one layer's slice of it, the gathered block): ``copy``,
+    ``transpose``, their asynchronous forms, and fusions made of nothing
+    but moves. Returns ``[(opcode, result shape), ...]`` — empty when the
+    program reads and writes the pool in the layout it was given."""
+    import re
+
+    def elements(shape):
+        return [
+            math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", shape)
+        ]
+
+    comps = _hlo_computations(hlo_text)
+    found = []
+    for opcode, shape, rest in comps.get("ENTRY", ()):
+        if opcode == "fusion":
+            body = comps.get(
+                re.search(r"calls=%?([\w.\-]+)", rest).group(1), ()
+            )
+            if not all(op in _MOVES for op, _, _ in body):
+                continue
+        elif opcode not in ("copy", "transpose", "copy-start"):
+            continue
+        if any(n in counts for n in elements(shape)):
+            found.append((opcode, re.sub(r"\{[^}]*\}", "", shape)))
+    return found
+
+
+def phase_pool_layout(run, S):
+    """Compile the decode and prefill programs at 25 heads of 64 (the
+    widths whose (25, 64) minor dimensions once forced a page-minor pool
+    and whole-pool copies around every step) and fail if the optimised
+    program copies or transposes the pool, a layer of it or the gathered
+    block. Eight layers, so that the pool is larger than any fast memory
+    the compiler could stage it in whole."""
+    from tensorframes_tpu.models import TransformerLM
+    from tensorframes_tpu.serve import GenerationEngine
+
+    lm = TransformerLM.init(0, S.layout_vocab, **S.layout_lm)
+    eng = GenerationEngine(
+        lm, max_slots=S.max_slots, page_size=S.page_size,
+        num_pages=S.layout_pages, attention_impl="gather",
+    )
+    pool, decode_args, prefill_args = _step_specs(eng)
+    whole = math.prod(pool.shape)
+    layer = whole // pool.shape[0]
+    block = eng.max_slots * eng._max_pages * math.prod(pool.shape[2:])
+    programs = {
+        "jit_decode": (eng._decode_jit, (whole, layer, block), decode_args),
+        "jit_prefill": (eng._prefill_jit, (whole, layer), prefill_args),
+    }
+    for name, (fn, counts, args) in programs.items():
+        compiled = fn.lower(eng._params_dev, pool, pool, *args).compile()
+        mem = compiled.memory_analysis()
+        moved = pool_relayouts(compiled.as_text(), counts)
+        run.emit(
+            phase=run.phase, program=name, pool=list(pool.shape),
+            temp_bytes=getattr(mem, "temp_size_in_bytes", None),
+            alias_bytes=getattr(mem, "alias_size_in_bytes", None),
+            relayouts=moved[:8],
+        )
+        if not run.rehearsal:
+            # XLA:CPU lays arrays out by other rules; the property is
+            # the chip's
+            run.check(
+                not moved,
+                f"{name} neither copies nor transposes the pool, a layer "
+                f"of it or the gathered block",
+                found=len(moved),
+            )
+            run.check(
+                mem.alias_size_in_bytes >= 2 * whole * pool.dtype.itemsize,
+                f"{name} updates both pool arrays in place (donated)",
+            )
+
+
+# ---------------------------------------------------------------------------
 # phases 2-3a: the serving plane
 # ---------------------------------------------------------------------------
 
@@ -378,11 +497,32 @@ def _serve_over_http(run, engine, S, label):
     return tokens[:n]
 
 
-def _engine_checks(run, eng, label):
-    """The checks that read one engine after it served."""
+def _step_specs(eng):
+    """Shapes alone: the pool, and what the decode and the prefill
+    program take after it."""
     import jax
     import jax.numpy as jnp
 
+    s, mp = eng.max_slots, eng._max_pages
+    spec = jax.ShapeDtypeStruct
+    pool = spec(
+        eng.pool.k.shape, eng.pool.k.dtype, sharding=eng.pool.k.sharding
+    )
+    decode = (
+        spec((s,), jnp.int32), spec((s,), jnp.int32),
+        spec((s, mp), jnp.int32), spec((s,), jnp.float32),
+        spec((s,), jnp.int32), spec((s,), jnp.float32),
+    )
+    prefill = (
+        spec((1, eng.max_seq_len), jnp.int32), spec((), jnp.int32),
+        spec((mp,), jnp.int32), spec((), jnp.float32),
+        spec((), jnp.int32), spec((), jnp.float32),
+    )
+    return pool, decode, prefill
+
+
+def _engine_checks(run, eng, label):
+    """The checks that read one engine after it served."""
     from tensorframes_tpu.obs import programs
 
     run.check(
@@ -413,14 +553,9 @@ def _engine_checks(run, eng, label):
         },
     )
     # the decode program as the engine builds it, lowered on shapes alone
-    s, mp = eng.max_slots, eng._max_pages
-    spec = jax.ShapeDtypeStruct
-    pool = spec(eng.pool.k.shape, eng.pool.k.dtype, sharding=eng.pool.k.sharding)
+    pool, decode_args, _ = _step_specs(eng)
     text = eng._decode_jit.lower(
-        eng._params_dev, pool, pool,
-        spec((s,), jnp.int32), spec((s,), jnp.int32),
-        spec((s, mp), jnp.int32), spec((s,), jnp.float32),
-        spec((s,), jnp.int32), spec((s,), jnp.float32),
+        eng._params_dev, pool, pool, *decode_args
     ).as_text()
     run.check(
         "tf.aliasing_output" in text or "jax.buffer_donor" in text,
@@ -574,8 +709,9 @@ def phase_kernels(run, S, state):
     rng = np.random.default_rng(2)
     pages = S.max_slots * max_pages
     q = jnp.asarray(rng.standard_normal((S.max_slots, n_kv, 1, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((pages + 1, ps, n_kv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages + 1, ps, n_kv, hd)), jnp.float32)
+    # the pool's layout: heads merged into the lane axis (serve/kv_pages.py)
+    kp = jnp.asarray(rng.standard_normal((pages + 1, ps, n_kv * hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((pages + 1, ps, n_kv * hd)), jnp.float32)
     table = rng.permutation(pages).reshape(S.max_slots, max_pages).astype(np.int32)
     lengths = rng.integers(1, S.lm["max_len"] + 1, size=S.max_slots).astype(np.int32)
     lengths[0], lengths[-1] = 1, S.lm["max_len"]
@@ -923,6 +1059,7 @@ def main(argv=None):
     run.run_phase("frame", phase_frame, S)
     run.run_phase("serve", phase_serve, S, state)
     run.run_phase("kernels", phase_kernels, S, state)
+    run.run_phase("pool_layout", phase_pool_layout, S)
     if len(jax.devices()) >= 4:
         run.run_phase("four_chips.dp", phase_dp, S)
         run.run_phase("four_chips.tp", phase_tp, S, state)

@@ -471,16 +471,20 @@ def transformer_step(params, tok, positions, attend, moe_top_k: int = 1):
         return _ln(h, params["ln_f"]) @ embed.T
 
 
-def transformer_prefill(params, tokens, moe_top_k: int = 1):
-    """Batched causal prompt pass that also RETURNS the per-layer k/v in
-    the decode-cache layout: ``tokens`` [B, P] ->
-    ``(logits [B, P, vocab], k [L, B, n_kv, P, hd], v [L, B, n_kv, P, hd])``.
+def transformer_prefill(params, tokens, store, moe_top_k: int = 1):
+    """Batched causal prompt pass that hands each layer's k/v to the
+    caller: ``tokens`` [B, P] -> ``logits [B, P, vocab]``, with
+    ``store(li, k, v)`` called once per layer on that layer's ``k`` /
+    ``v`` ``[B, P, n_kv, hd]`` (the per-token rows as the projection
+    produced them).
 
     This is the prefill half of serving decode: the whole prompt runs as
     dense MXU matmuls in one pass (instead of P sequential cache steps),
-    and the caller scatters the returned k/v into its cache/page pool and
-    continues with :func:`transformer_step`. Attention uses the same
-    grouped-query einsum family as the step path."""
+    the callback writes the rows wherever the caller keeps its cache —
+    layer by layer, so no ``[L, B, P, ...]`` stack of all layers' k/v is
+    ever held — and the caller continues with :func:`transformer_step`.
+    Attention uses the same grouped-query einsum family as the step
+    path."""
     import jax
     import jax.numpy as jnp
 
@@ -499,8 +503,7 @@ def transformer_prefill(params, tokens, moe_top_k: int = 1):
         jnp.arange(plen)[:, None] >= jnp.arange(plen)[None, :]
     )  # [P(q), P(k)]
     h = embed[tokens] + posemb[:plen][None]
-    ks, vs = [], []
-    for block in params["blocks"]:
+    for li, block in enumerate(params["blocks"]):
         n_kv = _kv_heads(block, d_model, n_heads)
         group = n_heads // n_kv
         kv_d = n_kv * hd
@@ -508,11 +511,12 @@ def transformer_prefill(params, tokens, moe_top_k: int = 1):
             x = _ln(h, block["ln1"])
             qkv = x @ jnp.asarray(block["qkv"])
             q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-            # cache layout [B, n_kv, P, hd] — what the decode step reads
-            kc = k.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
-            vc = v.reshape(bsz, plen, n_kv, hd).transpose(0, 2, 1, 3)
-            ks.append(kc)
-            vs.append(vc)
+            k = k.reshape(bsz, plen, n_kv, hd)
+            v = v.reshape(bsz, plen, n_kv, hd)
+            store(li, k, v)
+            # [B, n_kv, P, hd] — what the decode step's dense twin reads
+            kc = k.transpose(0, 2, 1, 3)
+            vc = v.transpose(0, 2, 1, 3)
             qh = q.reshape(bsz, plen, n_kv, group, hd).transpose(
                 0, 2, 3, 1, 4
             )
@@ -532,8 +536,7 @@ def transformer_prefill(params, tokens, moe_top_k: int = 1):
                     jnp.asarray(block["down"])
                 )
     with jax.named_scope("head"):
-        logits = _ln(h, params["ln_f"]) @ embed.T
-    return logits, jnp.stack(ks), jnp.stack(vs)
+        return _ln(h, params["ln_f"]) @ embed.T
 
 
 def transformer_prefill_chunk(params, tokens, positions, attend,
@@ -541,8 +544,8 @@ def transformer_prefill_chunk(params, tokens, positions, attend,
     """One CHUNK of a prompt through the block walk, with attention
     delegated — the mid-sequence sibling of :func:`transformer_step`
     (single token, cache owned by the caller) and
-    :func:`transformer_prefill` (whole prompt, dense causal, cache
-    returned). Chunked prefill needs a third shape: a ``[B, C]`` span of
+    :func:`transformer_prefill` (whole prompt, dense causal, k/v
+    handed to a callback). Chunked prefill needs a third shape: a ``[B, C]`` span of
     tokens at arbitrary ``positions``, attending to cache the caller
     already holds (earlier chunks, or a shared-prefix hit) PLUS itself
     causally.

@@ -32,6 +32,7 @@ logger = get_logger("ops.attention")
 __all__ = [
     "flash_attention",
     "attention_reference",
+    "gather_pages",
     "paged_attention",
     "ragged_paged_attention",
     "paged_page_size_hint",
@@ -328,7 +329,7 @@ def attention_reference(
     )
 
 
-def _check_paged_inputs(q, k_pages, v_pages, page_table, lengths):
+def _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer):
     """Shared validation for the paged decode reads (gather and fused).
 
     The position mask is ``arange(T) < lengths`` and the gather indexes
@@ -343,22 +344,24 @@ def _check_paged_inputs(q, k_pages, v_pages, page_table, lengths):
             f"{np.shape(q)}"
         )
     slots, n_kv, _, hd = np.shape(q)
+    want_nd = 3 if layer is None else 4
     for name, arr in (("k_pages", k_pages), ("v_pages", v_pages)):
-        if np.ndim(arr) != 4:
+        if np.ndim(arr) != want_nd:
             raise ValueError(
-                f"{name} must be [pool_pages, page_size, n_kv, head_dim]; "
-                f"got shape {np.shape(arr)}"
+                f"{name} must be [pool_pages, page_size, n_kv * head_dim]"
+                f" (with a leading layer axis when layer= is given); got "
+                f"shape {np.shape(arr)} and layer={layer!r}"
             )
     if np.shape(k_pages) != np.shape(v_pages):
         raise ValueError(
             f"k_pages and v_pages must share a shape; got "
             f"{np.shape(k_pages)} vs {np.shape(v_pages)}"
         )
-    if np.shape(k_pages)[2] != n_kv or np.shape(k_pages)[3] != hd:
+    if np.shape(k_pages)[-1] != n_kv * hd:
         raise ValueError(
-            f"page pool holds (n_kv={np.shape(k_pages)[2]}, "
-            f"head_dim={np.shape(k_pages)[3]}) but q asks for "
-            f"(n_kv={n_kv}, head_dim={hd})"
+            f"page pool rows hold n_kv * head_dim = "
+            f"{np.shape(k_pages)[-1]} lanes but q asks for "
+            f"(n_kv={n_kv}, head_dim={hd}) = {n_kv * hd}"
         )
     if np.ndim(page_table) != 2 or np.shape(page_table)[0] != slots:
         raise ValueError(
@@ -380,7 +383,51 @@ def _check_paged_inputs(q, k_pages, v_pages, page_table, lengths):
             )
 
 
-def paged_attention(q, k_pages, v_pages, page_table, lengths):
+def _own_lanes(n_kv: int, hd: int):
+    """``[n_kv, n_kv * hd]`` bool: lane ``c`` of a merged k/v row belongs
+    to KV head ``c // hd``."""
+    return (
+        jnp.arange(n_kv * hd)[None, :] // hd == jnp.arange(n_kv)[:, None]
+    )
+
+
+def _heads_on_lanes(q):
+    """``q`` [S, n_kv, group, hd] -> [S, n_kv * group, n_kv * hd]: each
+    head's query laid over its KV head's lanes of a merged row, zero
+    elsewhere. A product of this with merged rows IS the per-head score
+    (the zeros add nothing), computed without ever splitting the rows'
+    ``n_kv * hd`` lanes back into heads — which on the TPU would
+    re-tile the whole gathered block (``serve/kv_pages.py``)."""
+    slots, n_kv, group, hd = q.shape
+    own = _own_lanes(n_kv, hd)[None, :, None, :]
+    wide = jnp.where(own, jnp.tile(q, (1, 1, 1, n_kv)), 0.0)
+    return wide.reshape(slots, n_kv * group, n_kv * hd)
+
+
+def _heads_off_lanes(out, n_kv: int, hd: int):
+    """The inverse read: ``out`` [S, n_kv * group, n_kv * hd] holds each
+    head's context over EVERY lane; keep the head's own ``hd`` lanes ->
+    [S, n_kv, group, hd]."""
+    slots = out.shape[0]
+    out = out.reshape(slots, n_kv, -1, n_kv, hd)
+    own = jnp.eye(n_kv, dtype=bool)[None, :, None, :, None]
+    return jnp.where(own, out, 0.0).sum(axis=3)
+
+
+def gather_pages(pages, page_table, layer=None):
+    """The pages ``page_table`` ``[..., max_pages]`` names, in position
+    order: ``[..., max_pages * page_size, n_kv * hd]`` from ``pages``
+    ``[pool_pages, page_size, n_kv * hd]``, or from layer ``layer`` of a
+    whole pool ``[n_layers, pool_pages, ...]``. The layer index rides in
+    the gather's own indices (``pages[layer][table]`` would first copy
+    the layer's slice out of the pool), and merging ``(max_pages,
+    page_size)`` is free because ``page_size`` is the sublane axis."""
+    g = pages[page_table if layer is None else (layer, page_table)]
+    lead = page_table.shape[:-1]
+    return g.reshape(lead + (g.shape[-3] * g.shape[-2], g.shape[-1]))
+
+
+def paged_attention(q, k_pages, v_pages, page_table, lengths, layer=None):
     """Single-token attention read over a PAGED KV cache — the decode-side
     gather for the serving engine (:mod:`tensorframes_tpu.serve`), where
     each sequence's keys/values live in fixed-size pages scattered through
@@ -389,41 +436,55 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths):
     ``q`` [S, n_kv, group, hd] — one query token per slot, grouped-query
     layout (``group = n_heads / n_kv``; 1-sized slot batches and MHA both
     degenerate cleanly). ``k_pages``/``v_pages`` [pool_pages, page_size,
-    n_kv, hd] — the shared page pool. ``page_table`` [S, max_pages] int32
-    — each slot's ordered page list (entries past the sequence's live
-    pages may point anywhere valid; the position mask excludes them).
-    ``lengths`` [S] int32 — valid positions per slot, INCLUDING the token
-    just written.
+    n_kv * hd] — the shared page pool in the layout
+    ``serve/kv_pages.py`` defines: a token's k (or v) is ONE row with the
+    heads merged into the lane axis, because the TPU tiles an array's two
+    minor dimensions in (8, 128) and (n_kv, hd) = (25, 64) minor would
+    pad 2.56x or force a layout every access has to convert. With
+    ``layer=li`` the arrays are the engine's whole pool ``[n_layers,
+    pool_pages, page_size, n_kv * hd]`` and the read addresses layer
+    ``li`` inside the gather (slicing the layer out first would copy it).
+    ``page_table`` [S, max_pages] int32 — each slot's ordered page list
+    (entries past the sequence's live pages may point anywhere valid; the
+    position mask excludes them). ``lengths`` [S] int32 — valid positions
+    per slot, INCLUDING the token just written.
 
     Every shape is static: the gather reads ``max_pages * page_size``
     positions per slot and masks ``t >= lengths`` to ``_NEG_BIG`` before
     the softmax (masked lanes underflow to exactly 0), so one compiled
     program serves every mix of sequence lengths and slot turnover — the
-    no-recompile property continuous batching depends on. The einsum
-    family matches the dense decode-cache read in
-    ``models.transformer.transformer_generate`` (same contraction axes,
-    same mask value), so paged and dense decode agree to float
-    associativity. Returns [S, n_kv, group, hd].
+    no-recompile property continuous batching depends on.
+
+    The products run ON the merged lane axis: the query is laid over its
+    head's lanes (:func:`_heads_on_lanes`), ``scores[s, h, t] = sum_c
+    Q[s, h, c] * K[s, t, c]`` contracts all ``n_kv * hd`` lanes, the
+    context product yields every lane for every head and each head keeps
+    its own. The extra terms are exact zeros, so per head this is the
+    sum the dense decode-cache read in
+    ``models.transformer.transformer_generate`` computes (same operands,
+    same rounding of them, same mask value) and the two agree to float
+    associativity; the gathered block is consumed in the order the
+    gather wrote it. Precision follows the ambient matmul precision,
+    like every other product of the step. Returns [S, n_kv, group, hd].
 
     This is the REFERENCE formulation: it materializes two
-    ``[S, max_pages * page_size, n_kv, hd]`` gathered copies per call, so
-    a ragged batch pays max-length bandwidth for every slot.
+    ``[S, max_pages * page_size, n_kv * hd]`` gathered copies per call,
+    so a ragged batch pays max-length bandwidth for every slot.
     :func:`ragged_paged_attention` is the fused kernel that walks the
     page table in-kernel instead; this gather stays as its oracle."""
-    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths)
+    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer)
     slots, n_kv, group, hd = q.shape
-    mp = page_table.shape[1]
-    ps = k_pages.shape[1]
-    t = mp * ps
-    # [S, max_pages, ps, n_kv, hd] -> [S, T, n_kv, hd]: pages in table
-    # order ARE position order (page i holds positions i*ps..(i+1)*ps-1)
-    kg = k_pages[page_table].reshape(slots, t, n_kv, hd)
-    vg = v_pages[page_table].reshape(slots, t, n_kv, hd)
+    # [S, T, n_kv*hd]: pages in table order ARE position order (page i
+    # holds positions i*ps..(i+1)*ps-1)
+    kg = gather_pages(k_pages, page_table, layer)
+    vg = gather_pages(v_pages, page_table, layer)
+    t = kg.shape[1]
     scale = 1.0 / float(np.sqrt(hd))
-    s = jnp.einsum("bkgd,btkd->bkgt", q, kg) * scale
+    s = jnp.einsum("bhc,btc->bht", _heads_on_lanes(q), kg) * scale
     visible = jnp.arange(t)[None, :] < lengths[:, None]  # [S, T]
-    s = jnp.where(visible[:, None, None, :], s, _NEG_BIG)
-    return jnp.einsum("bkgt,btkd->bkgd", jax.nn.softmax(s, axis=-1), vg)
+    s = jnp.where(visible[:, None, :], s, _NEG_BIG)
+    out = jnp.einsum("bht,btc->bhc", jax.nn.softmax(s, axis=-1), vg)
+    return _heads_off_lanes(out, n_kv, hd)
 
 
 def paged_page_size_hint(dtype, head_dim: int) -> int:
@@ -448,15 +509,17 @@ def _ragged_paged_kernel(
     sequential, so the VMEM scratch carries the online-softmax state
     (``online_block_update`` — the same recurrence the flash kernel and
     the ring step fold with) across a slot's pages. One grid step streams
-    ONE page — all KV heads of it, a ``[page_size, n_kv, hd]`` tile —
-    through the carry: the page table is a scalar-prefetch input, so the
-    BlockSpec index maps chase the indirection and only this slot's OWN
-    pages cross HBM->VMEM — no [slots, max_pages * page_size] gather is
-    ever materialized. The KV heads are a static loop INSIDE the step:
-    the pool keeps ``[.., n_kv, hd]`` as its trailing dims, and Mosaic
-    only accepts a block whose last two dims are (8, 128)-aligned or the
-    array's own, so a one-head block is not expressible — each head's
-    ``[page_size, hd]`` tile is a strided read of the resident page.
+    ONE page — a ``[page_size, n_kv * hd]`` slab, contiguous in the
+    pool's layout and (8, 128)-tileable as it lies — through the carry:
+    the page table is a scalar-prefetch input, so the BlockSpec index
+    maps chase the indirection and only this slot's OWN pages cross
+    HBM->VMEM — no [slots, max_pages * page_size] gather is ever
+    materialized. The heads are NOT a loop: the query block holds every
+    head laid over its KV head's lanes (``_heads_on_lanes``), so one
+    ``[n_heads, n_kv*hd] x [n_kv*hd, page_size]`` product scores all
+    heads against the page and one ``[n_heads, page_size] x [page_size,
+    n_kv*hd]`` product accumulates their contexts over every lane; the
+    wrapper keeps each head's own lanes.
 
     Pages at or past ``lengths[s]`` are skipped entirely (``pl.when``),
     so a 1-token sequence in a ragged batch does one page of work while
@@ -479,25 +542,24 @@ def _ragged_paged_kernel(
 
     length = lens_ref[si]
     base = pi * page_size
-    n_kv, group = q_ref.shape[1], q_ref.shape[2]
+    n_heads = q_ref.shape[1]
 
     def update(with_mask):
         mask = None
         if with_mask:
             pos = base + jax.lax.broadcasted_iota(
-                jnp.int32, (group, page_size), 1
+                jnp.int32, (n_heads, page_size), 1
             )
             mask = pos < length
-        for h in range(n_kv):
-            m, l, acc = online_block_update(
-                q_ref[0, h],         # [group, hd]
-                k_ref[0, :, h, :],   # [page_size, hd]
-                v_ref[0, :, h, :],
-                m_scr[h], l_scr[h], acc_scr[h], scale, mask,
-            )
-            m_scr[h] = m
-            l_scr[h] = l
-            acc_scr[h] = acc
+        m, l, acc = online_block_update(
+            q_ref[0],        # [n_heads, n_kv*hd]
+            k_ref[0, 0],     # [page_size, n_kv*hd]
+            v_ref[0, 0],
+            m_scr[...], l_scr[...], acc_scr[...], scale, mask,
+        )
+        m_scr[...] = m
+        l_scr[...] = l
+        acc_scr[...] = acc
 
     # three regimes per page, mirroring the flash kernel's causal tiles:
     # fully past the sequence (skip — the ragged win), fully visible
@@ -519,18 +581,22 @@ def _ragged_paged_kernel(
 
 
 def ragged_paged_attention(
-    q, k_pages, v_pages, page_table, lengths, interpret: Optional[bool] = None
+    q, k_pages, v_pages, page_table, lengths, layer=None,
+    interpret: Optional[bool] = None,
 ):
     """Fused single-token paged-attention read: the Pallas kernel that
     replaces :func:`paged_attention`'s gather for the serving decode step
     (Ragged Paged Attention, PAPERS.md arXiv:2604.15464).
 
     Same contract as the gather oracle — ``q`` [S, n_kv, group, hd],
-    ``k_pages``/``v_pages`` [pool_pages, page_size, n_kv, hd],
-    ``page_table`` [S, max_pages] int32, ``lengths`` [S] int32 (valid
-    positions INCLUDING the token just written) — and agrees with it to
-    float tolerance (online softmax vs one-shot softmax associativity).
-    Returns [S, n_kv, group, hd] in ``q``'s dtype.
+    ``k_pages``/``v_pages`` [pool_pages, page_size, n_kv * hd] in the
+    pool's merged-lane layout (``serve/kv_pages.py``; or the whole
+    ``[n_layers, ...]`` pool with ``layer=li``, which the index maps
+    address directly), ``page_table`` [S, max_pages] int32, ``lengths``
+    [S] int32 (valid positions INCLUDING the token just written) — and
+    agrees with it to float tolerance (online softmax vs one-shot
+    softmax associativity). Returns [S, n_kv, group, hd] in ``q``'s
+    dtype.
 
     Why it wins: the gather reads ``max_pages * page_size`` positions
     per slot regardless of the slot's real length; this kernel walks
@@ -546,10 +612,13 @@ def ragged_paged_attention(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths)
+    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer)
     slots, n_kv, group, hd = q.shape
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     mp = page_table.shape[1]
-    ps = k_pages.shape[1]
+    ps, kvd = k_pages.shape[-2:]
+    n_heads = n_kv * group
     scale = 1.0 / float(np.sqrt(hd))
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
@@ -560,10 +629,10 @@ def ragged_paged_attention(
     # the k/v maps dereference the page table, so the pipeline fetches
     # exactly the pages the table names, in table (= position) order
     q_spec = pl.BlockSpec(
-        (1, n_kv, group, hd), lambda s, p, ptab, lens: (s, 0, 0, 0)
+        (1, n_heads, kvd), lambda s, p, ptab, lens: (s, 0, 0)
     )
     kv_spec = pl.BlockSpec(
-        (1, ps, n_kv, hd), lambda s, p, ptab, lens: (ptab[s, p], 0, 0, 0)
+        (1, 1, ps, kvd), lambda s, p, ptab, lens: (layer, ptab[s, p], 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -571,20 +640,21 @@ def ragged_paged_attention(
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n_kv, group, 1), jnp.float32),
-            pltpu.VMEM((n_kv, group, 1), jnp.float32),
-            pltpu.VMEM((n_kv, group, hd), jnp.float32),
+            pltpu.VMEM((n_heads, 1), jnp.float32),
+            pltpu.VMEM((n_heads, 1), jnp.float32),
+            pltpu.VMEM((n_heads, kvd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, n_kv, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, n_heads, kvd), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(page_table, lengths, q, k_pages, v_pages)
+    )(page_table, lengths, _heads_on_lanes(q), k_pages, v_pages)
+    return _heads_off_lanes(out, n_kv, hd)
 
 
 def _flash_kernel(

@@ -104,7 +104,15 @@ from ..utils.failures import (
 )
 from ..utils.logging import get_logger
 from . import tenancy as _tenancy
-from .kv_pages import PagePool, PrefixCache, pages_needed
+from .kv_pages import (
+    PagePool,
+    PrefixCache,
+    pages_needed,
+    read_pages,
+    split_heads,
+    write_prompt,
+    write_rows,
+)
 from .scheduler import (
     GenerationHandle,
     GenRequest,
@@ -288,13 +296,13 @@ def _span_attend(state, ptabs, pos, pos_c, counts, ps, trash, mp,
         )
         off = pos_c % ps
         with jax.named_scope("kv_write"):
-            state[0] = state[0].at[li, page, off].set(k)
-            state[1] = state[1].at[li, page, off].set(v)
+            state[0] = write_rows(state[0], li, page, off, k)
+            state[1] = write_rows(state[1], li, page, off, v)
         n_kv, hd = k.shape[2], k.shape[3]
         t = mp * ps
         with jax.named_scope("kv_read"):
-            kg = state[0][li][ptabs].reshape(slots, t, n_kv, hd)
-            vg = state[1][li][ptabs].reshape(slots, t, n_kv, hd)
+            kg = split_heads(read_pages(state[0], li, ptabs), hd)
+            vg = split_heads(read_pages(state[1], li, ptabs), hd)
         scale = 1.0 / float(np.sqrt(hd))
         s = jnp.einsum("sckgd,stkd->sckgt", q, kg) * scale
         visible = jnp.arange(t)[None, None, :] <= pos_c[:, :, None]
@@ -942,25 +950,29 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        ps = self.page_size
         trash = self.pool.trash_page
         top_k = self.top_k
 
         def prefill(p, kp, vp, prompt, length, ptab, temp, seed, top_p):
             full = {**p, "n_heads": n_heads}
-            logits, kc, vc = transformer_prefill(
-                full, prompt, moe_top_k=moe_top_k
+            state = [kp, vp]
+
+            def store(li, k, v):
+                # each layer's [1, Pmax, n_kv, hd] rows go into the pool
+                # as the layer produces them; positions past the real
+                # prompt land in the trash page
+                with jax.named_scope("kv_write"):
+                    state[0] = write_prompt(
+                        state[0], li, ptab, length, k[0], trash
+                    )
+                    state[1] = write_prompt(
+                        state[1], li, ptab, length, v[0], trash
+                    )
+
+            logits = transformer_prefill(
+                full, prompt, store, moe_top_k=moe_top_k
             )
-            # [L, 1, n_kv, Pmax, hd] -> [L, Pmax, n_kv, hd]; positions
-            # past the real prompt scatter into the trash page
-            k_all = kc[:, 0].transpose(0, 2, 1, 3)
-            v_all = vc[:, 0].transpose(0, 2, 1, 3)
-            pos = jnp.arange(prompt.shape[1])
-            with jax.named_scope("kv_write"):
-                page = jnp.where(pos < length, ptab[pos // ps], trash)
-                off = pos % ps
-                kp = kp.at[:, page, off].set(k_all)
-                vp = vp.at[:, page, off].set(v_all)
+            kp, vp = state
             with jax.named_scope("sample"):
                 last = logits[0, length - 1]
                 greedy = jnp.argmax(last, axis=-1)
@@ -1020,13 +1032,13 @@ class GenerationEngine:
                 page = jnp.where(offs < valid, ptab[pos_clipped // ps], trash)
                 off = pos_clipped % ps
                 with jax.named_scope("kv_write"):
-                    state[0] = state[0].at[li, page, off].set(k[0])
-                    state[1] = state[1].at[li, page, off].set(v[0])
+                    state[0] = write_rows(state[0], li, page, off, k[0])
+                    state[1] = write_rows(state[1], li, page, off, v[0])
                 n_kv, hd = k.shape[2], k.shape[3]
                 t = mp * ps
                 with jax.named_scope("kv_read"):
-                    kg = state[0][li][ptab].reshape(t, n_kv, hd)
-                    vg = state[1][li][ptab].reshape(t, n_kv, hd)
+                    kg = split_heads(read_pages(state[0], li, ptab), hd)
+                    vg = split_heads(read_pages(state[1], li, ptab), hd)
                 scale = 1.0 / float(np.sqrt(hd))
                 s = jnp.einsum("ckgd,tkd->ckgt", q[0], kg) * scale
                 visible = jnp.arange(t)[None, :] <= pos[:, None]
@@ -1075,22 +1087,36 @@ class GenerationEngine:
             full = {**p, "n_heads": n_heads}
             slots = toks.shape[0]
             state = [kp, vp]
+            page = ptabs[jnp.arange(slots), positions // ps]
+            off = positions % ps
+
+            def write(kp, vp, li, k, v):
+                return (
+                    write_rows(kp, li, page, off, k),
+                    write_rows(vp, li, page, off, v),
+                )
+
+            def read(kp, vp, li, q):
+                # the materialized gather (reference) or the fused
+                # ragged kernel (bandwidth scales with live tokens)
+                impl = ragged_paged_attention if fused else paged_attention
+                return impl(q, kp, vp, ptabs, positions + 1, layer=li)
+
+            # the layer index is data to a scatter and a gather, so each
+            # body is traced once, not once per layer: 48 traced copies
+            # were 1.5 s of every engine's set-up (PERF.md §6, PR 26).
+            # The fused kernel's index maps need the layer static.
+            write = jax.jit(write)
+            if not fused:
+                read = jax.jit(read)
 
             def attend(li, q, k, v):
                 # write this token's k/v into its page, then read the
-                # whole visible history through the page table — via the
-                # materialized gather (reference) or the fused ragged
-                # kernel (bandwidth scales with live tokens)
+                # whole visible history through the page table
                 with jax.named_scope("kv_write"):
-                    page = ptabs[jnp.arange(slots), positions // ps]
-                    off = positions % ps
-                    state[0] = state[0].at[li, page, off].set(k)
-                    state[1] = state[1].at[li, page, off].set(v)
-                read = ragged_paged_attention if fused else paged_attention
+                    state[0], state[1] = write(state[0], state[1], li, k, v)
                 with jax.named_scope("kv_read"):
-                    ctx = read(
-                        q, state[0][li], state[1][li], ptabs, positions + 1
-                    )
+                    ctx = read(state[0], state[1], li, q)
                 return ctx.reshape(slots, d_model)
 
             logits = transformer_step(
@@ -1244,10 +1270,11 @@ class GenerationEngine:
                         trash,
                     )
                     off = posn_c % ps
-                    inner[0] = inner[0].at[li, page, off].set(k)
-                    inner[1] = inner[1].at[li, page, off].set(v)
+                    inner[0] = write_rows(inner[0], li, page, off, k)
+                    inner[1] = write_rows(inner[1], li, page, off, v)
                     read = paged_attention(
-                        q, inner[0][li], inner[1][li], ptabs, posn_c + 1
+                        q, inner[0], inner[1], ptabs, posn_c + 1,
+                        layer=li,
                     )
                     return read.reshape(slots, d_model)
 
@@ -1679,15 +1706,11 @@ class GenerationEngine:
         src = act.cow_src
         dst = act.seq.pages[act.cached_tokens // self.page_size]
         pool = self.pool
-        pool.k = pool.place(pool.k.at[:, dst].set(pool.k[:, src]))
-        pool.v = pool.place(pool.v.at[:, dst].set(pool.v[:, src]))
-        for g in pool.groups.values():
-            # the donor's draft-KV rows ride the same page indices: the
-            # clone must carry them too, or the sharer's draft would
-            # propose from a zeroed page (correctness is unaffected —
-            # verify decides — but the acceptance rate would crater)
-            g.k = g.place(g.k.at[:, dst].set(g.k[:, src]))
-            g.v = g.place(g.v.at[:, dst].set(g.v[:, src]))
+        # the donor's draft-KV rows ride the same page indices: the
+        # clone carries every group too, or the sharer's draft would
+        # propose from a zeroed page (correctness is unaffected —
+        # verify decides — but the acceptance rate would crater)
+        pool.copy_page(src, dst)
         act.cow_src = None
         pool.free([src])
 

@@ -1480,8 +1480,7 @@ class Fleet:
             )
             return
         try:
-            eng.pool.k = eng.pool.k * 0.0 + 97.0
-            eng.pool.v = eng.pool.v * 0.0 - 97.0
+            eng.pool.fill(97.0, -97.0)
         except Exception:
             pass  # the fence is the fault; corruption is the drill's color
 

@@ -9,11 +9,27 @@ The paged layout (Ragged Paged Attention / vLLM's PagedAttention, see
 PAPERS.md) decouples the two lifetimes:
 
 - **device**: one pool of ``num_pages`` fixed-size pages per layer,
-  ``[n_layers, num_pages + 1, page_size, n_kv_heads, head_dim]`` — the
+  ``[n_layers, num_pages + 1, page_size, n_kv_heads * head_dim]`` — the
   shape never changes, so the decode step compiles exactly once. The
   extra row at index ``num_pages`` is the TRASH page: writes from
   inactive slots and prompt padding land there, keeping every program
   input in-bounds without per-slot branches.
+
+  **Why heads are merged into the last axis.** The TPU stores an array
+  in tiles of 8 sublanes x 128 lanes over its two minor dimensions. A
+  pool whose minor dimensions are ``(n_kv_heads, head_dim)`` = (25, 64)
+  (GPT-2 XL) would pad to 32 x 128, 2.56x its bytes, so the runtime
+  used to pick a page-minor device layout instead and every step
+  program paid whole-pool copies to convert it and back (PERF.md §5,
+  PR 24-26). With ``(page_size, n_kv_heads * head_dim)`` = (16, 1600)
+  minor the row-major layout is the natural one (1,600 lanes pad to
+  1,664: 4 %), a token's k/v is one contiguous row, a page is one
+  contiguous slab, and the step programs read and write the pool's
+  buffer in place. The layout is one rule for every model — nothing
+  here looks at the widths — and it is written down ONCE, in
+  :func:`kv_pool_shape`, :func:`write_rows`, :func:`write_prompt`,
+  :func:`read_pages` and the page-level methods of :class:`PagePool` / :class:`PageGroup`;
+  nothing else in the package indexes ``pool.k`` / ``pool.v`` by hand.
 - **host**: a free-list allocator and per-sequence page tables
   (:class:`SequencePages`). Sequences grow one page at a time; a
   finished sequence's pages return to the pool immediately, so HBM is
@@ -39,6 +55,7 @@ never a crash.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 from collections import OrderedDict
@@ -55,7 +72,12 @@ __all__ = [
     "PagePool",
     "PrefixCache",
     "SequencePages",
+    "kv_pool_shape",
     "pages_needed",
+    "read_pages",
+    "split_heads",
+    "write_prompt",
+    "write_rows",
 ]
 
 
@@ -64,16 +86,195 @@ def pages_needed(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size))
 
 
-class PagePool:
+# -- the device layout, and the step programs' accesses to it --------------
+
+
+def kv_pool_shape(
+    n_layers: int, num_pages: int, page_size: int, n_kv_heads: int,
+    head_dim: int,
+) -> Tuple[int, int, int, int]:
+    """THE pool layout: ``[n_layers, num_pages + 1 (trash), page_size,
+    n_kv_heads * head_dim]`` (module docstring: why heads are merged
+    into the lane axis). Head ``h`` of a row is lanes
+    ``h * head_dim .. (h + 1) * head_dim - 1``, so an even split of the
+    last axis across a tensor-parallel mesh lands on head boundaries
+    whenever ``n_kv_heads`` divides."""
+    return (
+        int(n_layers), int(num_pages) + 1, int(page_size),
+        int(n_kv_heads) * int(head_dim),
+    )
+
+
+def write_rows(pages, li: int, page, off, rows):
+    """Write new k (or v) rows into layer ``li`` of a pool array, inside
+    a step program: ``page`` / ``off`` are int32 index arrays of one
+    shape ``[...]`` and ``rows`` is ``[..., n_kv, hd]`` or already
+    ``[..., n_kv * hd]``. One scatter whose updates are whole rows, on
+    the donated buffer: it touches the rows it writes, nothing else."""
+    rows = rows.reshape(page.shape + (pages.shape[-1],))
+    return pages.at[li, page, off].set(rows)
+
+
+def write_prompt(pages, li: int, page_table, length, rows, trash: int):
+    """Write one sequence's prompt rows ``[P, n_kv, hd]`` (positions
+    ``0 .. P-1``, real up to ``length``) into layer ``li`` through its
+    ``page_table`` ``[max_pages]`` — :func:`write_rows`' result, paid
+    per PAGE instead of per row: every page the prompt fills completely
+    goes in as one contiguous ``[page_size, n_kv * hd]`` slab (pages
+    past it go to the ``trash`` page), and only the one page ``length``
+    ends inside is written row by row, so positions past the real
+    prompt still land in the trash page and nowhere else. On the TPU a
+    scatter costs per update: 1,024 row updates a layer took as long as
+    the whole-pool copy they replaced, 64 slabs and 16 rows take a
+    seventh of it (PERF.md §6, PR 26).
+
+    The body is traced ONCE per program, not once per layer and array:
+    a prefill calls this 2 x ``n_layers`` times, and the layer index is
+    data to a scatter anyway, so it goes in as an operand of one inner
+    ``jit`` (96 traced copies cost 1.4 s of every engine's set-up)."""
+    return _write_prompt()(pages, li, page_table, length, rows, trash)
+
+
+@functools.lru_cache(maxsize=None)
+def _write_prompt():
+    import jax
+    import jax.numpy as jnp
+
+    def write_prompt(pages, li, page_table, length, rows, trash):
+        ps, width = pages.shape[-2:]
+        plen = rows.shape[0]
+        rows = rows.reshape(plen, width)
+        full = plen // ps
+        ends = (jnp.arange(full) + 1) * ps
+        slab_page = jnp.where(ends <= length, page_table[:full], trash)
+        pages = pages.at[li, slab_page].set(
+            rows[: full * ps].reshape(full, ps, width)
+        )
+        edge = length // ps
+        pos = edge * ps + jnp.arange(ps)
+        edge_page = jnp.where(
+            pos < length,
+            page_table[jnp.minimum(edge, page_table.shape[0] - 1)],
+            trash,
+        )
+        return write_rows(
+            pages, li, edge_page, pos % ps, rows[jnp.minimum(pos, plen - 1)]
+        )
+
+    return jax.jit(write_prompt, static_argnums=5)
+
+
+def read_pages(pages, li: int, page_table):
+    """Gather layer ``li``'s pages named by ``page_table`` ``[...,
+    max_pages]`` into position order: ``[..., max_pages * page_size,
+    n_kv * hd]`` — :func:`~tensorframes_tpu.ops.attention.gather_pages`,
+    the one gather the decode read uses too."""
+    from ..ops.attention import gather_pages
+
+    return gather_pages(pages, page_table, li)
+
+
+def split_heads(rows, head_dim: int):
+    """``[..., n_kv * hd] -> [..., n_kv, hd]``: the per-head view of
+    gathered rows, for the span programs' many-query einsums. This DOES
+    re-tile the block on the TPU (lanes split 25 x 64); the decode read
+    (:func:`~tensorframes_tpu.ops.paged_attention`) never calls it."""
+    return rows.reshape(rows.shape[:-1] + (-1, int(head_dim)))
+
+
+class _PageArrays:
+    """The two device arrays of one page family (``k`` / ``v`` in the
+    :func:`kv_pool_shape` layout) and every page-level operation on
+    them — shared by :class:`PagePool` (the main arrays) and
+    :class:`PageGroup` (a draft model's). The eager operations here
+    re-pin their result through :meth:`place`, so the compiled step
+    programs always receive already-placed inputs instead of resharding
+    on dispatch."""
+
+    n_layers: int
+    n_kv_heads: int
+    head_dim: int
+    sharding = None
+
+    def _geometry(self) -> Tuple[int, int]:
+        """``(num_pages, page_size)`` of the index space."""
+        raise NotImplementedError
+
+    def _alloc_arrays(self, dtype) -> None:
+        import jax.numpy as jnp
+
+        shape = kv_pool_shape(
+            self.n_layers, *self._geometry(), self.n_kv_heads,
+            self.head_dim,
+        )
+        self.k = jnp.zeros(shape, dtype, device=self.sharding)
+        self.v = jnp.zeros(shape, dtype, device=self.sharding)
+
+    def place(self, arr):
+        """Pin ``arr`` to this family's sharding (identity when
+        unsharded)."""
+        if self.sharding is None:
+            return arr
+        import jax
+
+        return jax.device_put(arr, self.sharding)
+
+    def _map_arrays(self, fn) -> None:
+        self.k = self.place(fn(self.k))
+        self.v = self.place(fn(self.v))
+
+    def copy_page(self, src: int, dst: int) -> None:
+        """Page ``dst`` becomes a copy of page ``src`` in every layer
+        (the prefix cache's copy-on-write clone)."""
+        self._map_arrays(lambda a: a.at[:, dst].set(a[:, src]))
+
+    def permute_pages(self, perm) -> None:
+        """Row ``new`` takes the contents of row ``perm[new]``
+        (:meth:`PagePool.defragment`)."""
+        self._map_arrays(lambda a: a[:, perm])
+
+    def take_pages(self, rows):
+        """Pages ``rows`` of every layer as ``(k, v)`` device arrays in
+        the LOGICAL geometry ``[n_layers, len(rows), page_size,
+        n_kv_heads, head_dim]`` — what a tier snapshot carries, whatever
+        the device layout is."""
+        logical = (
+            self.n_layers, len(rows), self._geometry()[1],
+            self.n_kv_heads, self.head_dim,
+        )
+        return (
+            self.k[:, rows].reshape(logical),
+            self.v[:, rows].reshape(logical),
+        )
+
+    def put_pages(self, rows, k_src, v_src) -> None:
+        """Write logical-geometry page rows (:meth:`take_pages`'s
+        shape; host or device) at indices ``rows``."""
+        stored = (self.n_layers, len(rows)) + tuple(self.k.shape[2:])
+        self.k = self.place(self.k.at[:, rows].set(k_src.reshape(stored)))
+        self.v = self.place(self.v.at[:, rows].set(v_src.reshape(stored)))
+
+    def fill(self, k_value: float, v_value: float) -> None:
+        """Overwrite every element (the chaos drills' simulated device
+        loss)."""
+        self.k = self.place(self.k * 0.0 + k_value)
+        self.v = self.place(self.v * 0.0 + v_value)
+
+
+class PagePool(_PageArrays):
     """Fixed-size KV page pool: device arrays with a STATIC shape plus a
     host-side free-list allocator.
 
-    ``k``/``v`` are ``[n_layers, num_pages + 1, page_size, n_kv_heads,
-    head_dim]`` jax arrays — page ``num_pages`` is the trash page (see
-    module docstring). The arrays are exposed as plain attributes because
-    the engine's compiled step functions consume and return them
-    functionally (donated); the pool only tracks WHICH pages are
-    live, never their contents."""
+    ``k``/``v`` are ``[n_layers, num_pages + 1, page_size, n_kv_heads *
+    head_dim]`` jax arrays (:func:`kv_pool_shape`) — page ``num_pages``
+    is the trash page (see module docstring). The arrays are exposed as
+    plain attributes because the engine's compiled step functions
+    consume and return them functionally (donated); the pool only
+    tracks WHICH pages are live, never their contents. Code outside
+    this module passes them to the step programs whole and otherwise
+    goes through :func:`write_rows` / :func:`read_pages` (traced) and
+    :meth:`copy_page` / :meth:`take_pages` / :meth:`put_pages` /
+    :meth:`defragment` / :meth:`reset` (eager)."""
 
     def __init__(
         self,
@@ -109,16 +310,7 @@ class PagePool:
         self.sharding = sharding
         #: index of the trash page (valid to write, never read unmasked)
         self.trash_page = self.num_pages
-        shape = (
-            self.n_layers,
-            self.num_pages + 1,
-            self.page_size,
-            self.n_kv_heads,
-            self.head_dim,
-        )
-        dtype = jnp.float32 if dtype is None else dtype
-        self.k = jnp.zeros(shape, dtype, device=sharding)
-        self.v = jnp.zeros(shape, dtype, device=sharding)
+        self._alloc_arrays(jnp.float32 if dtype is None else dtype)
         #: named parallel page-array families addressed by the SAME page
         #: indices as ``k``/``v`` (:meth:`add_group`) — how a draft
         #: model's KV rides the pool without its own allocator: one
@@ -138,17 +330,13 @@ class PagePool:
         #: free(); the page returns to the free list at 0
         self._refcount = np.zeros(self.num_pages, np.int32)
 
-    def place(self, arr):
-        """Pin ``arr`` to the pool's sharding (identity when unsharded).
-        Every eager rewrite of the pool arrays — :meth:`defragment`,
-        the engine's copy-on-write clone, a migrated slot's rows — runs
-        through this so the compiled step programs always receive
-        already-placed inputs instead of resharding on dispatch."""
-        if self.sharding is None:
-            return arr
-        import jax
+    def _geometry(self) -> Tuple[int, int]:
+        return self.num_pages, self.page_size
 
-        return jax.device_put(arr, self.sharding)
+    def families(self) -> Dict[str, "_PageArrays"]:
+        """Every page family addressed by this pool's page indices: the
+        main arrays under ``""`` and each :meth:`add_group` by name."""
+        return {"": self, **self.groups}
 
     def add_group(
         self,
@@ -160,7 +348,7 @@ class PagePool:
         sharding=None,
     ) -> "PageGroup":
         """Attach a named PARALLEL page-array family (``[n_layers,
-        num_pages + 1, page_size, n_kv_heads, head_dim]``) addressed by
+        num_pages + 1, page_size, n_kv_heads * head_dim]``) addressed by
         the same page indices as the pool's own ``k``/``v`` — the
         speculative-decoding draft model's KV page group
         (docs/serving_llm.md "Speculative decoding"). Page BOOKKEEPING
@@ -178,6 +366,13 @@ class PagePool:
         )
         self.groups[name] = g
         return g
+
+    def copy_page(self, src: int, dst: int) -> None:
+        """Page ``dst`` becomes a copy of page ``src`` — in the main
+        arrays AND every group: a page is one logical unit."""
+        super().copy_page(src, dst)
+        for g in self.groups.values():
+            g.copy_page(src, dst)
 
     # -- allocation --------------------------------------------------------
 
@@ -257,21 +452,9 @@ class PagePool:
         every live sequence (their KV contents are rebuilt from
         host-side progress by re-prefill); any :class:`SequencePages`
         still holding pages after this call is stale."""
-        import jax.numpy as jnp
-
         with self._lock:
-            shape = (
-                self.n_layers,
-                self.num_pages + 1,
-                self.page_size,
-                self.n_kv_heads,
-                self.head_dim,
-            )
-            dtype = self.k.dtype
-            self.k = jnp.zeros(shape, dtype, device=self.sharding)
-            self.v = jnp.zeros(shape, dtype, device=self.sharding)
-            for g in self.groups.values():
-                g.reset()
+            for fam in self.families().values():
+                fam._alloc_arrays(fam.k.dtype)
             self._free = list(range(self.num_pages - 1, -1, -1))
             self._free_set = set(self._free)
             self._refcount[:] = 0
@@ -320,14 +503,11 @@ class PagePool:
                 perm[new] = old
             perm[len(remap) : self.num_pages] = tail
             perm[self.num_pages] = self.trash_page
-            self.k = self.place(self.k[:, perm])
-            self.v = self.place(self.v[:, perm])
-            for g in self.groups.values():
-                # a page is one logical unit across every group: the
-                # draft KV rows move with the same permutation, so page
-                # lists stay valid for both models
-                g.k = g.place(g.k[:, perm])
-                g.v = g.place(g.v[:, perm])
+            # a page is one logical unit across every group: the draft
+            # KV rows move with the same permutation, so page lists
+            # stay valid for both models
+            for fam in self.families().values():
+                fam.permute_pages(perm)
             self._refcount = self._refcount[perm[: self.num_pages]]
             for pages in all_lists:
                 pages[:] = [remap[p] for p in pages]
@@ -342,14 +522,15 @@ class PagePool:
         )
 
 
-class PageGroup:
+class PageGroup(_PageArrays):
     """One named parallel page-array family over a :class:`PagePool`'s
     index space (:meth:`PagePool.add_group`): its own ``k``/``v`` device
     arrays with the pool's ``num_pages + 1`` / ``page_size`` geometry
     (trash row included) but its own layer/head/dim shape and dtype —
     the speculative-decoding DRAFT model's KV. No allocator of its own:
     page index ``p`` in a sequence's table names row ``p`` here exactly
-    as it does in the main arrays."""
+    as it does in the main arrays. ``sharding`` is its own: the draft
+    group stays replicated even under a tensor-parallel pool."""
 
     def __init__(
         self,
@@ -365,35 +546,10 @@ class PageGroup:
         self.n_kv_heads = int(n_kv_heads)
         self.head_dim = int(head_dim)
         self.sharding = sharding
-        self._dtype = pool.k.dtype if dtype is None else dtype
-        self.reset()
+        self._alloc_arrays(pool.k.dtype if dtype is None else dtype)
 
-    def _shape(self):
-        return (
-            self.n_layers,
-            self.pool.num_pages + 1,
-            self.pool.page_size,
-            self.n_kv_heads,
-            self.head_dim,
-        )
-
-    def place(self, arr):
-        """Pin ``arr`` to this group's own sharding (identity when
-        unsharded — the draft group stays replicated even under a
-        tensor-parallel pool)."""
-        if self.sharding is None:
-            return arr
-        import jax
-
-        return jax.device_put(arr, self.sharding)
-
-    def reset(self) -> None:
-        """Fresh zeroed arrays (crash recovery, with
-        :meth:`PagePool.reset`)."""
-        import jax.numpy as jnp
-
-        self.k = jnp.zeros(self._shape(), self._dtype, device=self.sharding)
-        self.v = jnp.zeros(self._shape(), self._dtype, device=self.sharding)
+    def _geometry(self) -> Tuple[int, int]:
+        return self.pool.num_pages, self.pool.page_size
 
 
 class SequencePages:

@@ -110,7 +110,10 @@ class SlotSnapshot:
 
     ``k`` / ``v`` are ``[n_layers, n_pages, page_size, n_kv_heads,
     head_dim]`` rows gathered from the source pool in page-list order
-    (logical geometry — TP shards are merged by the export gather);
+    (LOGICAL geometry, whatever the pool's device layout is —
+    ``PagePool.take_pages`` / ``put_pages`` convert, so a snapshot
+    taken before the pool merged heads into its lane axis still
+    restores; TP shards are merged by the export gather);
     ``groups`` maps each page-group name (e.g. ``"draft"``) to its own
     ``(k, v)`` row pair. Request fields are carried verbatim so the
     destination's :class:`~.scheduler.GenRequest` continues the same
@@ -184,16 +187,11 @@ def export_slot(engine, request_id: int, reason: str = "handoff"):
 
         def fetch():
             _chaos.site("tier.handoff")
-            payload = {
-                "": (
-                    _d2h(pool.k[:, rows], what="tier.kv"),
-                    _d2h(pool.v[:, rows], what="tier.kv"),
-                ),
-            }
-            for name, g in pool.groups.items():
-                payload[name] = (
-                    _d2h(g.k[:, rows], what=f"tier.kv.{name}"),
-                    _d2h(g.v[:, rows], what=f"tier.kv.{name}"),
+            payload = {}
+            for name, fam in pool.families().items():
+                what = f"tier.kv.{name}" if name else "tier.kv"
+                payload[name] = tuple(
+                    _d2h(a, what=what) for a in fam.take_pages(rows)
                 )
             return payload
 
@@ -277,8 +275,7 @@ def _write_rows(holder, rows: np.ndarray, k_host, v_host) -> None:
     )
     k_src = _h2d(k_host, what="tier.kv") if use_h2d else k_host
     v_src = _h2d(v_host, what="tier.kv") if use_h2d else v_host
-    holder.k = holder.place(holder.k.at[:, rows].set(k_src))
-    holder.v = holder.place(holder.v.at[:, rows].set(v_src))
+    holder.put_pages(rows, k_src, v_src)
 
 
 def restore_slot(engine, snap: SlotSnapshot, _handle_factory=None):
@@ -321,10 +318,11 @@ def restore_slot(engine, snap: SlotSnapshot, _handle_factory=None):
                     g = pool.groups.get(name)
                     if g is None:
                         continue  # destination runs without this group
-                    if (
-                        tuple(gk.shape) != tuple(g.k[:, rows].shape)
-                        or gk.dtype != g.k.dtype
-                    ):
+                    want = (
+                        g.n_layers, rows.size, pool.page_size,
+                        g.n_kv_heads, g.head_dim,
+                    )
+                    if tuple(gk.shape) != want or gk.dtype != g.k.dtype:
                         # e.g. a different draft model: leave the rows
                         # zeroed; draft_pos resets below and the draft
                         # re-ingests (proposals degrade, bytes do not)

@@ -70,6 +70,7 @@ from ..models.transformer import (
     transformer_step,
     transformer_verify_chunk,
 )
+from .kv_pages import read_pages, split_heads, write_prompt, write_rows
 
 __all__ = [
     "estimate_collective_seconds",
@@ -110,11 +111,14 @@ def validate_tp_mesh(mesh, n_heads: int, n_kv: int, d_ff: int) -> str:
 
 
 def tp_kv_specs(axis: str):
-    """(in/out) PartitionSpec for the pool's ``[L, pages, ps, n_kv,
-    hd]`` arrays: sharded on the KV-head axis."""
+    """(in/out) PartitionSpec for the pool's ``[L, pages, ps, n_kv *
+    hd]`` arrays (``kv_pages.kv_pool_shape``): sharded on the merged
+    head axis, whose even split lands on KV-head boundaries because
+    ``validate_tp_mesh`` made ``n_kv`` divide — each shard holds its
+    ``n_kv / tp`` heads' lanes of every row."""
     from jax.sharding import PartitionSpec as P
 
-    return P(None, None, None, axis, None)
+    return P(None, None, None, axis)
 
 
 def _local_heads(arr, axis: str, kloc: int, head_axis: int):
@@ -167,7 +171,6 @@ def tp_prefill_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
 
     from ..ops.attention import _NEG_BIG
 
-    ps = engine.page_size
     trash = engine.pool.trash_page
     top_k = engine.top_k
     tp = int(mesh.devices.size)
@@ -185,10 +188,8 @@ def tp_prefill_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
             ql = _local_heads(q[0], axis, kloc, 1)
             kl = _local_heads(k[0], axis, kloc, 1)
             vl = _local_heads(v[0], axis, kloc, 1)
-            page = jnp.where(pos < length, ptab[pos // ps], trash)
-            off = pos % ps
-            state[0] = state[0].at[li, page, off].set(kl)
-            state[1] = state[1].at[li, page, off].set(vl)
+            state[0] = write_prompt(state[0], li, ptab, length, kl, trash)
+            state[1] = write_prompt(state[1], li, ptab, length, vl, trash)
             hd = kl.shape[2]
             scale = 1.0 / float(np.sqrt(hd))
             # dense causal attention WITHIN the prompt, local heads:
@@ -260,12 +261,12 @@ def tp_prefill_chunk_impl(
             vl = _local_heads(v[0], axis, kloc, 1)
             page = jnp.where(offs < valid, ptab[pos_clipped // ps], trash)
             off = pos_clipped % ps
-            state[0] = state[0].at[li, page, off].set(kl)
-            state[1] = state[1].at[li, page, off].set(vl)
+            state[0] = write_rows(state[0], li, page, off, kl)
+            state[1] = write_rows(state[1], li, page, off, vl)
             hd = kl.shape[2]
             t = mp * ps
-            kg = state[0][li][ptab].reshape(t, kloc, hd)
-            vg = state[1][li][ptab].reshape(t, kloc, hd)
+            kg = split_heads(read_pages(state[0], li, ptab), hd)
+            vg = split_heads(read_pages(state[1], li, ptab), hd)
             scale = 1.0 / float(np.sqrt(hd))
             s = jnp.einsum("ckgd,tkd->ckgt", ql, kg) * scale
             visible = jnp.arange(t)[None, :] <= pos[:, None]
@@ -344,12 +345,12 @@ def tp_verify_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
                 trash,
             )
             off = pos_c % ps
-            state[0] = state[0].at[li, page, off].set(kl)
-            state[1] = state[1].at[li, page, off].set(vl)
+            state[0] = write_rows(state[0], li, page, off, kl)
+            state[1] = write_rows(state[1], li, page, off, vl)
             hd = kl.shape[3]
             t = mp * ps
-            kg = state[0][li][ptabs].reshape(slots, t, kloc, hd)
-            vg = state[1][li][ptabs].reshape(slots, t, kloc, hd)
+            kg = split_heads(read_pages(state[0], li, ptabs), hd)
+            vg = split_heads(read_pages(state[1], li, ptabs), hd)
             scale = 1.0 / float(np.sqrt(hd))
             s = jnp.einsum("sckgd,stkd->sckgt", ql, kg) * scale
             visible = (
@@ -409,11 +410,11 @@ def tp_decode_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
             vl = _local_heads(v, axis, kloc, 1)
             page = ptabs[jnp.arange(slots), positions // ps]
             off = positions % ps
-            state[0] = state[0].at[li, page, off].set(kl)
-            state[1] = state[1].at[li, page, off].set(vl)
+            state[0] = write_rows(state[0], li, page, off, kl)
+            state[1] = write_rows(state[1], li, page, off, vl)
             read = ragged_paged_attention if fused else paged_attention
             ctx = read(
-                ql, state[0][li], state[1][li], ptabs, positions + 1
+                ql, state[0], state[1], ptabs, positions + 1, layer=li
             )
             ctx = jax.lax.all_gather(ctx, axis, axis=1, tiled=True)
             return ctx.reshape(slots, d_model)

@@ -358,8 +358,7 @@ class TestServingUnderChaos:
                 if w == 1:
                     # mid-run crash: device KV state is lost outright;
                     # restart() rebuilds it from host-side progress
-                    eng.pool.k = eng.pool.k * 0.0 + 99.0
-                    eng.pool.v = eng.pool.v * 0.0 - 99.0
+                    eng.pool.fill(99.0, -99.0)
                     eng.restart()
             eng.run_until_idle()
         wall = time.monotonic() - t0

@@ -778,9 +778,11 @@ class TestKillSoak:
         - ``w-victim`` stalls forever inside its first block (chaos
           latency) while heartbeating, and is **kill -9**'d once its
           lease is on disk — a genuine mid-block process death;
-        - ``w-zombie`` stalls 5 s inside its first block with
+        - ``w-zombie`` stalls 12 s inside its first block with
           heartbeats disabled and a 1.2 s TTL — it is presumed dead,
-          its block stolen, and its late write must be fence-rejected.
+          its block stolen (by ``w-healthy``, which starts once the
+          zombie holds its lease and has the stall to come up in), and
+          its late write must be fence-rejected.
 
         Asserts: byte-identity with a solo run, ≥ 1 reclaim and ≥ 1
         fence reject on the obs counters, the victim's block reclaimed
@@ -800,19 +802,32 @@ class TestKillSoak:
                 w: str(tmp_path / f"report-{w}.json")
                 for w in ("w-healthy", "w-victim", "w-zombie")
             }
-            healthy = _spawn_worker(
-                path, "w-healthy", 20.0, 0.0, reports["w-healthy"],
-                "seed=5;jobs.block=transient:p=0.25",
-            )
             victim = _spawn_worker(
                 path, "w-victim", 2.0, 0.0, reports["w-victim"],
                 "jobs.block=latency:ms=120000",
             )
             zombie = _spawn_worker(
                 path, "w-zombie", 1.2, 1e6, reports["w-zombie"],
-                "jobs.block=latency:ms=5000:times=1",
+                "jobs.block=latency:ms=12000:times=1",
             )
+            healthy = None
             try:
+                # the healthy worker starts once the zombie holds its
+                # lease: started together, a healthy worker that wins
+                # the start-up race on a loaded machine drains all 12
+                # blocks before the zombie claims one, and there is no
+                # late write left to fence
+                deadline = time.monotonic() + 120
+                while _victim_lease(path, "w-zombie") is None:
+                    assert time.monotonic() < deadline, (
+                        "zombie never claimed a lease"
+                    )
+                    assert zombie.poll() is None, zombie.stderr.read()
+                    time.sleep(0.05)
+                healthy = _spawn_worker(
+                    path, "w-healthy", 20.0, 0.0, reports["w-healthy"],
+                    "seed=5;jobs.block=transient:p=0.25",
+                )
                 # kill -9 the victim the moment it holds a lease
                 deadline = time.monotonic() + 120
                 victim_block = None
@@ -834,7 +849,7 @@ class TestKillSoak:
                 assert zombie.returncode == 0, out_z[1][-4000:]
             finally:
                 for p in (healthy, victim, zombie):
-                    if p.poll() is None:
+                    if p is not None and p.poll() is None:
                         p.kill()
             rep_h = json.load(open(reports["w-healthy"]))
             rep_z = json.load(open(reports["w-zombie"]))

@@ -80,11 +80,12 @@ class TestRaggedKernelOracle:
         q = jnp.asarray(
             rng.normal(size=(slots, n_kv, group, hd)).astype(np.float32)
         ).astype(dtype)
+        # the pool's layout (serve/kv_pages.py): heads merged into lanes
         kp = jnp.asarray(
-            rng.normal(size=(pool + 1, ps, n_kv, hd)).astype(np.float32)
+            rng.normal(size=(pool + 1, ps, n_kv * hd)).astype(np.float32)
         ).astype(dtype)
         vp = jnp.asarray(
-            rng.normal(size=(pool + 1, ps, n_kv, hd)).astype(np.float32)
+            rng.normal(size=(pool + 1, ps, n_kv * hd)).astype(np.float32)
         ).astype(dtype)
         ptab = rng.integers(0, pool, size=(slots, mp)).astype(np.int32)
         return q, kp, vp, ptab
@@ -161,7 +162,7 @@ class TestPagedInputValidation:
 
     def _args(self, rng):
         q = jnp.zeros((2, 2, 1, 8), jnp.float32)
-        kp = jnp.zeros((5, 4, 2, 8), jnp.float32)
+        kp = jnp.zeros((5, 4, 2 * 8), jnp.float32)
         ptab = np.zeros((2, 3), np.int32)
         lengths = np.ones(2, np.int32)
         return q, kp, ptab, lengths
@@ -182,10 +183,12 @@ class TestPagedInputValidation:
         with pytest.raises(ValueError, match="page_table must be \\[slots"):
             impl(q, kp, kp, np.zeros((3, 3), np.int32), lengths)
         with pytest.raises(ValueError, match="n_kv"):
-            impl(q, jnp.zeros((5, 4, 3, 8), jnp.float32),
-                 jnp.zeros((5, 4, 3, 8), jnp.float32), ptab, lengths)
+            impl(q, jnp.zeros((5, 4, 3 * 8), jnp.float32),
+                 jnp.zeros((5, 4, 3 * 8), jnp.float32), ptab, lengths)
         with pytest.raises(ValueError, match="share a shape"):
-            impl(q, kp, jnp.zeros((5, 4, 2, 4), jnp.float32), ptab, lengths)
+            impl(q, kp, jnp.zeros((5, 4, 2 * 4), jnp.float32), ptab, lengths)
+        with pytest.raises(ValueError, match="leading layer axis"):
+            impl(q, kp[None], kp[None], ptab, lengths)
 
 
 # ---------------------------------------------------------------------------

@@ -78,7 +78,8 @@ class TestPagePool:
 
     def test_static_shape_and_trash_row(self):
         pool = self._pool()
-        assert pool.k.shape == (2, 7, 4, 2, 4)  # num_pages + 1 trash row
+        # num_pages + 1 trash row; heads merged into the lane axis
+        assert pool.k.shape == (2, 7, 4, 2 * 4)
         assert pool.trash_page == 6
 
     def test_alloc_free_roundtrip(self):
@@ -135,14 +136,16 @@ class TestPagePool:
         a.pages = a.pages[1:]
         # stamp each live page's contents with its page index
         for p in a.pages + b.pages:
-            pool.k = pool.k.at[:, p].set(float(p))
+            stamp = np.full((2, 1, 4, 2, 4), float(p), np.float32)
+            pool.put_pages([p], stamp, -stamp)
         stamps = {p: float(p) for p in a.pages + b.pages}
         remap = pool.defragment([a, b])
         assert sorted(a.pages + b.pages) == [0, 1, 2]  # compacted prefix
         for old, new in remap.items():
-            np.testing.assert_array_equal(
-                np.asarray(pool.k[:, new]), stamps[old]
-            )
+            k, v = pool.take_pages([new])
+            assert k.shape == (2, 1, 4, 2, 4)
+            np.testing.assert_array_equal(np.asarray(k), stamps[old])
+            np.testing.assert_array_equal(np.asarray(v), -stamps[old])
         # freed tail is allocatable again
         assert pool.pages_free == 3
         pool.alloc(3)
@@ -439,8 +442,7 @@ class TestSupervisor:
         for _ in range(3):
             eng.step()
         before = _counter_value("serve.engine_restarts_total")
-        eng.pool.k = eng.pool.k * 0.0 + 7.25  # simulated device loss
-        eng.pool.v = eng.pool.v * 0.0 - 3.5
+        eng.pool.fill(7.25, -3.5)  # simulated device loss
         eng.restart()
         eng.run_until_idle()
         for p, h in zip(prompts, handles):

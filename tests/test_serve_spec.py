@@ -259,7 +259,7 @@ class TestMechanism:
             page_size=4,
         )
         g = pool.add_group("draft", n_layers=2, n_kv_heads=1, head_dim=3)
-        assert g.k.shape == (2, 7, 4, 1, 3)
+        assert g.k.shape == (2, 7, 4, 1 * 3)
         with pytest.raises(ValueError, match="already exists"):
             pool.add_group("draft", 1, 1, 1)
         seq = SequencePages(pool)
@@ -268,10 +268,11 @@ class TestMechanism:
         other.ensure(4)
         # color the group rows by page index, then free the first seq
         # so defragment must move the survivor's page
-        g.k = g.k.at[:].set(
-            jnp.arange(7, dtype=jnp.float32)[None, :, None, None, None]
-            * jnp.ones_like(g.k)
+        color = jnp.broadcast_to(
+            jnp.arange(7, dtype=jnp.float32)[None, :, None, None, None],
+            (2, 7, 4, 1, 3),
         )
+        g.put_pages(np.arange(7), color, color)
         held = other.pages[0]
         seq.release()
         remap = pool.defragment([other])
@@ -279,7 +280,7 @@ class TestMechanism:
         # the group row followed its page: contents still the ORIGINAL
         # page's color
         np.testing.assert_allclose(
-            np.asarray(g.k[:, other.pages[0]]), float(held)
+            np.asarray(g.take_pages([other.pages[0]])[0]), float(held)
         )
         pool.reset()
         np.testing.assert_allclose(np.asarray(g.k), 0.0)
