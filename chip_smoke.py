@@ -16,7 +16,9 @@ user would call, and checks what comes out by the repo's own means:
    training steps, flash forward + grad against the reference; then the KV
    pool's layout: the decode and prefill programs compiled at 25 heads of
    64 must hold no copy or transpose of the pool, of a layer of it or of
-   the gathered block;
+   the gathered block; the same for the decode and prefill-chunk programs
+   of a rotary / gated-expert model with window and full layers at
+   Mellum2-12B's published widths (one period of its layers);
 4. four chips (when the host has them) — dp frame ops, a tp=4 engine, a
    four-replica fleet, ring attention;
 5. README flow 1 on a float64 column, last, because it flips jax's x64 flag;
@@ -80,6 +82,16 @@ class Sizes:
     # GPT-2 XL's widths for the pool-layout guard: 25 heads of 64
     layout_lm = dict(d_model=1600, n_heads=25, n_layers=8, max_len=1024)
     layout_vocab, layout_pages = 1024, 176
+    # a rotary / RMS / gated-expert model with window and full layers at
+    # Mellum2-12B's published widths, one period of its layers
+    long_lm = dict(
+        hidden=2304, heads=32, kv_heads=4, head_dim=128, experts=64,
+        expert_width=896, per_token=8, window=1024, vocab=98304,
+        max_len=16768, layer_types=("window",) * 3 + ("full",),
+    )
+    # a pool the compiler cannot stage whole in fast memory: at 4,096
+    # pages (67 MB an array) it prefetches the pool with an async copy
+    long_slots, long_pages = 32, 16384
 
     @classmethod
     def toy(cls):
@@ -96,6 +108,12 @@ class Sizes:
         s.ring_l = 512
         s.layout_lm = dict(d_model=40, n_heads=5, n_layers=2, max_len=64)
         s.layout_vocab, s.layout_pages = 64, 12
+        s.long_lm = dict(
+            hidden=32, heads=4, kv_heads=2, head_dim=16, experts=8,
+            expert_width=16, per_token=2, window=32, vocab=64, max_len=160,
+            layer_types=("window",) * 3 + ("full",),
+        )
+        s.long_slots, s.long_pages = 4, 48
         return s
 
 
@@ -397,6 +415,115 @@ def phase_pool_layout(run, S):
             run.check(
                 mem.alias_size_in_bytes >= 2 * whole * pool.dtype.itemsize,
                 f"{name} updates both pool arrays in place (donated)",
+            )
+
+
+def _described_model(m):
+    """A params tree with a model description (zeros: the phase compiles,
+    it does not run): rotary, RMSNorm, SiLU-gated experts through the
+    grouped product, window and full layers."""
+    import jax.numpy as jnp
+
+    d, e, f = m["hidden"], m["experts"], m["expert_width"]
+    q, kv = m["heads"] * m["head_dim"], m["kv_heads"] * m["head_dim"]
+    z = lambda *shape: jnp.zeros(shape, jnp.bfloat16)
+    gain = lambda: {"g": jnp.ones((d,), jnp.bfloat16)}
+    return {
+        "embed": z(m["vocab"], d), "head": z(d, m["vocab"]),
+        "ln_f": gain(),
+        "blocks": [
+            {
+                "ln1": gain(), "qkv": z(d, q + 2 * kv), "proj": z(q, d),
+                "ln2": gain(),
+                "moe": {
+                    "router": z(d, e), "w_gate": z(e, d, f),
+                    "w_up": z(e, d, f), "w_down": z(e, f, d),
+                },
+            }
+            for _ in m["layer_types"]
+        ],
+        "spec": {
+            "n_heads": m["heads"], "n_kv_heads": m["kv_heads"],
+            "head_dim": m["head_dim"], "max_len": m["max_len"],
+            "norm": "rms", "norm_eps": 1e-6, "position": "rotary",
+            "layer_types": list(m["layer_types"]), "window": m["window"],
+            "rope": {
+                "window": {"theta": 500000.0},
+                "full": {
+                    "theta": 500000.0, "kind": "yarn", "factor": 16.0,
+                    "original_max_position": 8192,
+                    "attention_factor": 1.2772588722239782,
+                },
+            },
+            "mlp": "gated_experts", "n_experts": e,
+            "experts_per_token": m["per_token"], "tied_head": False,
+            "residual_dtype": "float32",
+        },
+    }
+
+
+def phase_pool_layout_long(run, S):
+    """The same guard for a long-sequence model: the decode and the
+    prefill-chunk program of a rotary / RMS / gated-expert model with
+    window and full layers, at Mellum2-12B's published widths and one
+    period of its layers, must compile for the chip and hold no copy or
+    transpose of the pool or of a row of it (a pool page is as deep as
+    the cache kinds' common divisor, ``serve/kv_pages.py``)."""
+    from tensorframes_tpu.serve import GenerationEngine
+    from tensorframes_tpu.serve.kv_pages import SequencePages
+
+    import jax
+    import numpy as np
+
+    eng = GenerationEngine(
+        _described_model(S.long_lm), max_slots=S.long_slots,
+        page_size=S.page_size, num_pages=S.long_pages,
+    )
+    run.check(
+        eng._long and eng.layout is not None
+        and [k.name for k in eng.layout.kinds] == ["full", "window"],
+        "a model with window layers gets two cache kinds, chunked "
+        "prefill and the live-bounded read by itself",
+        chunk=eng.prefill_chunk_tokens,
+    )
+    spec = lambda a: jax.ShapeDtypeStruct(
+        np.shape(a), a.dtype, sharding=getattr(a, "sharding", None)
+    )
+    pool = spec(eng.pool.k)
+    whole = math.prod(pool.shape)
+    programs = {
+        "jit_decode": (eng._decode_jit, eng._decode_args([])),
+        "jit_chunk_step": (
+            eng._prefill_chunk_jit,
+            eng._chunk_args(
+                np.zeros(1, np.int32), 0, 1, 1,
+                SequencePages(eng.pool, eng.layout), 0.0, 0, 1.0,
+            ),
+        ),
+    }
+    for name, (fn, args) in programs.items():
+        args = jax.tree.map(lambda a: spec(np.asarray(a)), args)
+        compiled = fn.lower(eng._params_dev, pool, pool, *args).compile()
+        mem = compiled.memory_analysis()
+        moved = pool_relayouts(
+            compiled.as_text(), (whole, whole // pool.shape[0])
+        )
+        run.emit(
+            phase=run.phase, program=name, pool=list(pool.shape),
+            temp_bytes=getattr(mem, "temp_size_in_bytes", None),
+            alias_bytes=getattr(mem, "alias_size_in_bytes", None),
+            relayouts=moved[:8],
+        )
+        if not run.rehearsal:
+            run.check(
+                not moved,
+                f"{name} (long) neither copies nor transposes the pool or "
+                f"a row of it",
+                found=len(moved),
+            )
+            run.check(
+                mem.alias_size_in_bytes >= 2 * whole * pool.dtype.itemsize,
+                f"{name} (long) updates both pool arrays in place",
             )
 
 
@@ -1060,6 +1187,7 @@ def main(argv=None):
     run.run_phase("serve", phase_serve, S, state)
     run.run_phase("kernels", phase_kernels, S, state)
     run.run_phase("pool_layout", phase_pool_layout, S)
+    run.run_phase("pool_layout.long", phase_pool_layout_long, S)
     if len(jax.devices()) >= 4:
         run.run_phase("four_chips.dp", phase_dp, S)
         run.run_phase("four_chips.tp", phase_tp, S, state)

@@ -15,12 +15,21 @@ All matmuls stay [tokens, d] x [d, d'] so XLA tiles them onto the MXU.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Dict, Optional
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
 __all__ = [
+    "ModelSpec",
+    "RopeSpec",
+    "model_spec",
+    "block_forward",
+    "rope_inv_freq",
+    "device_tree",
+    "static_entries",
     "init_transformer",
     "init_draft_transformer",
     "transformer_logits",
@@ -162,12 +171,301 @@ def init_draft_transformer(
     )
 
 
-def _ln(x, p):
+#: the entries of a params tree that are not arrays: the head count of
+#: the GPT-2-style tree, and the model description a tree may carry
+STATIC_KEYS = ("n_heads", "spec")
+
+
+def device_tree(params: Params) -> Params:
+    """``params`` without its non-array entries: what goes to the device
+    and into a jitted program as an argument."""
+    return {k: v for k, v in params.items() if k not in STATIC_KEYS}
+
+
+def static_entries(params: Params) -> Params:
+    """The non-array entries of ``params`` (:data:`STATIC_KEYS`): closed
+    over by a step program and merged back into its weight argument."""
+    return {k: params[k] for k in STATIC_KEYS if k in params}
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary parameters of one layer type. ``kind`` ``"default"`` is
+    plain RoPE (``theta ** (-2i / head_dim)``); ``"yarn"`` blends the
+    plain frequencies with the same divided by ``factor`` along a linear
+    ramp between the dimensions that make ``beta_fast`` and ``beta_slow``
+    turns over ``original_max_position`` positions, and scales cos and
+    sin by ``attention_factor``."""
+
+    theta: float
+    kind: str = "default"
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelSpec:
+    """What a block walk has to know of a model beyond its weights'
+    shapes — frozen and hashable, so a step program can close over it.
+
+    A GPT-2-style tree (``init_transformer``) carries none and gets the
+    description its weights imply (:func:`model_spec`): LayerNorm 1e-5,
+    learned positions, GELU MLP, tied head, head width ``d_model /
+    n_heads``, K/V heads from ``qkv``'s columns. Any other model puts a
+    plain dict under ``params["spec"]`` with these fields' names
+    (``rope`` a dict by layer type), and the same four walks run it."""
+
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    max_len: int
+    norm: str = "layer"  # "layer" | "rms"
+    norm_eps: float = 1e-5
+    position: str = "learned"  # "learned" | "rotary"
+    #: one of "full" / "window" per layer; empty = every layer full
+    layer_types: Tuple[str, ...] = ()
+    #: key j is visible to query p iff p - window < j <= p (window layers)
+    window: int = 0
+    rope: Tuple[Tuple[str, RopeSpec], ...] = ()
+    mlp: str = "gelu"  # "gelu" | "gated_experts"
+    n_experts: int = 0
+    experts_per_token: int = 0
+    tied_head: bool = True
+    #: dtype of the residual stream and of every product's accumulator;
+    #: None = the weights' own (the GPT-2-style tree)
+    residual_dtype: Optional[str] = None
+
+    def layer_type(self, li: int) -> str:
+        return self.layer_types[li] if self.layer_types else "full"
+
+    def layer_window(self, li: int) -> int:
+        """The window of layer ``li``; 0 for a full layer."""
+        return self.window if self.layer_type(li) == "window" else 0
+
+    def rope_of(self, li: int) -> RopeSpec:
+        return dict(self.rope)[self.layer_type(li)]
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """The cache kinds this model needs, full first."""
+        seen = set(self.layer_types) or {"full"}
+        return tuple(k for k in ("full", "window") if k in seen)
+
+
+def model_spec(params: Params) -> ModelSpec:
+    """The model description of a params tree: built from the dict under
+    ``params["spec"]`` where there is one, read off the weights of a
+    GPT-2-style tree otherwise."""
+    raw = params.get("spec")
+    if isinstance(raw, ModelSpec):
+        return raw
+    if raw is not None:
+        raw = dict(raw)
+        rope = tuple(
+            (kind, RopeSpec(**r))
+            for kind, r in sorted(dict(raw.pop("rope", {})).items())
+        )
+        raw["layer_types"] = tuple(raw.get("layer_types", ()))
+        return ModelSpec(rope=rope, **raw)
+    n_heads = int(params["n_heads"])
+    d_model = int(np.shape(params["embed"])[1])
+    return _gpt2_spec(
+        n_heads, d_model // n_heads,
+        _kv_heads(params["blocks"][0], d_model, n_heads),
+        int(np.shape(params["pos"])[0]),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _gpt2_spec(n_heads: int, head_dim: int, n_kv: int, max_len: int):
+    return ModelSpec(
+        n_heads=n_heads, n_kv_heads=n_kv, head_dim=head_dim,
+        max_len=max_len,
+    )
+
+
+def rope_inv_freq(rope: RopeSpec, head_dim: int) -> np.ndarray:
+    """``[head_dim / 2]`` float32 inverse frequencies of one layer type."""
+    half = head_dim // 2
+    i = np.arange(half, dtype=np.float64)
+    extrap = rope.theta ** (-2.0 * i / head_dim)
+    if rope.kind == "default":
+        return extrap.astype(np.float32)
+    if rope.kind != "yarn":
+        raise ValueError(f"unknown rope kind {rope.kind!r}")
+
+    def turns_dim(turns):  # the dimension that makes ``turns`` rotations
+        return head_dim * math.log(
+            rope.original_max_position / (turns * 2.0 * math.pi)
+        ) / (2.0 * math.log(rope.theta))
+
+    low = max(math.floor(turns_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(rope.beta_slow)), head_dim - 1)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    interp = extrap / rope.factor
+    return (interp * ramp + extrap * (1.0 - ramp)).astype(np.float32)
+
+
+def _rotary(spec: ModelSpec, li: int, q, k, positions):
+    """Rotate ``q`` ``[..., n_kv, group, hd]`` and ``k`` ``[..., n_kv,
+    hd]`` by their ``positions`` ``[...]`` in the rotate-half pairing
+    ``(x_i, x_{i + hd/2})``, in float32."""
     import jax.numpy as jnp
 
+    rope = spec.rope_of(li)
+    inv = jnp.asarray(rope_inv_freq(rope, spec.head_dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    scale = jnp.float32(rope.attention_factor)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1) * scale
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1) * scale
+
+    def turn(x, c, s):
+        half = x.shape[-1] // 2
+        rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+        return x * c + rot * s
+
+    kc, ks = cos[..., None, :], sin[..., None, :]
+    return (
+        turn(q, kc[..., None, :], ks[..., None, :]).astype(q.dtype),
+        turn(k, kc, ks).astype(k.dtype),
+    )
+
+
+def _norm(spec: ModelSpec, x, p):
+    import jax
+    import jax.numpy as jnp
+
+    if spec.norm == "rms":
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + spec.norm_eps) * jnp.asarray(
+            p["g"]
+        ).astype(jnp.float32)
     mu = x.mean(-1, keepdims=True)
     var = x.var(-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + 1e-5) * p["g"] + p["b"]
+    return (x - mu) / jnp.sqrt(var + spec.norm_eps) * p["g"] + p["b"]
+
+
+def _ln(x, p):
+    return _norm(_LAYER_NORM, x, p)
+
+
+def _mm(spec: ModelSpec, x, w):
+    """``x @ w``; with a stated residual dtype the operand takes the
+    weight's dtype and the product accumulates in the residual's."""
+    import jax.numpy as jnp
+
+    w = jnp.asarray(w)
+    if spec.residual_dtype is None:
+        return x @ w
+    return jnp.dot(
+        x.astype(w.dtype), w, preferred_element_type=spec.residual_dtype
+    )
+
+
+def _embed(spec: ModelSpec, params, tokens, positions):
+    """Token rows, plus the learned position rows where the model has
+    them (``positions`` broadcasts against ``tokens``; None = the
+    sequence axis of ``tokens`` counted from 0)."""
+    import jax.numpy as jnp
+
+    h = jnp.asarray(params["embed"])[tokens]
+    if spec.position == "learned":
+        pos = jnp.asarray(params["pos"])
+        if positions is None:  # a whole sequence from position 0
+            h = h + pos[: tokens.shape[1]][None]
+        else:
+            h = h + pos[positions]
+    if spec.residual_dtype is not None:
+        h = h.astype(spec.residual_dtype)
+    return h
+
+
+def _head(spec: ModelSpec, params, h):
+    """Final norm and the output head (the embedding again when tied)."""
+    import jax.numpy as jnp
+
+    x = _norm(spec, h, params["ln_f"])
+    if spec.tied_head:
+        return _mm(spec, x, jnp.asarray(params["embed"]).T)
+    return _mm(spec, x, params["head"])
+
+
+def _ffn(spec: ModelSpec, block, x, moe_top_k: int = 1, routed=None):
+    """The block's second half on the normed input: the GELU MLP, the
+    GELU expert layer of ``init_moe`` (masked, ``moe_top_k``), or the
+    gated experts of the description through the grouped product.
+    ``routed``, a list, collects each expert layer's per-expert pair
+    counts."""
+    import jax
+    import jax.numpy as jnp
+
+    if "moe" not in block:
+        return jax.nn.gelu(x @ jnp.asarray(block["up"])) @ (
+            jnp.asarray(block["down"])
+        )
+    from ..parallel.moe import moe_ffn, moe_grouped
+
+    if spec.mlp == "gated_experts":
+        y, counts = moe_grouped(block["moe"], x, k=spec.experts_per_token)
+        if routed is not None:
+            routed.append(counts)
+        return y
+    if x.ndim == 2:
+        return moe_ffn(block["moe"], x[:, None, :], k=moe_top_k)[:, 0]
+    return moe_ffn(block["moe"], x, k=moe_top_k)
+
+
+def block_forward(
+    spec: ModelSpec, li: int, block, h, positions, attend,
+    moe_top_k: int = 1, routed=None, ffn=None,
+):
+    """THE block: norm, q/k/v, positions, attention through ``attend``,
+    residual; norm, MLP or experts, residual — over the model
+    description, for every walk (:func:`transformer_logits`,
+    :func:`transformer_step`, :func:`transformer_prefill`, the chunk
+    family, the pipelined stage), so that a norm, a position scheme, an
+    expert layer or a window arrives once.
+
+    ``h`` is ``[..., d_model]`` and ``positions`` ``[...]`` (or
+    broadcastable to it). ``attend(li, q, k, v)`` gets ``q`` ``[...,
+    n_kv, group, hd]`` and ``k`` / ``v`` ``[..., n_kv, hd]``, rotated
+    where the model is rotary, owns the K/V state and the mask (the
+    layer's window is ``spec.layer_window(li)``), and returns the
+    context ``[..., n_heads * hd]``. ``ffn(block, x)`` replaces the
+    second half's function (the training walk's expert-parallel
+    apply). The named scopes put the layer part into every device
+    operation's name; they change no operation."""
+    import jax
+    import jax.numpy as jnp
+
+    n_kv, hd = spec.n_kv_heads, spec.head_dim
+    group = spec.n_heads // n_kv
+    q_d, kv_d = spec.n_heads * hd, n_kv * hd
+    lead = h.shape[:-1]
+    with jax.named_scope("attn"):
+        x = _norm(spec, h, block["ln1"])
+        qkv = _mm(spec, x, block["qkv"])
+        q, k, v = jnp.split(qkv, [q_d, q_d + kv_d], axis=-1)
+        q = q.reshape(lead + (n_kv, group, hd))
+        k = k.reshape(lead + (n_kv, hd))
+        v = v.reshape(lead + (n_kv, hd))
+        if spec.position == "rotary":
+            q, k = _rotary(
+                spec, li, q, k, jnp.broadcast_to(positions, lead)
+            )
+        h = h + _mm(spec, attend(li, q, k, v), block["proj"])
+    with jax.named_scope("mlp"):
+        x = _norm(spec, h, block["ln2"])
+        if ffn is not None:
+            return h + ffn(block, x)
+        return h + _ffn(spec, block, x, moe_top_k, routed).astype(h.dtype)
+
+
+_LAYER_NORM = ModelSpec(n_heads=1, n_kv_heads=1, head_dim=1, max_len=0)
 
 
 def _kv_heads(block, d_model: int, n_heads: int) -> int:
@@ -178,68 +476,73 @@ def _kv_heads(block, d_model: int, n_heads: int) -> int:
     return kv_d // (d_model // n_heads)
 
 
-def _attention(x, block, n_heads, causal, attn_impl, mesh, batch_axis=None):
+def _sequence_attend(spec, causal, attn_impl, mesh, batch_axis):
+    """The whole-sequence ``attend`` of the training and scoring walk:
+    ``q`` ``[B, L, n_kv, group, hd]`` against this layer's own ``k`` /
+    ``v`` ``[B, L, n_kv, hd]``. The dense read scores each K/V head
+    against its query group as it lies; the sequence kernels are
+    head-uniform, so only they get K and V repeated per query head."""
+    import jax
     import jax.numpy as jnp
 
-    from ..ops import (
-        attention_reference,
-        flash_attention,
-        ring_attention,
-        ulysses_attention,
-    )
+    from ..ops import flash_attention, ring_attention, ulysses_attention
+    from ..ops.attention import _NEG_BIG
 
-    bsz, length, d = x.shape
-    hd = d // n_heads
-    n_kv = _kv_heads(block, d, n_heads)
-    kv_d = n_kv * hd
-    qkv = x @ block["qkv"]  # [B, L, D + 2*kv_d]
-    q, k, v = jnp.split(qkv, [d, d + kv_d], axis=-1)
+    def attend(li, q, k, v):
+        bsz, length, n_kv, group, hd = q.shape
+        window = spec.layer_window(li)
+        if attn_impl == "reference":
+            s = jnp.einsum("bqkgd,btkd->bkgqt", q, k).astype(jnp.float32)
+            s = s * (1.0 / float(np.sqrt(hd)))
+            qi = jnp.arange(length)[:, None]
+            ki = jnp.arange(length)[None, :]
+            if causal:
+                valid = qi >= ki
+                if window:
+                    valid &= ki > qi - window
+                s = jnp.where(valid, s, _NEG_BIG)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bkgqt,btkd->bqkgd", p, v.astype(jnp.float32))
+            return o.astype(q.dtype).reshape(bsz, length, -1)
+        if window:
+            raise ValueError(
+                f"attn_impl {attn_impl!r} has no window mask; window "
+                f"layers run with attn_impl='reference'"
+            )
+        qh = q.reshape(bsz, length, n_kv * group, hd).transpose(0, 2, 1, 3)
+        kh = jnp.repeat(k.transpose(0, 2, 1, 3), group, axis=1)
+        vh = jnp.repeat(v.transpose(0, 2, 1, 3), group, axis=1)
+        if attn_impl == "ring":
+            o = ring_attention(
+                qh, kh, vh, mesh=mesh, causal=causal, batch_axis=batch_axis
+            )
+        elif attn_impl == "ulysses":
+            o = ulysses_attention(
+                qh, kh, vh, mesh=mesh, causal=causal, batch_axis=batch_axis
+            )
+        else:
+            o = flash_attention(qh, kh, vh, causal=causal)
+        return o.transpose(0, 2, 1, 3).reshape(bsz, length, -1)
 
-    def heads(t, h):  # [B, L, h*hd] -> [B, h, L, hd]
-        return t.reshape(bsz, length, h, hd).transpose(0, 2, 1, 3)
-
-    q = heads(q, n_heads)
-    k = heads(k, n_kv)
-    v = heads(v, n_kv)
-    if n_kv != n_heads:
-        # grouped-query: each k/v head serves n_heads/n_kv query heads.
-        # The repeat materializes full-H k/v for the compute path (the
-        # kernels are head-uniform); the GQA saving is in the weights and
-        # the decode KV cache, which store only n_kv heads.
-        k = jnp.repeat(k, n_heads // n_kv, axis=1)
-        v = jnp.repeat(v, n_heads // n_kv, axis=1)
-    if attn_impl == "ring":
-        o = ring_attention(
-            q, k, v, mesh=mesh, causal=causal, batch_axis=batch_axis
-        )
-    elif attn_impl == "ulysses":
-        o = ulysses_attention(
-            q, k, v, mesh=mesh, causal=causal, batch_axis=batch_axis
-        )
-    elif attn_impl == "flash":
-        o = flash_attention(q, k, v, causal=causal)
-    else:
-        o = attention_reference(q, k, v, causal=causal)
-    o = o.transpose(0, 2, 1, 3).reshape(bsz, length, d)
-    return o @ block["proj"]
+    return attend
 
 
 def _dense_block(
     block, h, n_heads, causal=True, attn_impl="reference", mesh=None,
     batch_axis=None,
 ):
-    """One dense transformer block (pre-LN attention + gelu MLP residuals)
-    — THE block forward, shared by the full-model path
-    (:func:`transformer_logits`) and the pipelined stage
-    (:func:`_pipe_stage_fn`) so the two cannot drift apart."""
-    import jax
+    """One GPT-2-style block over a whole sequence: :func:`block_forward`
+    with the description its weights imply — the pipelined stage's
+    body (:func:`_pipe_stage_fn`)."""
+    import jax.numpy as jnp
 
-    x = h + _attention(
-        _ln(h, block["ln1"]), block, n_heads, causal, attn_impl, mesh,
-        batch_axis,
+    d_model = h.shape[-1]
+    spec = _gpt2_spec(
+        n_heads, d_model // n_heads, _kv_heads(block, d_model, n_heads), 0
     )
-    return x + (
-        jax.nn.gelu(_ln(x, block["ln2"]) @ block["up"]) @ block["down"]
+    return block_forward(
+        spec, 0, block, h, jnp.arange(h.shape[1])[None],
+        _sequence_attend(spec, causal, attn_impl, mesh, batch_axis),
     )
 
 
@@ -303,56 +606,63 @@ def transformer_logits(
     import jax
     import jax.numpy as jnp
 
-    n_heads = params["n_heads"]
-    length = tokens.shape[1]
-    # params may be host numpy (frozen-model scoring closes over them);
-    # jnp-ify before indexing with traced token ids
-    embed = jnp.asarray(params["embed"])
-    pos = jnp.asarray(params["pos"])
-    x = embed[tokens] + pos[:length][None]
     from ..parallel.moe import (
         EXPERT_AXIS,
         moe_apply,
         moe_dispatch_apply,
         moe_ffn,
+        moe_grouped,
         moe_load_balance_loss,
     )
 
-    def run_dense(block, h):
-        return _dense_block(
-            block, h, n_heads, causal, attn_impl, mesh, batch_axis
-        )
+    spec = model_spec(params)
+    # params may be host numpy (frozen-model scoring closes over them);
+    # the walk jnp-ifies before indexing with traced token ids
+    positions = jnp.arange(tokens.shape[1])[None]
+    x = _embed(spec, params, tokens, None)
+    attend = _sequence_attend(spec, causal, attn_impl, mesh, batch_axis)
 
-    def run_moe(block, h_in):
-        h = _ln(h_in, block["ln1"])
-        y = h_in + _attention(
-            h, block, n_heads, causal, attn_impl, mesh, batch_axis
-        )
-        h = _ln(y, block["ln2"])
+    def experts(block, h):
+        if spec.mlp == "gated_experts":
+            return moe_grouped(block["moe"], h, k=spec.experts_per_token)[0]
         if mesh is not None and EXPERT_AXIS in mesh.axis_names:
             apply = (
                 moe_dispatch_apply if moe_impl == "dispatch" else moe_apply
             )
-            return y + apply(block["moe"], h, mesh=mesh, k=moe_top_k), h
-        return y + moe_ffn(block["moe"], h, k=moe_top_k), h
+            return apply(block["moe"], h, mesh=mesh, k=moe_top_k)
+        return moe_ffn(block["moe"], h, k=moe_top_k)
+
+    def run_dense(li, block, h):
+        return block_forward(spec, li, block, h, positions, attend)
+
+    def run_moe(li, block, h):
+        mid = []
+
+        def ffn(block, hx):
+            mid.append(hx)
+            return experts(block, hx).astype(h.dtype)
+
+        return block_forward(
+            spec, li, block, h, positions, attend, ffn=ffn
+        ), mid[0]
 
     if remat:
-        run_dense = jax.checkpoint(run_dense)
+        run_dense = jax.checkpoint(run_dense, static_argnums=0)
         if not collect_moe_aux:
-            run_moe = jax.checkpoint(run_moe)
+            run_moe = jax.checkpoint(run_moe, static_argnums=0)
 
     moe_aux = 0.0
-    for block in params["blocks"]:
+    top_k = spec.experts_per_token or moe_top_k
+    for li, block in enumerate(params["blocks"]):
         if "moe" in block:
-            x, h_mid = run_moe(block, x)
+            x, h_mid = run_moe(li, block, x)
             if collect_moe_aux:
                 moe_aux = moe_aux + moe_load_balance_loss(
-                    block["moe"], h_mid, k=moe_top_k
+                    block["moe"], h_mid, k=top_k
                 )
         else:
-            x = run_dense(block, x)
-    x = _ln(x, params["ln_f"])
-    logits = x @ embed.T
+            x = run_dense(li, block, x)
+    logits = _head(spec, params, x)
     if collect_moe_aux:
         return logits, moe_aux
     return logits
@@ -412,7 +722,8 @@ def left_pad_prompts(seqs, pad_id: int = 0):
     return out, lengths
 
 
-def transformer_step(params, tok, positions, attend, moe_top_k: int = 1):
+def transformer_step(params, tok, positions, attend, moe_top_k: int = 1,
+                     routed=None):
     """One decoder step for a batch of single tokens — THE per-token block
     walk, shared by the scan decode (:func:`transformer_generate`) and the
     paged serving engine (:mod:`tensorframes_tpu.serve`) so the two decode
@@ -425,50 +736,22 @@ def transformer_step(params, tok, positions, attend, moe_top_k: int = 1):
     (grouped-query layout; ``group == 1`` rows share a k/v head) and this
     step's k/v ``[B, n_kv, hd]``, stores k/v wherever the caller keeps its
     cache (scan-carried dense cache, paged pool), reads the visible
-    history, and returns the pre-``proj`` attention context. Returns
-    logits ``[B, vocab]``."""
+    history, and returns the pre-``proj`` attention context. The block
+    itself is :func:`block_forward`'s, over :func:`model_spec`'s
+    description: a rotary model's q and k arrive rotated, and the mask
+    of a window layer (``spec.layer_window(li)``) is the callback's.
+    ``routed``, a list, collects each expert layer's per-expert pair
+    counts. Returns logits ``[B, vocab]``."""
     import jax
-    import jax.numpy as jnp
 
-    from ..parallel.moe import moe_ffn
-
-    embed = jnp.asarray(params["embed"])
-    posemb = jnp.asarray(params["pos"])
-    n_heads = params["n_heads"]
-    d_model = embed.shape[1]
-    hd = d_model // n_heads
-    bsz = tok.shape[0]
-    h = embed[tok] + posemb[positions]
-    # the named scopes (here and in transformer_prefill) put the layer
-    # part into every device operation's name, so a profiler view reads
-    # attn / mlp / head instead of fusion.123; they change no operation
+    spec = model_spec(params)
+    h = _embed(spec, params, tok, positions)
     for li, block in enumerate(params["blocks"]):
-        n_kv = _kv_heads(block, d_model, n_heads)
-        group = n_heads // n_kv
-        kv_d = n_kv * hd
-        with jax.named_scope("attn"):
-            x = _ln(h, block["ln1"])
-            qkv = x @ jnp.asarray(block["qkv"])
-            q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-            att = attend(
-                li,
-                q.reshape(bsz, n_kv, group, hd),
-                k.reshape(bsz, n_kv, hd),
-                v.reshape(bsz, n_kv, hd),
-            )
-            h = h + att @ jnp.asarray(block["proj"])
-        with jax.named_scope("mlp"):
-            hx = _ln(h, block["ln2"])
-            if "moe" in block:
-                h = h + moe_ffn(
-                    block["moe"], hx[:, None, :], k=moe_top_k
-                )[:, 0]
-            else:
-                h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
-                    jnp.asarray(block["down"])
-                )
+        h = block_forward(
+            spec, li, block, h, positions, attend, moe_top_k, routed
+        )
     with jax.named_scope("head"):
-        return _ln(h, params["ln_f"]) @ embed.T
+        return _head(spec, params, h)
 
 
 def transformer_prefill(params, tokens, store, moe_top_k: int = 1):
@@ -488,59 +771,41 @@ def transformer_prefill(params, tokens, store, moe_top_k: int = 1):
     import jax
     import jax.numpy as jnp
 
-    from ..parallel.moe import moe_ffn
-
     tokens = jnp.asarray(tokens, dtype=jnp.int32)
     bsz, plen = tokens.shape
-    n_heads = params["n_heads"]
-    embed = jnp.asarray(params["embed"])
-    posemb = jnp.asarray(params["pos"])
-    d_model = embed.shape[1]
-    hd = d_model // n_heads
-    scale = 1.0 / float(np.sqrt(hd))
+    spec = model_spec(params)
+    scale = 1.0 / float(np.sqrt(spec.head_dim))
     neg = jnp.finfo(jnp.float32).min * 0.7
-    causal = (
-        jnp.arange(plen)[:, None] >= jnp.arange(plen)[None, :]
-    )  # [P(q), P(k)]
-    h = embed[tokens] + posemb[:plen][None]
+    qi, ki = jnp.arange(plen)[:, None], jnp.arange(plen)[None, :]
+    causal = qi >= ki  # [P(q), P(k)]
+
+    def attend(li, q, k, v):
+        store(li, k, v)
+        window = spec.layer_window(li)
+        visible = causal & (ki > qi - window) if window else causal
+        # [B, n_kv, P, hd] — what the decode step's dense twin reads
+        kc = k.transpose(0, 2, 1, 3)
+        vc = v.transpose(0, 2, 1, 3)
+        qh = q.transpose(0, 2, 3, 1, 4)
+        s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) * scale
+        s = jnp.where(visible[None, None, None], s, neg)
+        att = jnp.einsum(
+            "bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc
+        )
+        return att.transpose(0, 3, 1, 2, 4).reshape(bsz, plen, -1)
+
+    h = _embed(spec, params, tokens, None)
+    positions = jnp.arange(plen)[None]
     for li, block in enumerate(params["blocks"]):
-        n_kv = _kv_heads(block, d_model, n_heads)
-        group = n_heads // n_kv
-        kv_d = n_kv * hd
-        with jax.named_scope("attn"):
-            x = _ln(h, block["ln1"])
-            qkv = x @ jnp.asarray(block["qkv"])
-            q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-            k = k.reshape(bsz, plen, n_kv, hd)
-            v = v.reshape(bsz, plen, n_kv, hd)
-            store(li, k, v)
-            # [B, n_kv, P, hd] — what the decode step's dense twin reads
-            kc = k.transpose(0, 2, 1, 3)
-            vc = v.transpose(0, 2, 1, 3)
-            qh = q.reshape(bsz, plen, n_kv, group, hd).transpose(
-                0, 2, 3, 1, 4
-            )
-            s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) * scale
-            s = jnp.where(causal[None, None, None], s, neg)
-            att = jnp.einsum(
-                "bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc
-            )
-            att = att.transpose(0, 3, 1, 2, 4).reshape(bsz, plen, d_model)
-            h = h + att @ jnp.asarray(block["proj"])
-        with jax.named_scope("mlp"):
-            hx = _ln(h, block["ln2"])
-            if "moe" in block:
-                h = h + moe_ffn(block["moe"], hx, k=moe_top_k)
-            else:
-                h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
-                    jnp.asarray(block["down"])
-                )
+        h = block_forward(
+            spec, li, block, h, positions, attend, moe_top_k
+        )
     with jax.named_scope("head"):
-        return _ln(h, params["ln_f"]) @ embed.T
+        return _head(spec, params, h)
 
 
 def transformer_prefill_chunk(params, tokens, positions, attend,
-                              moe_top_k: int = 1):
+                              moe_top_k: int = 1, head_at=None):
     """One CHUNK of a prompt through the block walk, with attention
     delegated — the mid-sequence sibling of :func:`transformer_step`
     (single token, cache owned by the caller) and
@@ -559,14 +824,16 @@ def transformer_prefill_chunk(params, tokens, positions, attend,
     residuals, head split) is token-local and identical to
     :func:`transformer_prefill`'s, so a prompt prefilled in chunks
     produces byte-identical k/v and logits to one dense pass. Returns
-    logits ``[B, C, vocab]``."""
+    logits ``[B, C, vocab]`` — or, with ``head_at`` (a traced row of the
+    chunk), ``[B, vocab]`` for that row alone: the output head is as wide
+    as the vocabulary, and a prefill needs it once."""
     import jax.numpy as jnp
 
     tokens = jnp.asarray(tokens, dtype=jnp.int32)
-    embed = jnp.asarray(params["embed"])
-    posemb = jnp.asarray(params["pos"])
-    h = embed[tokens] + posemb[positions][None]
-    return _chunk_blocks(params, h, attend, moe_top_k)
+    return _chunk_blocks(
+        params, tokens, jnp.asarray(positions)[None], attend, moe_top_k,
+        head_at,
+    )
 
 
 def transformer_verify_chunk(params, tokens, positions, attend,
@@ -586,49 +853,26 @@ def transformer_verify_chunk(params, tokens, positions, attend,
     import jax.numpy as jnp
 
     tokens = jnp.asarray(tokens, dtype=jnp.int32)
-    embed = jnp.asarray(params["embed"])
-    posemb = jnp.asarray(params["pos"])
-    h = embed[tokens] + posemb[positions]  # [B, C] positions -> [B, C, D]
-    return _chunk_blocks(params, h, attend, moe_top_k)
+    return _chunk_blocks(params, tokens, positions, attend, moe_top_k)
 
 
-def _chunk_blocks(params, h, attend, moe_top_k: int):
+def _chunk_blocks(params, tokens, positions, attend, moe_top_k: int,
+                  head_at=None):
     """The shared ``[B, C]`` delegated-attention block walk of the
     chunk family (:func:`transformer_prefill_chunk` /
     :func:`transformer_verify_chunk`) — one implementation so the
-    prefill-chunk and verify programs cannot drift apart."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel.moe import moe_ffn
-
-    bsz, clen, _ = h.shape
-    n_heads = params["n_heads"]
-    embed = jnp.asarray(params["embed"])
-    d_model = embed.shape[1]
-    hd = d_model // n_heads
+    prefill-chunk and verify programs cannot drift apart; the block is
+    :func:`block_forward`'s, like every walk's. ``positions`` is
+    ``[1, C]`` or ``[B, C]``."""
+    spec = model_spec(params)
+    h = _embed(spec, params, tokens, positions)
     for li, block in enumerate(params["blocks"]):
-        n_kv = _kv_heads(block, d_model, n_heads)
-        group = n_heads // n_kv
-        kv_d = n_kv * hd
-        x = _ln(h, block["ln1"])
-        qkv = x @ jnp.asarray(block["qkv"])
-        q, k, v = jnp.split(qkv, [d_model, d_model + kv_d], axis=-1)
-        att = attend(
-            li,
-            q.reshape(bsz, clen, n_kv, group, hd),
-            k.reshape(bsz, clen, n_kv, hd),
-            v.reshape(bsz, clen, n_kv, hd),
+        h = block_forward(
+            spec, li, block, h, positions, attend, moe_top_k
         )
-        h = h + att @ jnp.asarray(block["proj"])
-        hx = _ln(h, block["ln2"])
-        if "moe" in block:
-            h = h + moe_ffn(block["moe"], hx, k=moe_top_k)
-        else:
-            h = h + jax.nn.gelu(hx @ jnp.asarray(block["up"])) @ (
-                jnp.asarray(block["down"])
-            )
-    return _ln(h, params["ln_f"]) @ embed.T
+    if head_at is not None:
+        h = h[:, head_at]
+    return _head(spec, params, h)
 
 
 def transformer_tp_specs(params, axis: str = "tp"):
@@ -757,16 +1001,13 @@ def transformer_generate(
             f"max_new_tokens must be >= 1; got {max_new_tokens}"
         )
     bsz, plen = prompt.shape
-    n_heads = params["n_heads"]
-    embed = jnp.asarray(params["embed"])
-    posemb = jnp.asarray(params["pos"])
-    d_model = embed.shape[1]
-    hd = d_model // n_heads
+    spec = model_spec(params)
+    n_kv, hd = spec.n_kv_heads, spec.head_dim
     total = plen + max_new_tokens
-    if total > posemb.shape[0]:
+    if total > spec.max_len:
         raise ValueError(
             f"prompt ({plen}) + max_new_tokens ({max_new_tokens}) = "
-            f"{total} exceeds max_len {posemb.shape[0]}"
+            f"{total} exceeds max_len {spec.max_len}"
         )
     blocks = params["blocks"]
     scale = 1.0 / float(np.sqrt(hd))
@@ -781,7 +1022,6 @@ def transformer_generate(
 
     # GQA: the cache stores only the model's n_kv k/v heads — the decode
     # memory ceiling shrinks by the group factor (n_kv == n_heads for MHA)
-    n_kv = _kv_heads(blocks[0], d_model, n_heads)
     k0 = jnp.zeros((len(blocks), bsz, n_kv, total, hd), jnp.float32)
     v0 = jnp.zeros_like(k0)
 
@@ -813,10 +1053,13 @@ def transformer_generate(
                 caches[1], v.reshape(1, bsz, n_kv, 1, hd), (li, 0, 0, t, 0)
             )
             s = jnp.einsum("bkgd,bktd->bkgt", q, caches[0][li]) * scale
-            s = jnp.where(visible[:, None, None, :], s, neg)
+            seen = visible
+            if spec.layer_window(li):
+                seen = seen & (slots > t - spec.layer_window(li))
+            s = jnp.where(seen[:, None, None, :], s, neg)
             return jnp.einsum(
                 "bkgt,bktd->bkgd", jax.nn.softmax(s, axis=-1), caches[1][li]
-            ).reshape(bsz, d_model)
+            ).reshape(bsz, -1)
 
         # per-row position offset: a left-padded row's token at slot t sits
         # at real position t - offset (pad slots gather position 0; they
@@ -947,13 +1190,11 @@ class TransformerLM:
         (the reference rode Spark's task retry instead, SURVEY §5)."""
         import jax
 
-        static = self.params["n_heads"]
-        p = {k: v for k, v in self.params.items() if k != "n_heads"}
+        static = static_entries(self.params)
+        p = device_tree(self.params)
 
         def loss_fn(p_, toks_):
-            return transformer_loss(
-                {**p_, "n_heads": static}, toks_, **loss_kwargs
-            )
+            return transformer_loss({**p_, **static}, toks_, **loss_kwargs)
 
         def step(p_, toks_):
             loss, grads = jax.value_and_grad(loss_fn)(p_, toks_)
@@ -974,7 +1215,7 @@ class TransformerLM:
             on_step=on_step,
             place_restored=place_restored,
         )
-        self.params = {**jax.device_get(p), "n_heads": static}
+        self.params = {**jax.device_get(p), **static}
         return losses
 
     def fit(
@@ -1423,11 +1664,11 @@ class TransformerLM:
         if run is not None:
             cache.move_to_end(key)
         else:
-            static = self.params["n_heads"]
+            static = static_entries(self.params)
 
             def impl(p, prompt_arr, seed_arr, temp_arr, top_p_arr, lens):
                 return transformer_generate(
-                    {**p, "n_heads": static},
+                    {**p, **static},
                     prompt_arr,
                     max_new_tokens,
                     temperature=temp_arr if sampled else 0.0,
@@ -1446,12 +1687,9 @@ class TransformerLM:
         # exactly one generation's weights are ever pinned)
         dev = getattr(self, "_generate_params", None)
         if dev is None or dev[0] is not self.params:
-            host = {
-                k: v for k, v in self.params.items() if k != "n_heads"
-            }
             dev = self._generate_params = (
                 self.params,
-                jax.device_put(host),
+                jax.device_put(device_tree(self.params)),
             )
         return np.asarray(
             run(

@@ -6,6 +6,7 @@ from .attention import (
     flash_attention,
     online_block_update,
     paged_attention,
+    paged_attention_live,
     paged_page_size_hint,
     ragged_paged_attention,
 )
@@ -16,6 +17,7 @@ __all__ = [
     "flash_attention",
     "attention_reference",
     "paged_attention",
+    "paged_attention_live",
     "ragged_paged_attention",
     "paged_page_size_hint",
     "online_block_update",

@@ -487,6 +487,132 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, layer=None):
     return _heads_off_lanes(out, n_kv, hd)
 
 
+#: table rows :func:`paged_attention_live` walks at a time, about
+LIVE_BLOCK_PAGES = 64
+
+
+def live_read_blocks(rows: int, block_pages: int = LIVE_BLOCK_PAGES):
+    """``(blocks, rows per block)`` of a ``rows``-row page table under
+    :func:`paged_attention_live`: blocks of about ``block_pages`` rows,
+    all alike — a window's table (66 rows at a window of 1,024 and pages
+    of 16) is one block."""
+    n_blocks = max(1, rows // int(block_pages))
+    return n_blocks, -(-rows // n_blocks)
+
+
+def paged_attention_live(
+    q, k_pages, v_pages, table, first, q_pos, lengths, layer,
+    window: int = 0, block_pages: int = LIVE_BLOCK_PAGES,
+):
+    """The paged read bounded by what is live, for one query per slot or
+    a span of them: attention of ``q`` ``[S, C, n_kv, group, hd]`` at
+    absolute positions ``q_pos`` ``[S, C]`` over the keys each slot's
+    ``table`` ``[S, T]`` names in layer ``layer`` of the whole pool
+    ``[n_layers, pool_pages, page_size, n_kv * hd]``.
+
+    Row ``r`` of a slot's table is its position-page ``first[s] + r``
+    (``first`` ``[S]``: 0 for a kind that keeps every page, the first
+    page still held for a window kind, whose table is as wide as the
+    window and no wider); key ``j`` of slot ``s`` exists iff ``j <
+    lengths[s]`` and is visible to the query at ``p`` iff ``j <= p``
+    and, with a ``window``, ``p - window < j``.
+
+    The table is walked ``block_pages`` rows at a time under the
+    online-softmax recurrence, and the walk stops at the block that
+    holds the longest slot's last live key: the trip count is data, so
+    one program serves every mix of lengths, and a step moves the K and
+    V of the pages that are live (rows past a slot's own end name the
+    trash page: one page, read again and again, and masked) instead of
+    ``max_seq_len`` positions for every slot. No ``[S, T * page_size]``
+    copy of a slot's whole table is ever made, and the scores of a span
+    exist one block at a time. Scores, softmax and the accumulator are
+    float32 whatever the pool holds. Returns ``[S, C, n_kv * group *
+    hd]`` float32."""
+    slots, c, n_kv, group, hd = q.shape
+    ps = k_pages.shape[-2]
+    t = table.shape[1]
+    n_blocks, bp = live_read_blocks(t, block_pages)
+    if n_blocks * bp != t:  # whole blocks: the tail rows name nothing
+        table = jnp.pad(
+            table, ((0, 0), (0, n_blocks * bp - t)), mode="edge"
+        )
+    scale = 1.0 / float(np.sqrt(hd))
+    span = bp * ps
+    in_block = jnp.arange(span, dtype=jnp.int32)
+    # one query a slot: the products run ON the rows' merged lanes, the
+    # query laid over its head's lanes (``paged_attention``'s form: all
+    # heads against a slot's block in one MXU product, and the gathered
+    # block is never re-tiled from 512 lanes into 4 x 128). A span of
+    # queries splits the lanes into heads instead: four times fewer
+    # FLOPs, and the re-tiling is shared by the span's rows.
+    merged = c == 1
+    if merged:
+        qc = _heads_on_lanes(q[:, 0]).astype(k_pages.dtype)  # [S, H, lanes]
+    else:
+        qc = q.astype(k_pages.dtype)
+
+    def block(j, carry):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(table, j * bp, bp, axis=1)
+        kb = k_pages[layer, rows].reshape(slots, span, n_kv * hd)
+        vb = v_pages[layer, rows].reshape(slots, span, n_kv * hd)
+        k_pos = (first[:, None] + j * bp) * ps + in_block[None, :]  # [S, T]
+        if merged:
+            s = jnp.einsum(
+                "shc,stc->sht", qc, kb, preferred_element_type=jnp.float32
+            ).reshape(slots, 1, n_kv, group, span) * scale
+        else:
+            kb = kb.reshape(slots, span, n_kv, hd)
+            vb = vb.reshape(slots, span, n_kv, hd)
+            s = jnp.einsum(
+                "scngd,stnd->scngt", qc, kb,
+                preferred_element_type=jnp.float32,
+            ) * scale
+        seen = (k_pos[:, None, :] <= q_pos[:, :, None]) & (
+            k_pos < lengths[:, None]
+        )[:, None, :]
+        if window:
+            seen &= k_pos[:, None, :] > q_pos[:, :, None] - window
+        seen = seen[:, :, None, None, :]
+        m_new = jnp.maximum(
+            m, jnp.max(jnp.where(seen, s, _NEG_BIG), axis=-1)
+        )
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(axis=-1)
+        if merged:  # every lane for every head; each keeps its own
+            ctx = _heads_off_lanes(
+                jnp.einsum(
+                    "sht,stc->shc",
+                    p.reshape(slots, n_kv * group, span).astype(vb.dtype),
+                    vb, preferred_element_type=jnp.float32,
+                ),
+                n_kv, hd,
+            )[:, None]
+        else:
+            ctx = jnp.einsum(
+                "scngt,stnd->scngd", p.astype(vb.dtype), vb,
+                preferred_element_type=jnp.float32,
+            )
+        return m_new, l, alpha[..., None] * acc + ctx
+
+    stat = (slots, c, n_kv, group)
+    carry = (
+        jnp.full(stat, _NEG_BIG, jnp.float32),
+        jnp.zeros(stat, jnp.float32),
+        jnp.zeros(stat + (hd,), jnp.float32),
+    )
+    if n_blocks == 1:
+        carry = block(0, carry)
+    else:
+        live = jnp.max(lengths - first * ps)  # keys past the first row
+        trips = jnp.clip(-(-live // span), 1, n_blocks)
+        carry = jax.lax.fori_loop(0, trips, block, carry)
+    _, l, acc = carry
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.reshape(slots, c, n_kv * group * hd)
+
+
 def paged_page_size_hint(dtype, head_dim: int) -> int:
     """The measured-best key-tile width for the fused paged read, from
     the flash sweep's ``_BEST_BLOCKS``: the ragged kernel's key tile IS

@@ -29,6 +29,7 @@ from jax.lax import axis_size as _axis_size
 __all__ = [
     "init_moe",
     "moe_ffn",
+    "moe_grouped",
     "moe_ffn_sharded",
     "moe_apply",
     "moe_dispatch_apply",
@@ -73,17 +74,22 @@ def _expert_partials(params, x, expert_offset, gates, expert_ids):
     # jnp-ify once: the loop indexes the expert axis with a traced index,
     # which raw numpy arrays cannot do
     w_up_all = jnp.asarray(params["w_up"])
-    b_up_all = jnp.asarray(params["b_up"])
     w_down_all = jnp.asarray(params["w_down"])
-    b_down_all = jnp.asarray(params["b_down"])
+    gated = "w_gate" in params  # SiLU-gated experts carry no bias
+    if gated:
+        w_gate_all = jnp.asarray(params["w_gate"])
+    else:
+        b_up_all = jnp.asarray(params["b_up"])
+        b_down_all = jnp.asarray(params["b_down"])
 
     def one_expert(e_local, acc):
         w_up = w_up_all[e_local]
-        b_up = b_up_all[e_local]
         w_down = w_down_all[e_local]
-        b_down = b_down_all[e_local]
-        h = jax.nn.gelu(x @ w_up + b_up)
-        y = h @ w_down + b_down
+        if gated:
+            y = (jax.nn.silu(x @ w_gate_all[e_local]) * (x @ w_up)) @ w_down
+        else:
+            h = jax.nn.gelu(x @ w_up + b_up_all[e_local])
+            y = h @ w_down + b_down_all[e_local]
         mask = (expert_ids == e_local + expert_offset).astype(x.dtype)
         combined_gate = (gates * mask).sum(axis=-1)  # over the k slots
         return acc + y * combined_gate[..., None]
@@ -113,6 +119,82 @@ def _route_topk(params, x, k):
     if k > 1:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates, expert_ids
+
+
+def moe_grouped(params: Params, x, k: int, expert_offset: int = 0):
+    """The dropless grouped expert path: every token's ``k`` experts and
+    no others, whatever the routing.
+
+    Route over the router's whole width (softmax in float32 from a
+    product at ``highest`` precision, the ``k`` largest, renormalised to
+    sum 1 for ``k > 1``); sort the token-expert pairs by expert; one
+    grouped product per weight over the experts HELD
+    (``ops.grouped.grouped_matmul``: each expert's rows padded to whole
+    tiles, a tile meets ``w[e]`` and no other, so the FLOPs are the
+    pairs' and their padding's, not experts x tokens, and an expert's
+    weight is read once); weigh each pair by its gate and sum a token's
+    pairs. Nothing is dropped
+    and no capacity is set: an expert with every token and one with none
+    are group sizes like any other.
+
+    ``params`` hold the experts this chip holds on their leading axis —
+    global experts ``expert_offset .. expert_offset + n_local`` — and the
+    router at its published width; pairs routed elsewhere add nothing,
+    so the shares of a layer divided over chips add up to the layer
+    (:func:`_expert_partials`' signature, the masked oracle's). Experts
+    are SiLU-gated (``w_gate``, ``w_up``, ``w_down``); the GELU pair with
+    biases that ``init_moe`` builds is served by :func:`moe_ffn`.
+    ``x`` is ``[..., D]``. Returns
+    ``(y [..., D] float32, counts [n_local] int32)``, ``counts`` the
+    pairs each held expert got."""
+    import jax
+    import jax.numpy as jnp
+
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    t = xt.shape[0]
+    w_up = jnp.asarray(params["w_up"])
+    w_down = jnp.asarray(params["w_down"])
+    n_local = w_up.shape[0]
+    router = jnp.asarray(params["router"])
+    if not 1 <= k <= router.shape[-1]:
+        raise ValueError(f"k={k} must be in [1, {router.shape[-1]}]")
+    logits = jnp.dot(
+        xt.astype(jnp.float32), router.astype(jnp.float32),
+        precision="highest",
+    )
+    gates, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if k > 1:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    # every expert's pairs in whole tiles of rows, one expert to a tile
+    # (ops/grouped.py): the rows of expert e meet w[e] and no other
+    from ..ops.grouped import grouped_matmul, tile_layout
+
+    local = ids.reshape(-1) - expert_offset
+    held = (local >= 0) & (local < n_local)
+    pairs = t * k
+    tile_rows = 16 if pairs <= 2048 else 128
+    row, tile_expert, n_active, sizes = tile_layout(
+        jnp.where(held, local, n_local), n_local, tile_rows
+    )
+    n_rows = tile_expert.shape[0] * tile_rows
+    # the padded rows' tokens: a row no pair sits at reads token 0 and
+    # is read by nobody
+    token = jnp.zeros((n_rows + 1,), jnp.int32).at[row].set(
+        jnp.arange(pairs, dtype=jnp.int32) // k
+    )[:n_rows]
+    rows = xt[token].astype(w_up.dtype)  # [rows, d]
+
+    def grouped(a, w):
+        return grouped_matmul(a, w, tile_expert, n_active, tile_rows)
+
+    h = jax.nn.silu(grouped(rows, jnp.asarray(params["w_gate"])))
+    y = grouped(h * grouped(rows, w_up), w_down)
+    # back to token order: pair j of token i sits at row[i * k + j]
+    mine = y[jnp.minimum(row, n_rows - 1)].reshape(t, k, d)
+    mine = jnp.where(held.reshape(t, k, 1), mine, 0.0)
+    out = (mine * gates[..., None]).sum(axis=1)
+    return out.reshape(lead + (d,)), sizes
 
 
 def moe_ffn(params: Params, x, k: int = 1):

@@ -71,7 +71,10 @@ import numpy as np
 
 from ..models.transformer import (
     _kv_heads,
+    device_tree,
     filter_logits,
+    model_spec,
+    static_entries,
     transformer_prefill,
     transformer_prefill_chunk,
     transformer_step,
@@ -105,8 +108,10 @@ from ..utils.failures import (
 from ..utils.logging import get_logger
 from . import tenancy as _tenancy
 from .kv_pages import (
+    CacheLayout,
     PagePool,
     PrefixCache,
+    SequencePages,
     pages_needed,
     read_pages,
     split_heads,
@@ -228,6 +233,30 @@ _m_recomputed = _counter(
     "a preemption had released the pages that held them (the cost of "
     "failures.preemptions_total's events)",
 )
+_m_kind_pages = _gauge(
+    "serve.pages_in_use_by_kind",
+    "KV pool pages the live sequences' cache kinds hold (\"full\": layers "
+    "that keep every position; \"window\": layers that keep the last "
+    "window's)",
+    labels=("kind",),
+)
+_m_window_released = _counter(
+    "serve.window_pages_released_total",
+    "Pool pages window layers gave back before their sequence ended: "
+    "every position in them was more than the window behind the next "
+    "query",
+)
+_m_window_allocated = _counter(
+    "serve.window_pages_allocated_total",
+    "Pool pages taken for window layers (what "
+    "serve.window_pages_released_total is a share of)",
+)
+_m_tokens_routed = _counter(
+    "moe.tokens_routed_total",
+    "Token-expert pairs the serving step programs put through an expert "
+    "layer (tokens x experts per token x expert layers; padding rows of "
+    "a prefill chunk not counted)",
+)
 _m_collective_s = _counter(
     "serve.collective_seconds",
     "ESTIMATED wall seconds spent in cross-chip collectives by the "
@@ -236,6 +265,13 @@ _m_collective_s = _counter(
     "the real gathers overlap compute inside the compiled step)",
 )
 
+
+#: a model whose sequences can be longer than this is prefilled in
+#: chunks of ``_AUTO_CHUNK_TOKENS`` unless the caller, the Config or the
+#: tune store chose otherwise: the one-pass program is as wide as the
+#: longest sequence and scores it densely
+_AUTO_CHUNK_ABOVE = 2048
+_AUTO_CHUNK_TOKENS = 1024
 
 _engine_seq_lock = threading.Lock()
 _engine_seq = 0
@@ -431,7 +467,7 @@ class GenerationEngine:
         queue_capacity: int = 64,
         top_k: int = 0,
         eos_id: Optional[int] = None,
-        moe_top_k: int = 1,
+        moe_top_k: Optional[int] = None,
         attention_impl: Optional[str] = None,
         prefill_chunk_tokens: Optional[int] = None,
         prefix_cache: Optional[bool] = None,
@@ -443,16 +479,27 @@ class GenerationEngine:
         import jax
 
         params = getattr(model, "params", model)
-        n_heads = params["n_heads"]
+        #: the model description (``models.transformer.ModelSpec``): the
+        #: tree's own where it carries one, else what a GPT-2-style
+        #: tree's weights imply
+        spec = self.spec = model_spec(params)
+        n_heads, n_kv, hd = spec.n_heads, spec.n_kv_heads, spec.head_dim
         d_model = int(np.shape(params["embed"])[1])
-        hd = d_model // n_heads
-        n_kv = _kv_heads(params["blocks"][0], d_model, n_heads)
-        model_max = int(np.shape(params["pos"])[0])
+        model_max = spec.max_len
+        if moe_top_k is None:
+            moe_top_k = spec.experts_per_token or 1
+        windowed = "window" in spec.kinds
+        if windowed and (mesh is not None or draft_params is not None):
+            raise ValueError(
+                "a model with window layers runs on one chip without "
+                "speculation: the tensor-parallel and the draft/verify "
+                "programs have no window mask and no second cache kind"
+            )
         self.max_seq_len = int(max_seq_len or model_max)
         if self.max_seq_len > model_max:
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} exceeds the model's "
-                f"positional table ({model_max})"
+                f"longest sequence ({model_max})"
             )
         # dtype only — never np.asarray the embed table (that would
         # d2h-copy the whole embedding just to read one attribute)
@@ -556,14 +603,6 @@ class GenerationEngine:
             kv_sharding = NamedSharding(mesh, tp_kv_specs(self._tp_axis))
         elif self._device is not None:
             kv_sharding = jax.sharding.SingleDeviceSharding(self._device)
-        self.pool = PagePool(
-            n_layers=len(params["blocks"]),
-            n_kv_heads=n_kv,
-            head_dim=hd,
-            num_pages=num_pages,
-            page_size=self.page_size,
-            sharding=kv_sharding,
-        )
         cfg = get_config()
         if attention_impl is None:
             attention_impl = cfg.serve_attention_impl
@@ -589,19 +628,73 @@ class GenerationEngine:
                 f"prefill_chunk_tokens must be >= 0; got "
                 f"{prefill_chunk_tokens}"
             )
+        #: the engine chose chunking itself, from the model's longest
+        #: sequence: EVERY prompt then goes through the chunk program
+        #: (the one-pass program would be ``max_seq_len`` wide and is
+        #: never built), the chunk and decode programs read the pool
+        #: bounded by what is live, and :meth:`start` builds both
+        #: before any traffic
+        self._long = prefill_chunk_tokens == 0 and (
+            self.max_seq_len > _AUTO_CHUNK_ABOVE
+        )
+        if self._long:
+            prefill_chunk_tokens = _AUTO_CHUNK_TOKENS
+        if windowed:
+            # window layers are prefilled chunk by chunk, whole pages
+            # at a time, releasing pages behind the window as they go
+            self._long = True
+            ps = self.page_size
+            prefill_chunk_tokens = ps * max(
+                1,
+                min(
+                    prefill_chunk_tokens or _AUTO_CHUNK_TOKENS,
+                    self.max_seq_len,
+                ) // ps,
+            )
         self.prefill_chunk_tokens = int(prefill_chunk_tokens)
         #: the chunk program's STATIC width: the chunk size when chunked
         #: prefill is on, else the full prompt row (the prefix-cache
         #: resume path then runs as one "chunk" mid-sequence)
         self._chunk_c = self.prefill_chunk_tokens or self.max_seq_len
+        n_layers = len(params["blocks"])
+        #: cache kinds (``serve/kv_pages.py``): None for a model whose
+        #: layers all keep every position — one kind, a pool page as
+        #: deep as the model, today's pool to the letter
+        self.layout: Optional[CacheLayout] = None
+        if windowed:
+            self.layout = CacheLayout.of(
+                spec.layer_types, spec.window, self.page_size,
+                lookahead=self._chunk_c,
+            )
+        #: how the live-read programs find a layer in the pool: the
+        #: cache kinds, or the one kind of a model whose layers are alike
+        self._pool_layout = self.layout or CacheLayout.of(
+            ("full",) * n_layers, 0, self.page_size
+        )
+        self.pool = PagePool(
+            n_layers=self._pool_layout.depth,
+            n_kv_heads=n_kv,
+            head_dim=hd,
+            num_pages=num_pages,
+            page_size=self.page_size,
+            # a GPT-2-style tree keeps the float32 pool it always had; a
+            # tree with a description states its cache with its weights
+            dtype=kv_dtype if "spec" in params else None,
+            sharding=kv_sharding,
+        )
         if prefix_cache is None:
-            prefix_cache = cfg.serve_prefix_cache
+            prefix_cache = cfg.serve_prefix_cache and not windowed
+        if prefix_cache and windowed:
+            raise ValueError(
+                "the prefix cache shares pages of one cache kind; a model "
+                "with window layers runs without it"
+            )
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.pool) if prefix_cache else None
         )
         self.scheduler = Scheduler(
             self.pool, self.max_slots, queue_capacity, self.max_seq_len,
-            prefix_cache=self.prefix_cache,
+            prefix_cache=self.prefix_cache, layout=self.layout,
         )
         self.top_k = int(top_k)
         self.eos_id = eos_id
@@ -673,8 +766,19 @@ class GenerationEngine:
         # hidden rows — per-chip weight HBM scales ~1/N); the step
         # programs gather shards back to bit-exact full weights inside
         # the mesh (serve/tp.py).
+        #: token-expert pairs one token makes through the whole model
+        self._pairs_per_token = (
+            spec.experts_per_token
+            * sum("moe" in b for b in params["blocks"])
+            if spec.mlp == "gated_experts" else 0
+        )
+        #: window pages counted into the counters so far
+        self._window_counted = [0, 0]
         self._host_params = params
-        host = {k: v for k, v in params.items() if k != "n_heads"}
+        host = device_tree(params)
+        #: the tree's non-array entries, merged back into the weight
+        #: argument inside every step program
+        self._static = static_entries(params)
         self._tp_param_specs = None
         if mesh is not None:
             from jax.sharding import NamedSharding
@@ -739,8 +843,12 @@ class GenerationEngine:
             )
         else:
             prefill_fn = self._prefill_impl(n_heads, moe_top_k)
-            decode_fn = self._decode_impl(n_heads, moe_top_k)
-            chunk_fn = self._prefill_chunk_impl(n_heads, moe_top_k)
+            if self._long:
+                decode_fn = self._decode_live_impl(moe_top_k)
+                chunk_fn = self._chunk_live_impl(moe_top_k)
+            else:
+                decode_fn = self._decode_impl(n_heads, moe_top_k)
+                chunk_fn = self._prefill_chunk_impl(n_heads, moe_top_k)
             verify_fn = (
                 self._verify_impl(n_heads, moe_top_k)
                 if self.draft_len
@@ -954,7 +1062,7 @@ class GenerationEngine:
         top_k = self.top_k
 
         def prefill(p, kp, vp, prompt, length, ptab, temp, seed, top_p):
-            full = {**p, "n_heads": n_heads}
+            full = {**p, **self._static}
             state = [kp, vp]
 
             def store(li, k, v):
@@ -1018,7 +1126,7 @@ class GenerationEngine:
             p, kp, vp, chunk, start, valid, total_len, ptab, temp, seed,
             top_p,
         ):
-            full = {**p, "n_heads": n_heads}
+            full = {**p, **self._static}
             c = chunk.shape[1]
             offs = jnp.arange(c)
             pos = start + offs  # absolute positions; tail is padding
@@ -1084,7 +1192,7 @@ class GenerationEngine:
         fused = self.attention_impl == "fused"
 
         def decode(p, kp, vp, toks, positions, ptabs, temps, seeds, top_ps):
-            full = {**p, "n_heads": n_heads}
+            full = {**p, **self._static}
             slots = toks.shape[0]
             state = [kp, vp]
             page = ptabs[jnp.arange(slots), positions // ps]
@@ -1117,7 +1225,7 @@ class GenerationEngine:
                     state[0], state[1] = write(state[0], state[1], li, k, v)
                 with jax.named_scope("kv_read"):
                     ctx = read(state[0], state[1], li, q)
-                return ctx.reshape(slots, d_model)
+                return ctx.reshape(slots, -1)
 
             logits = transformer_step(
                 full, toks, positions, attend, moe_top_k=moe_top_k
@@ -1140,6 +1248,221 @@ class GenerationEngine:
             return state[0], state[1], nxt
 
         return decode
+
+    def _kind_views(self):
+        """``view(tabs, li) -> (pool row, [.., T] table, kind index)`` for
+        the live-read programs: which row of a pool page holds layer
+        ``li`` and which column of its kind's table names that page. A
+        model of one kind has one table and ``li`` is the row."""
+        layout = self._pool_layout
+
+        def view(tabs, li):
+            ki, sub, row = layout.locate(li)
+            tab = tabs[layout.kinds[ki].name]
+            if layout.kinds[ki].units > 1:
+                tab = tab[..., sub]
+            return row, tab, ki
+
+        return view
+
+    def _kind_widths(self, span: int) -> Dict[str, int]:
+        """Rows of each kind's page table in a program whose queries
+        span ``span`` positions: every page of the longest sequence for
+        a kind that keeps them all, the window's and the span's for a
+        window kind."""
+        return {
+            k.name: (
+                min(
+                    self._max_pages,
+                    pages_needed(k.window + span, self.page_size) + 1,
+                )
+                if k.window
+                else self._max_pages
+            )
+            for k in self._pool_layout.kinds
+        }
+
+    def _decode_live_impl(self, moe_top_k: int):
+        """The decode program of a long-sequence model: the block walk
+        and the sampling of :meth:`_decode_impl`, with the read bounded
+        by what is live (``ops.paged_attention_live``: a window layer's
+        table is the window's pages, a full layer's walk stops at the
+        longest slot's last page), the window mask in that read, one
+        page table per cache kind, and the expert layers' routing
+        counts packed behind the tokens in the one array the host reads
+        back."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.attention import paged_attention_live
+
+        ps = self.page_size
+        top_k = self.top_k
+        spec = self.spec
+        view = self._kind_views()
+
+        def decode(p, kp, vp, toks, positions, tabs, temps, seeds, top_ps):
+            full = {**p, **self._static}
+            slots = toks.shape[0]
+            state = [kp, vp]
+            lane = jnp.arange(slots)
+            off = positions % ps
+            q_pos = positions[:, None]
+            routed: List = []
+
+            def attend(li, q, k, v):
+                row, tab, ki = view(tabs, li)
+                first = tabs["first"][:, ki]
+                at = jnp.clip(positions // ps - first, 0, tab.shape[1] - 1)
+                page = tab[lane, at]
+                with jax.named_scope("kv_write"):
+                    state[0] = write_rows(
+                        state[0], row, page, off, k.astype(kp.dtype)
+                    )
+                    state[1] = write_rows(
+                        state[1], row, page, off, v.astype(vp.dtype)
+                    )
+                with jax.named_scope("kv_read"):
+                    return paged_attention_live(
+                        q[:, None], state[0], state[1], tab, first, q_pos,
+                        positions + 1, row, window=spec.layer_window(li),
+                    )[:, 0]
+
+            logits = transformer_step(
+                full, toks, positions, attend, moe_top_k=moe_top_k,
+                routed=routed,
+            )
+            with jax.named_scope("sample"):
+                # the sampled rule sorts the whole vocabulary for every
+                # slot; a batch of greedy requests takes the argmax
+                nxt = jax.lax.cond(
+                    jnp.any(temps > 0),
+                    lambda: _sample_slot_tokens(
+                        logits, positions, temps, seeds, top_ps, top_k
+                    ),
+                    lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                )
+            if routed:  # [layers, experts] pair counts ride the readback
+                nxt = jnp.concatenate(
+                    [nxt, jnp.stack(routed).reshape(-1).astype(jnp.int32)]
+                )
+            return state[0], state[1], nxt
+
+        return decode
+
+    def _chunk_live_impl(self, moe_top_k: int):
+        """The prefill-chunk program of a long-sequence model: one
+        page-aligned ``[1, C]`` span at ``start``, its K and V written a
+        whole page at a time (pages wholly past the real tokens go to
+        the trash page; the page the prompt ends inside takes the
+        padding rows too, which decode overwrites before any read
+        unmasks them), its queries read against the pages written so
+        far by ``ops.paged_attention_live`` — a window layer's the
+        window's and the chunk's own, a full layer's up to the chunk's
+        end — under the causal and the window mask."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.attention import paged_attention_live
+
+        ps = self.page_size
+        trash = self.pool.trash_page
+        top_k = self.top_k
+        max_len = self.max_seq_len
+        spec = self.spec
+        view = self._kind_views()
+
+        def chunk_step(
+            p, kp, vp, chunk, start, valid, total_len, tabs, temp, seed,
+            top_p,
+        ):
+            full = {**p, **self._static}
+            c = chunk.shape[1]
+            pos = start + jnp.arange(c)  # absolute; the tail is padding
+            pos_c = jnp.minimum(pos, max_len - 1)
+            slab = jnp.arange(c // ps)
+            state = [kp, vp]
+            end = (start + valid)[None]
+
+            def attend(li, q, k, v):
+                row, tab, ki = view(tabs, li)
+                first = tabs["first"][:, ki]
+                at = jnp.clip(
+                    start // ps + slab - first[0], 0, tab.shape[1] - 1
+                )
+                page = jnp.where(slab * ps < valid, tab[0, at], trash)
+                width = state[0].shape[-1]
+                with jax.named_scope("kv_write"):
+                    for i, rows in enumerate((k, v)):
+                        state[i] = state[i].at[row, page].set(
+                            rows.astype(state[i].dtype).reshape(
+                                c // ps, ps, width
+                            )
+                        )
+                with jax.named_scope("kv_read"):
+                    return paged_attention_live(
+                        q, state[0], state[1], tab, first, pos[None],
+                        end, row, window=spec.layer_window(li),
+                    )
+
+            last = transformer_prefill_chunk(
+                full, chunk, pos_c, attend, moe_top_k=moe_top_k,
+                head_at=valid - 1,
+            )[0]
+            tok = _sample_slot_tokens(
+                last[None], (total_len - 1)[None], temp[None], seed[None],
+                top_p[None], top_k,
+            )[0]
+            return state[0], state[1], tok
+
+        return chunk_step
+
+    def _warm_programs(self) -> None:
+        """Build the chunk and the decode program before any traffic
+        (:meth:`start` of a long-sequence engine): one dispatch of each
+        with every table naming the trash page, so nothing a request
+        will read is written. At these widths a program takes from tens
+        of seconds to minutes to build, and a build inside traffic
+        stalls every stream for that long."""
+        import jax
+
+        pool = self.pool
+        chunk = self._chunk_args(
+            np.zeros(self._chunk_c, np.int32), 0, 1, 1,
+            SequencePages(pool, self.layout), 0.0, 0, 1.0,
+        )
+        self._record_program(
+            "prefill_chunk", self._params_dev, pool.k, *chunk
+        )
+        pool.k, pool.v, _ = jax.block_until_ready(
+            self._prefill_chunk_jit(self._params_dev, pool.k, pool.v, *chunk)
+        )
+        args = self._decode_args([])
+        self._record_program("decode", self._params_dev, pool.k, *args)
+        pool.k, pool.v, _ = jax.block_until_ready(
+            self._decode_jit(self._params_dev, pool.k, pool.v, *args)
+        )
+
+    def _tables(self, seqs, span: int):
+        """The page tables of ``seqs`` (None = an idle slot, every row
+        the trash page) for a live-read program whose queries span
+        ``span`` positions: ``{kind: [S, T] or [S, T, units] int32,
+        "first": [S, kinds] int32}``."""
+        kinds = self._pool_layout.kinds
+        widths = self._kind_widths(span)
+        out = {"first": np.zeros((len(seqs), len(kinds)), np.int32)}
+        for ki, kind in enumerate(kinds):
+            width = widths[kind.name]
+            shape = (len(seqs), width) + (
+                (kind.units,) if kind.units > 1 else ()
+            )
+            tab = np.full(shape, self.pool.trash_page, np.int32)
+            for i, seq in enumerate(seqs):
+                if seq is not None:
+                    tab[i] = seq.table(width, ki)
+                    out["first"][i, ki] = seq.first[ki]
+            out[kind.name] = tab
+        return out
 
     def _verify_impl(self, n_heads: int, moe_top_k: int):
         """The VERIFY step — the engine's fourth compiled program, the
@@ -1166,7 +1489,7 @@ class GenerationEngine:
         def verify(
             p, kp, vp, toks, starts, n_valid, ptabs, temps, seeds, top_ps
         ):
-            full = {**p, "n_heads": n_heads}
+            full = {**p, **self._static}
             slots = toks.shape[0]
             pos = starts[:, None] + jnp.arange(c)[None, :]  # [S, C]
             pos_c = jnp.clip(pos, 0, max_len - 1)
@@ -1685,7 +2008,7 @@ class GenerationEngine:
             # degrade, the verify pass still decides every byte
             act.draft_pos = act.cached_tokens
         chunking = self.prefill_chunk_tokens > 0
-        if act.cached_tokens > 0 or (
+        if act.cached_tokens > 0 or self._long or (
             chunking and plen > self.prefill_chunk_tokens
         ):
             self._apply_cow(act)
@@ -1732,18 +2055,24 @@ class GenerationEngine:
         start = act.prefill_pos
         c = self._chunk_c
         valid = min(c, plen - start)
-        chunk_row = np.zeros((1, c), np.int32)
-        chunk_row[0, :valid] = req.prompt[start : start + valid]
-        ptab = act.seq.table(self._max_pages)
-        args = (
-            chunk_row,
-            np.int32(start),
-            np.int32(valid),
-            np.int32(plen),
-            ptab,
-            np.float32(req.temperature),
-            np.int32(req.seed),
-            np.float32(req.top_p),
+        if self._long:
+            if start % self.page_size:
+                # the live chunk program writes whole pages; a prefix
+                # hit that ends inside one starts over at its first row
+                start -= start % self.page_size
+                valid = min(c, plen - start)
+            try:
+                # window kinds give back what fell behind this chunk's
+                # first query, then take the chunk's own pages: as many
+                # as were just freed, once the window is full
+                act.seq.advance(start)
+                act.seq.ensure(start + valid)
+            except PagePoolExhausted:
+                self.scheduler.preempt(idx)
+                return
+        args = self._chunk_args(
+            req.prompt[start : start + valid], start, valid, plen,
+            act.seq, req.temperature, req.seed, req.top_p,
         )
         pool = self.pool
         self._record_program(
@@ -1762,6 +2091,7 @@ class GenerationEngine:
 
         recompute = max(0, min(start + valid, req.computed) - start)
         t0 = time.perf_counter()
+        routed = valid * self._pairs_per_token
         with _use_trace(req.trace), _span(
             "serve.prefill_chunk",
             chain=self._phases,
@@ -1769,6 +2099,8 @@ class GenerationEngine:
             start=start,
             tokens=valid,
             recompute=recompute,
+            pad_share=1.0 - valid / c,
+            tokens_routed=routed,
         ):
             pool.k, pool.v, tok = run_with_retries(
                 dispatch,
@@ -1785,9 +2117,62 @@ class GenerationEngine:
         self._charge_flops(timings, self._prefill_chunk_jit)
         act.prefill_pos = start + valid
         _m_prefill_chunks.inc()
+        if routed:
+            _m_tokens_routed.inc(routed)
         if act.prefill_pos >= plen:
             self._register_prefix(act)
             self._emit(idx, act, int(tok))
+
+    def _chunk_args(
+        self, tokens, start, valid, plen, seq, temperature, seed, top_p
+    ):
+        """The chunk program's arguments after the weights and the
+        pool."""
+        chunk_row = np.zeros((1, self._chunk_c), np.int32)
+        chunk_row[0, :valid] = tokens[:valid]
+        if self._long:
+            ptab = self._tables([seq], self._chunk_c)
+        else:
+            ptab = seq.table(self._max_pages)
+        return (
+            chunk_row,
+            np.int32(start),
+            np.int32(valid),
+            np.int32(plen),
+            ptab,
+            np.float32(temperature),
+            np.int32(seed),
+            np.float32(top_p),
+        )
+
+    def _decode_args(self, ready: List[Tuple[int, _Active]]):
+        """The decode program's arguments after the weights and the
+        pool: each ready slot's pending token, its write position, page
+        tables and sampling parameters; idle slots name the trash page."""
+        s = self.max_slots
+        toks = np.zeros(s, np.int32)
+        positions = np.zeros(s, np.int32)
+        temps = np.zeros(s, np.float32)
+        seeds = np.zeros(s, np.int32)
+        top_ps = np.ones(s, np.float32)
+        seqs: List = [None] * s
+        for idx, act in ready:
+            toks[idx] = act.generated[-1]
+            # this token's write position
+            positions[idx] = act.length - 1
+            seqs[idx] = act.seq
+            temps[idx] = act.req.temperature
+            seeds[idx] = act.req.seed
+            top_ps[idx] = act.req.top_p
+        if self._long:
+            ptabs = self._tables(seqs, 1)
+        else:
+            ptabs = np.full(
+                (s, self._max_pages), self.pool.trash_page, np.int32
+            )
+            for idx, act in ready:
+                ptabs[idx] = act.seq.table(self._max_pages)
+        return (toks, positions, ptabs, temps, seeds, top_ps)
 
     def _prefill_full(self, idx: int, act: _Active) -> None:
         req = act.req
@@ -1861,23 +2246,7 @@ class GenerationEngine:
         s = self.max_slots
         pool = self.pool
         with _span("serve.decode_args", chain=self._phases):
-            toks = np.zeros(s, np.int32)
-            positions = np.zeros(s, np.int32)
-            ptabs = np.full(
-                (s, self._max_pages), pool.trash_page, np.int32
-            )
-            temps = np.zeros(s, np.float32)
-            seeds = np.zeros(s, np.int32)
-            top_ps = np.ones(s, np.float32)
-            for idx, act in ready:
-                toks[idx] = act.generated[-1]
-                # this token's write position
-                positions[idx] = act.length - 1
-                ptabs[idx] = act.seq.table(self._max_pages)
-                temps[idx] = act.req.temperature
-                seeds[idx] = act.req.seed
-                top_ps[idx] = act.req.top_p
-            args = (toks, positions, ptabs, temps, seeds, top_ps)
+            args = self._decode_args(ready)
             self._record_program("decode", self._params_dev, pool.k, *args)
 
         # synced inside the retry window, like prefill (the host loop
@@ -1900,6 +2269,12 @@ class GenerationEngine:
                 dispatch, what="serve.decode_step"
             )
             self._wait_returned_t = time.perf_counter()
+            if self._pairs_per_token:
+                # the expert layers' pair counts came back behind the
+                # tokens, in the one array the host reads anyway
+                nxt = np.asarray(nxt)
+                self._note_routing(sp, nxt[s:], len(ready))
+                nxt = nxt[:s]
         with _span("serve.readback", chain=self._phases):
             self._charge_collectives()
             nxt = np.asarray(nxt)
@@ -1930,13 +2305,64 @@ class GenerationEngine:
         if self._wait_returned_t is not None:
             attrs["host_gap_s"] = time.perf_counter() - self._wait_returned_t
         live = sum(a.length for _, a in ready)
-        if self.attention_impl == "fused":
+        if self._long:  # by cache kind, and their mean over the layers
+            read, live = self._live_read_attrs(attrs, ready)
+        elif self.attention_impl == "fused":
             read = sum(len(a.seq.pages) for _, a in ready) * self.page_size
         else:
             read = self.max_slots * self._max_pages * self.page_size
         attrs["kv_tokens_read"] = read
         attrs["kv_tokens_live"] = live
         attrs["kv_read_amplification"] = read / live
+
+    def _live_read_attrs(self, attrs: dict, ready) -> Tuple[float, float]:
+        """Key positions the live read touches per layer against those a
+        query can see, by cache kind (``kv_tokens_read_<kind>`` /
+        ``kv_tokens_live_<kind>``) and, weighted by each kind's layers,
+        for the mean layer. A window layer's table is gathered whole
+        for every slot; a full layer's is walked a block at a time up
+        to the longest slot's last."""
+        from ..ops.attention import live_read_blocks
+
+        ps = self.page_size
+        lengths = [a.length for _, a in ready]
+        widths = self._kind_widths(1)
+        total_read = total_live = layers = 0.0
+        for kind in self._pool_layout.kinds:
+            width, n = widths[kind.name], len(kind.layers)
+            if kind.window:
+                read = self.max_slots * width * ps
+                live = sum(min(l, kind.window) for l in lengths)
+            else:
+                n_blocks, rows = live_read_blocks(width)
+                trips = min(-(-max(lengths) // (rows * ps)), n_blocks)
+                read = self.max_slots * trips * rows * ps
+                live = sum(lengths)
+            attrs[f"kv_tokens_read_{kind.name}"] = read
+            attrs[f"kv_tokens_live_{kind.name}"] = live
+            total_read += n * read
+            total_live += n * live
+            layers += n
+        return total_read / layers, total_live / layers
+
+    def _note_routing(self, sp, counts, occupancy: int) -> None:
+        """One decode step's routing, from the pair counts the program
+        returned with its tokens (``[expert layers x experts]``): the
+        ``serve.decode_step`` span's ``experts_hit`` (distinct experts
+        a layer touched, mean over layers) and
+        ``expert_load_max_over_mean`` (the fullest expert's pairs over
+        the mean expert's, mean over layers), and the pairs counter.
+        Idle slots route too (their rows are computed and thrown away);
+        the counter counts the live ones."""
+        per_layer = np.asarray(counts).reshape(-1, self.spec.n_experts)
+        _m_tokens_routed.inc(occupancy * self._pairs_per_token)
+        if sp is None:
+            return
+        mean = np.maximum(per_layer.mean(axis=1), 1e-9)
+        sp.attrs["experts_hit"] = float((per_layer > 0).sum(axis=1).mean())
+        sp.attrs["expert_load_max_over_mean"] = float(
+            (per_layer.max(axis=1) / mean).mean()
+        )
 
     @staticmethod
     def _charge_prefill_tokens(
@@ -2275,6 +2701,17 @@ class GenerationEngine:
         )
         _m_pages_in_use.set(float(self.pool.pages_in_use))
         _m_pages_shared.set(float(self.pool.pages_shared))
+        if self.layout is not None:
+            pool = self.pool
+            for kind, n in pool.kind_in_use.items():
+                _m_kind_pages.set(float(n), kind=kind)
+            now = (
+                pool.kind_allocated.get("window", 0),
+                pool.kind_released.get("window", 0),
+            )
+            _m_window_allocated.inc(now[0] - self._window_counted[0])
+            _m_window_released.inc(now[1] - self._window_counted[1])
+            self._window_counted = list(now)
         if _tenancy.enabled():
             _tenancy.update_active_gauge(self.scheduler.slots)
 
@@ -2591,6 +3028,9 @@ class GenerationEngine:
         (pair with the scoring server's generate endpoint)."""
         if self._thread is not None:
             raise RuntimeError("engine already started")
+        if self._long and not self.program_signatures:
+            with self._step_lock:
+                self._warm_programs()
         self._stop.clear()
         self._thread = threading.Thread(
             target=self._supervised_loop, daemon=True

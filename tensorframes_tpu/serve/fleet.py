@@ -1480,7 +1480,16 @@ class Fleet:
             )
             return
         try:
-            eng.pool.fill(97.0, -97.0)
+            # under the step lock and to completion: the restart worker
+            # waits for the same drained poison and may already have the
+            # probe's prefill queued; a scramble dispatched beside that
+            # step (two multi-device programs from two threads) wedged
+            # the replica's stepping thread for good under load
+            import jax
+
+            with eng._step_lock:
+                eng.pool.fill(97.0, -97.0)
+                jax.block_until_ready((eng.pool.k, eng.pool.v))
         except Exception:
             pass  # the fence is the fault; corruption is the drill's color
 

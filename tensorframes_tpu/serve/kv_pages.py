@@ -47,6 +47,16 @@ decode appends past them); a request diverging inside a cached page
 gets a private copy-on-write clone (the engine copies the page row,
 then overwrites from the divergence point).
 
+**Cache kinds.** A model whose layers keep different spans of the past
+(full layers every position, window layers the last ``window``) has one
+cache KIND per layer type, all in this one pool: a pool page is
+``depth`` layers deep (:class:`CacheLayout`: the greatest common divisor
+of the kinds' layer counts; every layer, for a model of one kind), a
+position-page of a kind takes ``layers / depth`` of them, and both kinds
+draw on the same free list, so memory moves between them as the traffic
+does. A window kind gives a page back once every position in it is more
+than ``window`` behind the next query (:meth:`SequencePages.advance`).
+
 Exhaustion raises
 :class:`~tensorframes_tpu.utils.failures.PagePoolExhausted` — the
 scheduler's cue to evict cache entries, then preempt-and-requeue,
@@ -55,8 +65,10 @@ never a crash.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import hashlib
+import math
 import threading
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -68,6 +80,8 @@ from ..utils.failures import PagePoolExhausted
 from . import tenancy as _tenancy
 
 __all__ = [
+    "CacheKind",
+    "CacheLayout",
     "PageGroup",
     "PagePool",
     "PrefixCache",
@@ -84,6 +98,85 @@ __all__ = [
 def pages_needed(tokens: int, page_size: int) -> int:
     """Pages required to hold ``tokens`` positions."""
     return -(-int(tokens) // int(page_size))
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """One kind of K/V state: the model layers that keep it, how many
+    pool pages one of its position-pages takes, and its window (0 =
+    every position is kept)."""
+
+    name: str
+    layers: Tuple[int, ...]
+    units: int
+    window: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """How a model's layers lie in the pool: ``depth`` layers to a pool
+    page, the kinds (full first), and the positions a window kind may
+    hold ahead of the next query (``lookahead``: the prefill chunk)."""
+
+    depth: int
+    kinds: Tuple[CacheKind, ...]
+    page_size: int
+    lookahead: int = 0
+
+    @staticmethod
+    def of(layer_types, window: int, page_size: int, lookahead: int = 0):
+        """``layer_types``: one of "full" / "window" per layer."""
+        by_kind = {
+            name: tuple(i for i, t in enumerate(layer_types) if t == name)
+            for name in ("full", "window")
+        }
+        by_kind = {k: v for k, v in by_kind.items() if v}
+        if sum(len(v) for v in by_kind.values()) != len(layer_types):
+            raise ValueError(f"unknown layer type in {set(layer_types)}")
+        if "window" in by_kind and (window < 1 or window % page_size):
+            raise ValueError(
+                f"window {window} must be a positive multiple of the "
+                f"page size {page_size}"
+            )
+        depth = math.gcd(*(len(v) for v in by_kind.values()))
+        return CacheLayout(
+            depth=depth,
+            kinds=tuple(
+                CacheKind(
+                    name, layers, len(layers) // depth,
+                    window if name == "window" else 0,
+                )
+                for name, layers in by_kind.items()
+            ),
+            page_size=int(page_size),
+            lookahead=int(lookahead),
+        )
+
+    @functools.lru_cache(maxsize=None)
+    def locate(self, li: int) -> Tuple[int, int, int]:
+        """Layer ``li`` -> (its kind's index, which of a position-page's
+        pool pages holds it, its row of that pool page)."""
+        for ki, kind in enumerate(self.kinds):
+            if li in kind.layers:
+                j = kind.layers.index(li)
+                return ki, j // self.depth, j % self.depth
+        raise ValueError(f"layer {li} is in no cache kind")
+
+    def held_pages(self, kind: CacheKind, tokens: int, frontier: int = 0):
+        """Position-pages of ``kind`` a sequence covering ``tokens``
+        positions holds when its next query is at ``frontier``: a window
+        kind never reaches past ``frontier + window + lookahead``."""
+        want = pages_needed(tokens, self.page_size)
+        if kind.window:
+            ahead = frontier + kind.window + self.lookahead
+            want = min(want, pages_needed(ahead, self.page_size) + 1)
+        return want
+
+    def units_needed(self, tokens: int) -> int:
+        """Most pool pages one sequence of ``tokens`` positions holds."""
+        return sum(
+            k.units * self.held_pages(k, tokens) for k in self.kinds
+        )
 
 
 # -- the device layout, and the step programs' accesses to it --------------
@@ -329,6 +422,29 @@ class PagePool(_PageArrays):
         #: page table or the prefix cache naming the same page), -1 per
         #: free(); the page returns to the free list at 0
         self._refcount = np.zeros(self.num_pages, np.int32)
+        #: pool pages the sequences' cache kinds took, gave back before
+        #: their sequence ended (a window kind's), and hold now, by
+        #: kind name — :class:`SequencePages` keeps them
+        self.kind_allocated: Dict[str, int] = {}
+        self.kind_released: Dict[str, int] = {}
+        self.kind_in_use: Dict[str, int] = {}
+
+    def _count(self, kind: str, taken: int = 0, released: int = 0,
+               dropped: int = 0) -> None:
+        """A sequence took ``taken`` pool pages for ``kind``, its window
+        released ``released``, or it let go of ``dropped`` at its end."""
+        with self._lock:
+            if taken:
+                self.kind_allocated[kind] = (
+                    self.kind_allocated.get(kind, 0) + taken
+                )
+            if released:
+                self.kind_released[kind] = (
+                    self.kind_released.get(kind, 0) + released
+                )
+            self.kind_in_use[kind] = (
+                self.kind_in_use.get(kind, 0) + taken - released - dropped
+            )
 
     def _geometry(self) -> Tuple[int, int]:
         return self.num_pages, self.page_size
@@ -483,7 +599,9 @@ class PagePool(_PageArrays):
         invariant."""
         with self._lock:
             owners: Dict[int, int] = {}
-            all_lists: List[List[int]] = [seq.pages for seq in sequences]
+            all_lists: List[List[int]] = [
+                held for seq in sequences for held in seq.held
+            ]
             all_lists.extend(page_lists)
             for pages in all_lists:
                 for p in pages:
@@ -552,48 +670,129 @@ class PageGroup(_PageArrays):
         return self.pool.num_pages, self.pool.page_size
 
 
-class SequencePages:
-    """One sequence's slice of the pool: the ordered page list (page ``i``
-    holds positions ``i*page_size .. (i+1)*page_size - 1``) and growth /
-    release bookkeeping. Pure host state — the device-visible form is
-    :meth:`table`."""
+_ONE_KIND = CacheLayout(
+    depth=0, kinds=(CacheKind("full", (), 1),), page_size=0
+)
 
-    def __init__(self, pool: PagePool):
+
+class SequencePages:
+    """One sequence's slice of the pool: per cache kind the ordered pool
+    pages it holds (position-page ``i`` of a kind holds positions
+    ``i*page_size .. (i+1)*page_size - 1`` of that kind's layers, in
+    ``units`` consecutive entries) and growth / release bookkeeping. Pure
+    host state — the device-visible form is :meth:`table`.
+
+    Without a ``layout`` there is one kind that keeps every position and
+    whose position-page is one pool page: ``pages`` is then the whole
+    holding, as it is for every model whose layers are all alike."""
+
+    def __init__(self, pool: PagePool, layout: Optional[CacheLayout] = None):
         self.pool = pool
-        self.pages: List[int] = []
+        self.layout = layout
+        self.kinds = (layout or _ONE_KIND).kinds
+        #: per kind, the pool pages held, in position order
+        self.held: List[List[int]] = [[] for _ in self.kinds]
+        #: per kind, the position-page its first held entry belongs to
+        self.first: List[int] = [0 for _ in self.kinds]
+        #: the next query's position (:meth:`advance`)
+        self.frontier = 0
+
+    @property
+    def pages(self) -> List[int]:
+        """The first kind's pool pages (the full kind's where there is
+        one): the whole holding of a model with one kind."""
+        return self.held[0]
+
+    @pages.setter
+    def pages(self, value: List[int]) -> None:
+        self.held[0] = value
+
+    def _covered(self, ki: int) -> int:
+        """Position-pages of kind ``ki`` reached so far."""
+        return self.first[ki] + len(self.held[ki]) // self.kinds[ki].units
 
     @property
     def capacity(self) -> int:
         """Token positions the currently-held pages can store."""
-        return len(self.pages) * self.pool.page_size
+        return self.pool.page_size * min(
+            self._covered(ki) for ki in range(len(self.kinds))
+        )
 
     def ensure(self, tokens: int) -> None:
-        """Grow the page list until ``tokens`` positions fit. All-or-
-        nothing per call; raises :class:`PagePoolExhausted` (holdings
-        unchanged) when the pool cannot supply the missing pages."""
-        missing = pages_needed(tokens, self.pool.page_size) - len(self.pages)
-        if missing > 0:
-            self.pages.extend(self.pool.alloc(missing))
+        """Grow every kind until ``tokens`` positions fit (a window kind
+        only as far ahead of the next query as it may reach,
+        :meth:`CacheLayout.held_pages`). All-or-nothing per call; raises
+        :class:`PagePoolExhausted` (holdings unchanged) when the pool
+        cannot supply the missing pages."""
+        ps = self.pool.page_size
+        missing = []
+        for ki, kind in enumerate(self.kinds):
+            want = pages_needed(tokens, ps)
+            if kind.window:
+                want = self.layout.held_pages(kind, tokens, self.frontier)
+            missing.append(max(0, want - self._covered(ki)) * kind.units)
+        if not any(missing):
+            return
+        grant = self.pool.alloc(sum(missing))
+        for ki, n in enumerate(missing):
+            if n:
+                self.held[ki].extend(grant[:n])
+                self.pool._count(self.kinds[ki].name, taken=n)
+                del grant[:n]
+
+    def advance(self, frontier: int) -> int:
+        """The next query is at position ``frontier``: every window kind
+        gives back the pages whose positions are all more than its
+        window behind it (key ``j`` is visible to query ``p`` iff ``p -
+        window < j``). Returns the pool pages released."""
+        self.frontier = int(frontier)
+        released = 0
+        for ki, kind in enumerate(self.kinds):
+            if not kind.window:
+                continue
+            dead = max(0, self.frontier - kind.window + 1) // (
+                self.pool.page_size
+            )
+            if dead <= self.first[ki]:
+                continue
+            n = kind.units * min(
+                dead - self.first[ki], len(self.held[ki]) // kind.units
+            )
+            if n:
+                self.pool.free(self.held[ki][:n])
+                del self.held[ki][:n]
+                self.pool._count(kind.name, released=n)
+                released += n
+            self.first[ki] = dead
+        return released
 
     def release(self) -> None:
         """Return every held page to the pool (idempotent)."""
-        if self.pages:
-            self.pool.free(self.pages)
-            self.pages = []
+        for ki, kind in enumerate(self.kinds):
+            if self.held[ki]:
+                self.pool.free(self.held[ki])
+                self.pool._count(kind.name, dropped=len(self.held[ki]))
+                self.held[ki] = []
+            self.first[ki] = 0
+        self.frontier = 0
 
-    def table(self, max_pages: int) -> np.ndarray:
-        """The ``[max_pages]`` int32 page table the compiled step reads —
-        held pages in position order, trash-filled past the end (those
-        entries are masked by the position mask, but must stay in
-        bounds)."""
-        if len(self.pages) > max_pages:
+    def table(self, max_pages: int, kind: int = 0) -> np.ndarray:
+        """The int32 page table the compiled step reads for one kind —
+        ``[max_pages]`` (``[max_pages, units]`` for a kind whose
+        position-page takes several pool pages), row ``r`` the
+        position-page ``first[kind] + r``, held pages in position order,
+        trash-filled past the end (those entries are masked by the
+        position mask, but must stay in bounds)."""
+        units = self.kinds[kind].units
+        held = self.held[kind]
+        if len(held) > max_pages * units:
             raise ValueError(
-                f"sequence holds {len(self.pages)} pages > max_pages "
+                f"sequence holds {len(held) // units} pages > max_pages "
                 f"{max_pages}"
             )
-        out = np.full(max_pages, self.pool.trash_page, np.int32)
-        out[: len(self.pages)] = self.pages
-        return out
+        out = np.full(max_pages * units, self.pool.trash_page, np.int32)
+        out[: len(held)] = held
+        return out if units == 1 else out.reshape(max_pages, units)
 
 
 class _PrefixEntry:

@@ -248,6 +248,7 @@ class Scheduler:
         queue_capacity: int,
         max_seq_len: int,
         prefix_cache=None,
+        layout=None,
     ):
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1; got {max_slots}")
@@ -259,6 +260,11 @@ class Scheduler:
         #: cached prompt-prefix pages into new sequences, and pool
         #: exhaustion evicts cache entries before preempting live work
         self.prefix_cache = prefix_cache
+        #: the model's cache kinds (:class:`~.kv_pages.CacheLayout`);
+        #: None = one kind that keeps every position. Admission,
+        #: :meth:`grow` and the feasibility check count every kind's
+        #: pages, all from the one pool
+        self.layout = layout
         self.slots: List[Optional[_Active]] = [None] * self.max_slots
         self._waiting: Deque[GenRequest] = deque()
         self._lock = threading.Condition()
@@ -303,9 +309,14 @@ class Scheduler:
                 f"({req.max_new_tokens}) = {total} exceeds max_seq_len "
                 f"{self.max_seq_len}"
             )
-        if pages_needed(total, self.pool.page_size) > self.pool.num_pages:
+        need = (
+            pages_needed(total, self.pool.page_size)
+            if self.layout is None
+            else self.layout.units_needed(total)
+        )
+        if need > self.pool.num_pages:
             raise ValueError(
-                f"request needs {pages_needed(total, self.pool.page_size)} "
+                f"request needs {need} "
                 f"pages at full length but the pool holds only "
                 f"{self.pool.num_pages} — it could never be scheduled"
             )
@@ -388,7 +399,7 @@ class Scheduler:
                 else:
                     req = self._waiting.popleft()
                 self._lock.notify_all()
-            seq = SequencePages(self.pool)
+            seq = SequencePages(self.pool, self.layout)
             cow_src: Optional[int] = None
             cached = 0
             if self.prefix_cache is not None:
@@ -470,6 +481,10 @@ class Scheduler:
         this step's batch)."""
         act = self.slots[idx]
         assert act is not None
+        if self.layout is not None:
+            # the next query is the pending token's: a window kind gives
+            # back the pages that fell wholly behind its window
+            act.seq.advance(act.length - 1)
         while True:
             try:
                 # the pending token writes at position length - 1 (its

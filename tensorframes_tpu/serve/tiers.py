@@ -181,6 +181,11 @@ def export_slot(engine, request_id: int, reason: str = "handoff"):
             # mid-prefill (chunked) or pre-clone: the cheap recompute
             # path (replay/preempt) beats moving half-built state
             return None
+        if getattr(engine, "layout", None) is not None:
+            # a snapshot carries one cache kind's pages; a model with
+            # window layers holds two: not migratable, the caller's
+            # ordinary ladder (preempt and recompute) applies
+            return None
         t0 = time.monotonic()
         pool = engine.pool
         rows = np.asarray(act.seq.pages, np.int32)

@@ -51,7 +51,8 @@ def test_cpu_rehearsal_runs_every_phase():
     assert lines[-1]["device"]["platform"] == "cpu"
     ended = {l["phase"] for l in lines if l.get("event") == "end"}
     assert ended == {
-        "frame", "serve", "kernels", "pool_layout", "four_chips.dp", "four_chips.tp",
+        "frame", "serve", "kernels", "pool_layout", "pool_layout.long",
+        "four_chips.dp", "four_chips.tp",
         "four_chips.fleet", "four_chips.ring", "float64",
     }
     checks = [l for l in lines if "check" in l]

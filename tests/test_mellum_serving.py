@@ -1,0 +1,399 @@
+"""The rotary / RMS / gated-expert block with window and full layers, at a
+small size on the CPU: the serving engine against the plain reference of
+``chipbench/models/mellum.py`` (which shares no code with the package) on
+seeded weights, and the parts it is made of — the model description, the
+rotary frequencies, the grouped expert path against ``moe_ffn``, the cache
+kinds, the live-bounded paged read."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from chipbench.models import mellum  # noqa: E402
+from tensorframes_tpu.models import transformer as tr  # noqa: E402
+from tensorframes_tpu.ops.attention import paged_attention_live  # noqa: E402
+from tensorframes_tpu.parallel.moe import (  # noqa: E402
+    init_moe,
+    moe_ffn,
+    moe_grouped,
+)
+from tensorframes_tpu.serve import GenerationEngine  # noqa: E402
+from tensorframes_tpu.serve.kv_pages import (  # noqa: E402
+    CacheLayout,
+    PagePool,
+    SequencePages,
+)
+
+YARN = {
+    "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+    "original_max_position_embeddings": 8192, "beta_fast": 32,
+    "beta_slow": 1, "attention_factor": 1.2772588722239782,
+}
+PUBLISHED = {
+    "head_dim": 128,
+    "rope_parameters": {
+        "full_attention": YARN,
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+    },
+}
+TINY = {
+    "hidden_size": 64, "num_attention_heads": 8, "num_key_value_heads": 2,
+    "head_dim": 16, "num_hidden_layers": 4,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "moe_intermediate_size": 32, "num_experts": 8, "num_experts_per_tok": 2,
+    "rms_norm_eps": 1e-6, "sliding_window": 32, "vocab_size": 256,
+    "tie_word_embeddings": False, "n_positions": 256,
+    "rope_parameters": PUBLISHED["rope_parameters"],
+}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return mellum.init_params(3, TINY, "float32")
+
+
+def gaps(params, prompts, outs):
+    """How far each served token's reference logit lies below the
+    reference's best at its position (float32 reference, the whole
+    sequence forward, no cache)."""
+    import jax.numpy as jnp
+
+    tokens = np.zeros((len(prompts), TINY["n_positions"]), np.int32)
+    rows, cols, served = [], [], []
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        seq = list(p) + list(o[:-1])
+        tokens[i, : len(seq)] = seq
+        for j, t in enumerate(o):
+            rows.append(i), cols.append(len(p) - 1 + j), served.append(t)
+    ref = mellum.reference_logits(
+        params, TINY, tokens, np.asarray(rows), np.asarray(cols), "float32"
+    )
+    got = jnp.take_along_axis(ref, jnp.asarray(served)[:, None], -1)[:, 0]
+    return np.asarray(jnp.max(ref, -1) - got)
+
+
+def serve(params, prompts, new_tokens, **engine):
+    geometry = dict(
+        max_slots=4, page_size=16, num_pages=64, max_seq_len=256,
+        queue_capacity=16, prefill_chunk_tokens=32,
+    )
+    geometry.update(engine)
+    eng = GenerationEngine(params, **geometry)
+    handles = [
+        eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, new_tokens)
+    ]
+    eng.run_until_idle()
+    return eng, handles, [h.result().tolist() for h in handles]
+
+
+def prompts_of(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 256, size=n).tolist() for n in lengths]
+
+
+# ------------------------------------------------- engine against reference
+
+CASES = {
+    # prompt + generation runs far past the window of 32: window pages
+    # are released under the sequence while it decodes
+    "decode_across_the_window": dict(lengths=(100, 17, 70, 5), new=(40, 12, 30, 8)),
+    # one chunk, several chunks, a chunk that ends on a page boundary
+    "chunked_prefill": dict(lengths=(31, 32, 33, 130, 64), new=(4, 4, 4, 4, 4)),
+    # a pool too small for all four: the youngest is preempted, requeued
+    # and recomputed, its stream unbroken
+    "recompute_after_preemption": dict(
+        lengths=(90, 80, 70, 60), new=(60, 60, 60, 60), num_pages=48,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_engine_agrees_with_the_reference_at_logit_level(params, case):
+    spec = dict(CASES[case])
+    prompts = prompts_of(spec.pop("lengths"))
+    eng, handles, outs = serve(params, prompts, spec.pop("new"), **spec)
+    # the token the engine served is the reference's own first choice, or
+    # within float32 rounding of it, at every position
+    assert gaps(params, prompts, outs).max() < 1e-3
+    assert eng.pool.pages_in_use == 0
+    assert eng.num_step_programs == 2  # chunk and decode, nothing else
+    preempted = sum(h.timings.get("preemptions", 0) for h in handles)
+    assert (preempted > 0) == (case == "recompute_after_preemption")
+    if case == "recompute_after_preemption":
+        assert any(h.timings.get("recomputed_tokens", 0) for h in handles)
+
+
+@pytest.mark.parametrize("chunk", [16, 48, 256])
+def test_chunked_prefill_serves_what_whole_prefill_serves(params, chunk):
+    prompts = prompts_of((130, 47))
+    _, _, whole = serve(params, prompts, (6, 6), prefill_chunk_tokens=256)
+    _, _, parts = serve(params, prompts, (6, 6), prefill_chunk_tokens=chunk)
+    assert parts == whole
+
+
+def test_walks_agree_with_the_reference_on_whole_sequences(params):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(5)
+    tokens = rng.integers(0, 256, (2, 128)).astype(np.int32)
+    ours = tr.transformer_logits(params, jnp.asarray(tokens))
+    rows = np.repeat(np.arange(2), 128)
+    cols = np.tile(np.arange(128), 2)
+    ref = mellum.reference_logits(params, TINY, tokens, rows, cols, "float32")
+    np.testing.assert_allclose(
+        np.asarray(ours).reshape(-1, 256), np.asarray(ref), atol=2e-4
+    )
+    # the scan decode walks the same block, one token at a time
+    lm = tr.TransformerLM(params)
+    out = lm.generate(tokens[:, :40], 8)
+    assert gaps(params, tokens[:, :40].tolist(), out[:, 40:].tolist()).max() < 1e-3
+
+
+def test_page_accounting_of_both_kinds_returns_to_zero(params):
+    eng, _, _ = serve(params, prompts_of((100, 40, 9)), (30, 30, 30))
+    pool = eng.pool
+    assert pool.pages_in_use == 0
+    assert pool.kind_in_use == {"full": 0, "window": 0}
+    assert pool.kind_released["window"] > 0
+    assert pool.kind_allocated["window"] > pool.kind_released["window"]
+    assert "full" not in pool.kind_released  # full layers keep every page
+
+
+def test_decode_span_and_counters_carry_routing_and_cache_kinds(params):
+    import io
+    import json
+
+    from tensorframes_tpu import obs
+
+    sink = io.StringIO()
+    obs.set_trace_sink(sink)
+    try:
+        serve(params, prompts_of((100, 20)), (12, 12))
+    finally:
+        obs.set_trace_sink(None)
+    events = [json.loads(l) for l in sink.getvalue().splitlines() if l.strip()]
+    steps = [e["attrs"] for e in events if e["name"] == "serve.decode_step"]
+    chunks = [e["attrs"] for e in events if e["name"] == "serve.prefill_chunk"]
+    assert steps and chunks
+    for a in steps:
+        assert 1 <= a["experts_hit"] <= TINY["num_experts"]
+        assert a["expert_load_max_over_mean"] >= 1.0
+        for kind in ("full", "window"):
+            assert a[f"kv_tokens_read_{kind}"] >= a[f"kv_tokens_live_{kind}"] > 0
+        assert a["kv_read_amplification"] >= 1.0
+    for a in chunks:
+        assert 0.0 <= a["pad_share"] < 1.0 and a["tokens"] > 0
+        assert a["tokens_routed"] == a["tokens"] * 2 * 4  # top-2, 4 layers
+    snap = obs.registry().snapshot()
+    assert sum(snap["moe.tokens_routed_total"]["values"].values()) > 0
+    assert sum(snap["serve.window_pages_released_total"]["values"].values()) > 0
+
+
+# ------------------------------------------------------ model description
+
+
+def test_model_description_of_a_gpt2_tree_and_of_a_described_tree(params):
+    lm = tr.init_transformer(0, 64, d_model=32, n_heads=4, n_kv_heads=2, max_len=48)
+    spec = tr.model_spec(lm)
+    assert (spec.n_heads, spec.n_kv_heads, spec.head_dim) == (4, 2, 8)
+    assert (spec.norm, spec.position, spec.mlp, spec.tied_head) == (
+        "layer", "learned", "gelu", True,
+    )
+    assert spec.max_len == 48 and spec.kinds == ("full",)
+    spec = tr.model_spec(params)
+    assert spec.head_dim * spec.n_heads != TINY["hidden_size"]  # 128 != 64
+    assert spec.kinds == ("full", "window") and spec.layer_window(0) == 32
+    assert spec.layer_window(3) == 0 and spec.rope_of(3).kind == "yarn"
+    assert hash(spec) == hash(tr.model_spec(params))
+    eng = GenerationEngine(params, max_slots=2, page_size=16, num_pages=32)
+    assert eng.spec == spec and eng.max_seq_len == 256
+    assert eng._long and eng.prefill_chunk_tokens % 16 == 0
+
+
+@pytest.mark.parametrize("who", ["program", "reference"])
+def test_rotary_frequencies_match_hand_worked_values(who):
+    if who == "reference":
+        assert mellum.yarn_range(PUBLISHED) == (18, 35)
+        full, factor = mellum.inv_freq(PUBLISHED, "full_attention")
+        plain, one = mellum.inv_freq(PUBLISHED, "sliding_attention")
+    else:
+        rope = tr.RopeSpec(
+            theta=500000.0, kind="yarn", factor=16.0,
+            original_max_position=8192, beta_fast=32.0, beta_slow=1.0,
+            attention_factor=YARN["attention_factor"],
+        )
+        full, factor = tr.rope_inv_freq(rope, 128), rope.attention_factor
+        plain, one = tr.rope_inv_freq(tr.RopeSpec(theta=500000.0), 128), 1.0
+    assert factor == pytest.approx(0.1 * np.log(16) + 1.0) and one == 1.0
+    extrap = lambda i: 500000.0 ** (-2.0 * i / 128)
+    np.testing.assert_allclose(plain, [extrap(i) for i in range(64)], rtol=1e-6)
+    # below dimension 18 plain, above 35 divided by 16, a line between
+    np.testing.assert_allclose(full[:19], [extrap(i) for i in range(19)], rtol=1e-6)
+    np.testing.assert_allclose(
+        full[35:], [extrap(i) / 16 for i in range(35, 64)], rtol=1e-6
+    )
+    r = (26 - 18) / (35 - 18)
+    assert full[26] == pytest.approx(extrap(26) * (r / 16 + 1 - r), rel=1e-6)
+
+
+# ----------------------------------------------------- grouped expert path
+
+
+def routed_inputs(case, tokens):
+    """A SiLU-gated expert layer (``init_moe``'s shapes and a gate
+    matrix) and inputs whose routing is known: tokens are scaled basis
+    vectors and the router a scaled identity, so token ``i`` prefers
+    expert ``i % 8``."""
+    p = init_moe(1, 8, 16, 8)
+    rng = np.random.default_rng(2)
+    if case == "even":
+        router = (8.0 * np.eye(8)).astype(np.float32)
+        x = np.eye(8, dtype=np.float32)[np.arange(tokens) % 8]
+    elif case == "one_expert":
+        router = np.zeros((8, 8), np.float32)
+        router[:, 3] = 4.0
+        x = np.abs(rng.normal(size=(tokens, 8))).astype(np.float32)
+    else:  # experts 0 and 1 take everything at k = 2, six get no token
+        router = np.zeros((8, 8), np.float32)
+        router[:, 0], router[:, 1] = 4.0, 3.0
+        x = np.abs(rng.normal(size=(tokens, 8))).astype(np.float32)
+    gate = np.random.default_rng(4).normal(size=p["w_up"].shape)
+    p = {
+        "router": router, "w_up": p["w_up"], "w_down": p["w_down"],
+        "w_gate": (0.3 * gate).astype(np.float32),
+    }
+    return p, (x + 0.01 * rng.normal(size=x.shape).astype(np.float32))
+
+
+@pytest.mark.parametrize("tokens", [32, 88])
+@pytest.mark.parametrize("case", ["even", "one_expert", "empty_experts"])
+def test_grouped_expert_path_agrees_with_the_masked_oracle(case, tokens):
+    p, x = routed_inputs(case, tokens)
+    k = 2 if case == "empty_experts" else 1
+    want = moe_ffn(p, x[None], k=k)[0]
+    got, counts = moe_grouped(p, x, k=k)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    counts = np.asarray(counts)
+    assert counts.sum() == tokens * k  # no pair dropped
+    if case == "even":
+        assert (counts == tokens // 8).all()
+    elif case == "one_expert":
+        assert counts[3] == tokens
+    else:
+        assert counts[0] == counts[1] == tokens and counts[2:].sum() == 0
+
+
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_the_shares_of_an_expert_layer_add_up_to_the_layer(params, shares):
+    """Guide section 4's test: the experts divided over ``shares`` chips,
+    each told which it holds, every share routing over the router's
+    whole width; the parts add up to what the reference's layer gives."""
+    import jax.numpy as jnp
+
+    moe = params["blocks"][0]["moe"]
+    x = np.random.default_rng(6).normal(size=(24, 64)).astype(np.float32)
+    whole, counts = moe_grouped(moe, x, k=2)
+    held = 8 // shares
+    total = 0.0
+    seen = []
+    for s in range(shares):
+        mine = {
+            name: (w if name == "router" else w[s * held : (s + 1) * held])
+            for name, w in moe.items()
+        }
+        part, c = moe_grouped(mine, x, k=2, expert_offset=s * held)
+        total = total + part
+        seen.extend(np.asarray(c))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole), atol=1e-5)
+    assert seen == list(np.asarray(counts))
+    oracle = moe_ffn(moe, jnp.asarray(x)[None], k=2)[0]
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(oracle), atol=1e-5)
+
+
+# ------------------------------------------------------------- cache kinds
+
+
+def test_cache_layout_puts_both_kinds_on_one_pool():
+    lay = CacheLayout.of(["window"] * 3 + ["full"] + ["window"] * 3 + ["full"], 32, 16, 32)
+    assert lay.depth == 2
+    assert [(k.name, k.units, k.window) for k in lay.kinds] == [
+        ("full", 1, 0), ("window", 3, 32),
+    ]
+    assert lay.locate(3) == (0, 0, 0) and lay.locate(7) == (0, 0, 1)
+    assert lay.locate(0) == (1, 0, 0) and lay.locate(6) == (1, 2, 1)
+    # 400 positions: 25 full pages; the window kind never holds more than
+    # the window, the chunk ahead and a page: 5 position-pages of 3
+    assert lay.units_needed(400) == 25 + 3 * 5
+    with pytest.raises(ValueError):
+        CacheLayout.of(["window", "full"], 24, 16)  # not whole pages
+
+
+def test_window_pages_are_released_behind_the_window():
+    lay = CacheLayout.of(["window"] * 3 + ["full"], 32, 16, 32)
+    pool = PagePool(lay.depth, 2, 8, 64, 16)
+    seq = SequencePages(pool, lay)
+    seq.ensure(200)  # admission: every full page, the window's reach
+    assert len(seq.pages) == 13 and len(seq.held[1]) == 3 * 5
+    held = []
+    for start in range(0, 200, 32):  # prefill, a chunk at a time
+        seq.advance(start)
+        seq.ensure(min(200, start + 32))
+        assert seq.capacity >= min(200, start + 32)
+        # keys start - 31 .. start + 31 are all on held pages
+        assert seq.first[1] * 16 <= max(0, start - 31)
+        held.append(len(seq.held[1]) // 3)
+    assert max(held) <= 5 and seq.first[1] > 0
+    table = seq.table(6, 1)
+    assert table.shape == (6, 3) and (table[-1] == pool.trash_page).all()
+    seq.release()
+    assert pool.pages_in_use == 0 and pool.kind_in_use == {"full": 0, "window": 0}
+    # every window page but the last five position-pages went back early
+    assert pool.kind_released == {"window": pool.kind_allocated["window"] - 3 * held[-1]}
+
+
+# ------------------------------------------------------ live-bounded read
+
+
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("block_pages", [2, 64])
+def test_live_read_agrees_with_dense_attention(window, block_pages):
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(7)
+    slots, c, n_kv, group, hd, ps, pages = 3, 5, 2, 2, 8, 4, 12
+    lengths = np.asarray([37, 9, 20], np.int32)
+    k = rng.normal(size=(slots, pages * ps, n_kv, hd)).astype(np.float32)
+    v = rng.normal(size=k.shape).astype(np.float32)
+    q = rng.normal(size=(slots, c, n_kv, group, hd)).astype(np.float32)
+    # every slot's pages scattered through one pool, layer 1 of 2
+    order = rng.permutation(slots * pages)
+    table = order.reshape(slots, pages).astype(np.int32)
+    pool_k = np.zeros((2, slots * pages + 1, ps, n_kv * hd), np.float32)
+    pool_v = np.zeros_like(pool_k)
+    for s in range(slots):
+        pool_k[1, table[s]] = k[s].reshape(pages, ps, n_kv * hd)
+        pool_v[1, table[s]] = v[s].reshape(pages, ps, n_kv * hd)
+    q_pos = (lengths[:, None] - c + np.arange(c)[None]).astype(np.int32)
+    got = paged_attention_live(
+        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
+        jnp.asarray(table), jnp.zeros(slots, jnp.int32), jnp.asarray(q_pos),
+        jnp.asarray(lengths), 1, window=window, block_pages=block_pages,
+    )
+    pos = np.arange(pages * ps)
+    for s in range(slots):
+        for i in range(c):
+            seen = (pos <= q_pos[s, i]) & (pos < lengths[s])
+            if window:
+                seen &= pos > q_pos[s, i] - window
+            sc = np.einsum("kgd,tkd->kgt", q[s, i], k[s]) / np.sqrt(hd)
+            sc = np.where(seen[None, None], sc, -np.inf)
+            p = np.asarray(jax.nn.softmax(jnp.asarray(sc), -1))
+            want = np.einsum("kgt,tkd->kgd", p, v[s]).reshape(-1)
+            np.testing.assert_allclose(np.asarray(got[s, i]), want, atol=2e-5)

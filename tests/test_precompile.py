@@ -48,7 +48,9 @@ jax.config.update = recording_update
 import tensorframes_tpu as tft
 import jax.numpy as jnp
 def chain(x):
-    for _ in range(48):
+    # long enough to compile in well over the 0.1 s this package admits
+    # from (48 links took 0.08-0.15 s here: an entry two runs in three)
+    for _ in range(384):
         x = jnp.tanh(x @ x) + 1.0
     return x
 if sys.argv[1:] == ["compile"]:

@@ -705,14 +705,24 @@ class TestGenerateEndpoint:
             )
             assert status == 200
             np.testing.assert_array_equal(payload["tokens"], _solo(lm, p, 6))
-            scrape = _http(addr, b"GET /metrics HTTP/1.1\r\n\r\n").decode()
+            counted = 'tft_serving_requests_total{kind="generate",status="ok"}'
+            # the connection's thread counts its request once the client
+            # has closed, which the next connection's scrape can outrun
+            # on a loaded machine: ask again, for a bounded while
+            for _ in range(100):
+                scrape = _http(
+                    addr, b"GET /metrics HTTP/1.1\r\n\r\n"
+                ).decode()
+                if counted in scrape:
+                    break
+                time.sleep(0.05)
             for name in (
                 "tft_serve_queue_depth",
                 "tft_serve_active_slots",
                 "tft_serve_pages_in_use",
                 "tft_serve_ttft_seconds_count",
                 "tft_serve_inter_token_seconds_count",
-                'tft_serving_requests_total{kind="generate",status="ok"}',
+                counted,
             ):
                 assert name in scrape, name
         assert eng._thread is None  # server stop also stopped its engine
