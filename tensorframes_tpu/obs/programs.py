@@ -577,13 +577,17 @@ class InstrumentedProgram:
     delegates to the wrapped jit."""
 
     __slots__ = (
-        "_fn", "_sync", "_estimated", "record", "_key", "_name",
-        "_kind", "_meta", "_cache_size",
+        "_fn", "_sync", "_after_issue", "_estimated", "record", "_key",
+        "_name", "_kind", "_meta", "_cache_size",
     )
 
-    def __init__(self, fn, key: str, name: str, kind: str, meta, sync):
+    def __init__(
+        self, fn, key: str, name: str, kind: str, meta, sync,
+        after_issue=None,
+    ):
         self._fn = fn
         self._sync = sync
+        self._after_issue = after_issue
         self._estimated = False
         self.record: Optional[ProgramRecord] = None
         self._key = key
@@ -599,8 +603,12 @@ class InstrumentedProgram:
         self._cache_size = -1
 
     def __call__(self, *args, **kwargs):
+        after_issue = self._after_issue
         if not enabled():
-            return self._fn(*args, **kwargs)
+            out = self._fn(*args, **kwargs)
+            if after_issue is not None:
+                after_issue()
+            return out
         rec = self.record
         if rec is None:
             rec = self.record = program(
@@ -612,6 +620,8 @@ class InstrumentedProgram:
         t0 = time.perf_counter()
         try:
             out = self._fn(*args, **kwargs)
+            if after_issue is not None:
+                after_issue()
             if self._sync:
                 import jax
 
@@ -648,15 +658,24 @@ class InstrumentedProgram:
 
 
 def instrument(
-    fn, *, key: str, name: str, kind: str, sync: bool = False, **meta
+    fn, *, key: str, name: str, kind: str, sync: bool = False,
+    after_issue=None, **meta
 ) -> InstrumentedProgram:
     """Wrap a jitted callable so its costs land in the registry.
 
     ``sync=True`` blocks on the outputs inside the timing window —
     correct only where the call site synchronizes anyway (the serving
     step dispatches); pipelined call sites (the batch engine's chunk
-    loops) keep ``sync=False`` and accumulate enqueue wall."""
-    return InstrumentedProgram(fn, key, name, kind, meta, sync)
+    loops) keep ``sync=False`` and accumulate enqueue wall.
+
+    ``after_issue`` is called with no arguments on the calling thread
+    each time the jitted call has returned — the program is issued and
+    the device works — and before the wrapper waits for it: host work
+    that needs nothing of this program's result runs there beside the
+    device instead of between two programs (the serving engine delivers
+    the last step's tokens there). It runs inside the timed window and
+    under the kill switch too, and what it raises fails the call."""
+    return InstrumentedProgram(fn, key, name, kind, meta, sync, after_issue)
 
 
 # ---------------------------------------------------------------------------

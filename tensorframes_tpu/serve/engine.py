@@ -47,6 +47,19 @@ steps complete. Observability: queue depth / batch occupancy /
 pages-in-use gauges, time-to-first-token and inter-token latency
 histograms, all on the PR-1 registry (``docs/observability.md``).
 
+**Deferred delivery.** Between two step programs the stepping thread
+does only what the next program's arguments need: the token joins
+``generated``, a finished slot and its pages go back. What else a step
+owes — the token and the end mark handed to the handle (which wakes the
+stream's thread), counters, histograms, ``timings``, the cost record,
+the gauges — is queued in order (:meth:`GenerationEngine._owe`) and run
+once the next program is issued, while the device works and before the
+thread waits for it (``after_issue`` of ``obs/programs.py``). When no
+program follows — the loop goes idle, ``step()`` / ``run_until_idle()``
+return, a request is failed, preempted, expired or moved — it is
+delivered then and there, in a ``serve.deliver`` span, so no end mark
+overtakes a token and nothing waits while the thread sleeps.
+
 **Supervision** (``docs/fault_tolerance.md``): step failures are
 classified against the ``utils/failures.py`` taxonomy — transient
 dispatch errors retry with bounded backoff inside the step, device OOM
@@ -65,7 +78,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -153,6 +167,17 @@ _m_itl = _histogram(
 )
 _m_tokens = _counter(
     "serve.tokens_total", "Tokens emitted across all generation streams"
+)
+_m_delivered = _counter(
+    "serve.tokens_delivered_total",
+    "Tokens handed to their streams' handles (delivery is deferred: "
+    "serve/engine.py)",
+)
+_m_delivered_under_program = _counter(
+    "serve.tokens_delivered_under_program_total",
+    "Tokens handed to their handles while the next step program was "
+    "in flight, the host's delivery hidden behind the device's work; "
+    "over serve.tokens_delivered_total, how often deferral engages",
 )
 _m_requests = _counter(
     "serve.requests_total",
@@ -814,6 +839,8 @@ class GenerationEngine:
         # after. sync=True is semantics-neutral here — every dispatch
         # site already block_until_ready()s inside its retry window, so
         # the wrapper's sync just moves the wait inside the timing.
+        # after_issue: between the issue and that wait the stepping
+        # thread delivers what the steps so far owe (_deliver_hosted).
         mmeta = dict(
             max_slots=self.max_slots, page_size=self.page_size,
             max_seq_len=self.max_seq_len, d_model=d_model,
@@ -858,13 +885,15 @@ class GenerationEngine:
             jax.jit(prefill_fn, donate_argnums=donate),
             key=f"serve.{seq}:prefill",
             name=f"serve.prefill[{self.name}]",
-            kind="serve.step", sync=True, **mmeta,
+            kind="serve.step", sync=True,
+            after_issue=self._deliver_hosted, **mmeta,
         )
         self._decode_jit = _programs.instrument(
             jax.jit(decode_fn, donate_argnums=donate),
             key=f"serve.{seq}:decode",
             name=f"serve.decode[{self.name}]",
-            kind="serve.step", sync=True, **mmeta,
+            kind="serve.step", sync=True,
+            after_issue=self._deliver_hosted, **mmeta,
         )
         # built unconditionally (a jit wrapper is free until dispatched);
         # it only dispatches — and only then counts a program — when
@@ -873,7 +902,8 @@ class GenerationEngine:
             jax.jit(chunk_fn, donate_argnums=donate),
             key=f"serve.{seq}:prefill_chunk",
             name=f"serve.prefill_chunk[{self.name}]",
-            kind="serve.step", sync=True, **mmeta,
+            kind="serve.step", sync=True,
+            after_issue=self._deliver_hosted, **mmeta,
         )
         self._verify_jit = self._draft_jit = None
         if self.draft_len:
@@ -891,6 +921,7 @@ class GenerationEngine:
                 key=f"serve.{seq}:verify",
                 name=f"serve.verify[{self.name}]",
                 kind="serve.step", sync=True,
+                after_issue=self._deliver_hosted,
                 draft_len=self.draft_len, **mmeta,
             )
             self._draft_jit = _programs.instrument(
@@ -901,6 +932,7 @@ class GenerationEngine:
                 key=f"serve.{seq}:draft",
                 name=f"serve.draft[{self.name}]",
                 kind="serve.step", sync=True,
+                after_issue=self._deliver_hosted,
                 draft_len=self.draft_len, **mmeta,
             )
         #: distinct (name, abstract input signature) pairs dispatched —
@@ -939,6 +971,16 @@ class GenerationEngine:
         #: (each begins where the last ended), so their walls add up to
         #: the host time between two dispatches with nothing in between
         self._phases = _SpanChain()
+        #: what the steps so far owe and no program's arguments need,
+        #: oldest first: ``(fn, args)`` (:meth:`_owe`). Appended and
+        #: drained under the step lock only
+        self._owed: Deque[Tuple] = deque()
+        #: tokens and end marks handed over so far (:meth:`_drain`
+        #: reads the difference one delivery made)
+        self._delivered = [0, 0]
+        #: tokens handed over and seconds taken under the program now in
+        #: flight, for its span's ``delivered_tokens`` / ``deliver_s``
+        self._hosted = [0, 0.0]
         _m_pages_capacity.set(float(num_pages))
         _m_tp_degree.set(float(self.tp_degree), engine=self.name)
         #: estimated collective wall per dispatched step (0 solo): a
@@ -956,6 +998,7 @@ class GenerationEngine:
         # per-request cost attribution (obs/requests.py): observe every
         # finishing slot while it still holds its pages
         self.scheduler.on_request_done = self._account_request
+        self.scheduler.before_release = self._deliver_unless_wedged
 
     # -- tuned serving knobs ----------------------------------------------
 
@@ -1776,7 +1819,10 @@ class GenerationEngine:
     def step(self) -> bool:
         """One scheduler iteration: sweep expired deadlines, admit +
         prefill newcomers, grow pages (preempting on exhaustion), one
-        decode step for the batch. Returns whether work remains.
+        decode step for the batch. Returns whether work remains. When
+        it returns, every token the step produced is on its handle
+        (and every finished handle is closed): with no next program in
+        sight the delivery is not deferred past the return.
 
         Failure classification (the supervisor's contract,
         ``docs/fault_tolerance.md``): transient dispatch errors retry
@@ -1789,7 +1835,10 @@ class GenerationEngine:
         # whatever the caller did since its last step() is no phase of
         # this one; the engine's own loops keep the chain (_step_once)
         self._phases.reset()
-        return self._step_once()
+        try:
+            return self._step_once()
+        finally:
+            self._deliver_idle()
 
     def _step_once(self) -> bool:
         with self._step_lock:
@@ -1889,8 +1938,12 @@ class GenerationEngine:
                         _m_handles_failed.inc(reason=_fail_reason(e))
                 raise
         with _span("serve.bookkeeping", chain=self._phases):
-            self._refresh_gauges()
-            return self.scheduler.has_work()
+            more = self.scheduler.has_work()
+            if more:  # a program follows, and the gauges wait for it
+                self._owe(self._refresh_gauges)
+            else:
+                self._refresh_gauges()
+            return more
 
     def _note_oom(self) -> bool:
         """One more consecutive OOM recovery attempt; False once the
@@ -2101,24 +2154,19 @@ class GenerationEngine:
             recompute=recompute,
             pad_share=1.0 - valid / c,
             tokens_routed=routed,
-        ):
+        ) as sp:
             pool.k, pool.v, tok = run_with_retries(
                 dispatch,
                 what=f"serve.prefill_chunk request {req.request_id}",
             )
             self._wait_returned_t = time.perf_counter()
-        self._charge_collectives()
-        timings = req.handle.timings
-        self._charge_prefill_tokens(timings, valid, recompute)
-        timings["prefill_s"] = (
-            timings.get("prefill_s", 0.0) + time.perf_counter() - t0
+            self._note_hosted(sp)
+        self._owe(
+            self._charge_prefill, req.handle.timings,
+            self._prefill_chunk_jit, valid, recompute,
+            self._wait_returned_t - t0, routed,
         )
-        timings["prefill_chunks"] = timings.get("prefill_chunks", 0) + 1
-        self._charge_flops(timings, self._prefill_chunk_jit)
         act.prefill_pos = start + valid
-        _m_prefill_chunks.inc()
-        if routed:
-            _m_tokens_routed.inc(routed)
         if act.prefill_pos >= plen:
             self._register_prefix(act)
             self._emit(idx, act, int(tok))
@@ -2224,16 +2272,14 @@ class GenerationEngine:
                 dispatch, what=f"serve.prefill request {req.request_id}"
             )
             self._wait_returned_t = time.perf_counter()
-        timings = req.handle.timings
-        timings["prefill_s"] = (
-            timings.get("prefill_s", 0.0) + self._wait_returned_t - t0
-        )
+            self._note_hosted(sp)
         with _span("serve.readback", chain=self._phases):
-            self._charge_collectives()
             tok = int(tok)
         with _span("serve.emit", chain=self._phases, tokens=1) as sp:
-            self._charge_prefill_tokens(timings, plen, recompute)
-            self._charge_flops(timings, self._prefill_jit)
+            self._owe(
+                self._charge_prefill, req.handle.timings, self._prefill_jit,
+                plen, recompute, self._wait_returned_t - t0,
+            )
             act.prefill_pos = plen
             self._register_prefix(act)
             self._emit(idx, act, tok)
@@ -2250,8 +2296,10 @@ class GenerationEngine:
             self._record_program("decode", self._params_dev, pool.k, *args)
 
         # synced inside the retry window, like prefill (the host loop
-        # needs ``nxt`` before the next step anyway, so the sync costs
-        # no pipelining); same donation caveat as prefill
+        # needs ``nxt`` before the next step's arguments; what is done
+        # with the step before's tokens needs no more than the host, and
+        # runs between this call's issue and its wait: _deliver_hosted);
+        # same donation caveat as prefill
         def dispatch():
             import jax
 
@@ -2269,6 +2317,7 @@ class GenerationEngine:
                 dispatch, what="serve.decode_step"
             )
             self._wait_returned_t = time.perf_counter()
+            self._note_hosted(sp)
             if self._pairs_per_token:
                 # the expert layers' pair counts came back behind the
                 # tokens, in the one array the host reads anyway
@@ -2276,15 +2325,16 @@ class GenerationEngine:
                 self._note_routing(sp, nxt[s:], len(ready))
                 nxt = nxt[:s]
         with _span("serve.readback", chain=self._phases):
-            self._charge_collectives()
             nxt = np.asarray(nxt)
         with _span(
             "serve.emit", chain=self._phases, tokens=len(ready)
         ) as sp:
+            self._owe(self._charge_collectives)
             share = 1.0 / max(1, len(ready))
             for idx, act in ready:
-                self._charge_flops(
-                    act.req.handle.timings, self._decode_jit, share
+                self._owe(
+                    self._charge_flops,
+                    act.req.handle.timings, self._decode_jit, share,
                 )
                 self._emit(idx, act, int(nxt[idx]))
             if sp is not None:
@@ -2364,18 +2414,29 @@ class GenerationEngine:
             (per_layer.max(axis=1) / mean).mean()
         )
 
-    @staticmethod
-    def _charge_prefill_tokens(
-        timings: dict, tokens: int, recompute: int
+    def _charge_prefill(
+        self, timings: dict, prog, tokens: int, recompute: int,
+        wall: float, routed: Optional[int] = None,
     ) -> None:
-        """One prefill dispatch put ``tokens`` prompt tokens through the
-        model, ``recompute`` of which a preemption had computed once."""
+        """One prefill dispatch of ``prog`` took ``wall`` seconds and put
+        ``tokens`` prompt tokens through the model, ``recompute`` of
+        which a preemption had computed once; ``routed`` (a chunk's
+        token-expert pairs) marks the chunk program. Owed, not done in
+        the gap: nothing here is an argument of the next program."""
+        self._charge_collectives()
         timings["prefill_tokens"] = timings.get("prefill_tokens", 0) + tokens
         if recompute:
             timings["recomputed_tokens"] = (
                 timings.get("recomputed_tokens", 0) + recompute
             )
             _m_recomputed.inc(recompute)
+        timings["prefill_s"] = timings.get("prefill_s", 0.0) + wall
+        self._charge_flops(timings, prog)
+        if routed is not None:
+            timings["prefill_chunks"] = timings.get("prefill_chunks", 0) + 1
+            _m_prefill_chunks.inc()
+            if routed:
+                _m_tokens_routed.inc(routed)
 
     # -- speculative decoding ---------------------------------------------
 
@@ -2487,11 +2548,12 @@ class GenerationEngine:
 
         with _span(
             "serve.draft", chain=self._phases, occupancy=len(ready)
-        ):
+        ) as sp:
             g.k, g.v, out = run_with_retries(
                 dispatch, what="serve.draft"
             )
             self._wait_returned_t = time.perf_counter()
+            self._note_hosted(sp)
         # no _charge_collectives: the draft program is replicated —
         # it runs no cross-chip gathers even under a TP mesh
         for idx, act in ready:
@@ -2556,11 +2618,12 @@ class GenerationEngine:
         t0 = time.perf_counter()
         with _span(
             "serve.verify", chain=self._phases, occupancy=len(ready)
-        ):
+        ) as sp:
             pool.k, pool.v, u = run_with_retries(
                 dispatch, what="serve.verify"
             )
             self._wait_returned_t = time.perf_counter()
+            self._note_hosted(sp)
         verify_wall = self._wait_returned_t - t0
         _m_verify_s.observe(verify_wall)
         self._charge_collectives()
@@ -2630,22 +2693,140 @@ class GenerationEngine:
             )
 
     def _emit(self, idx: int, act: _Active, tok: int) -> None:
-        now = time.monotonic()
+        """The part of a token's emission the next program waits for:
+        the token joins ``generated`` (the next decode's input), and a
+        finished slot goes back with its pages, so :meth:`admit` fills
+        it in the very next step. Handing the token and the end mark to
+        the handle, and what is counted and charged with them, is owed
+        (:meth:`_deliver_token`, :meth:`_deliver_finish`)."""
+        first = act.req.emitted == 0 and not act.generated
         act.generated.append(tok)
-        act.req.handle._emit(tok)
-        _m_tokens.inc()
-        if act.req.emitted == 0 and len(act.generated) == 1:
+        self._owe(self._deliver_token, act, tok, first)
+        eos = act.req.eos_id
+        if (eos is not None and tok == eos) or act.remaining <= 0:
+            # the cost record counts the pages the slot held to its end
+            self._owe(self._deliver_finish, act, len(act.seq.pages))
+            self.scheduler.detach(idx)
+
+    # -- deferred delivery -------------------------------------------------
+
+    def _owe(self, fn, *args) -> None:
+        """Queue ``fn(*args)`` for the next delivery: work a step owes
+        that no program's arguments need. Stepping thread, step lock
+        held; run in order by :meth:`_drain`."""
+        self._owed.append((fn, args))
+
+    def _deliver_token(self, act: _Active, tok: int, first: bool) -> None:
+        """Hand one token to its handle — this wakes the stream's
+        thread — and stamp what a client sees: TTFT and ITL are taken
+        at the hand-over, not when the program that made the token
+        returned."""
+        handle = act.req.handle
+        if handle.done:
+            # closed past the step lock meanwhile (a wedged engine's
+            # handles, serve/fleet.py::_fence): what was not delivered
+            # before the end mark never is, and a replay recomputes it
+            return
+        now = time.monotonic()
+        handle._emit(tok)
+        self._delivered[0] += 1
+        if first:
             _m_ttft.observe(now - act.req.submitted_at)
         elif act.last_emit_t is not None:
             _m_itl.observe(now - act.last_emit_t)
         if act.last_emit_t is not None:
-            t = act.req.handle.timings
+            t = handle.timings
             t["decode_s"] = t.get("decode_s", 0.0) + now - act.last_emit_t
         act.last_emit_t = now
-        eos = act.req.eos_id
-        if (eos is not None and tok == eos) or act.remaining <= 0:
-            self.scheduler.finish(idx)
-            _m_requests.inc(status="completed")
+
+    def _deliver_finish(self, act: _Active, kv_pages: int) -> None:
+        """Close a finished request's handle, after its last token (the
+        queue keeps the order): the cost record first, as
+        :meth:`Scheduler.finish` has it."""
+        handle = act.req.handle
+        if handle.done:
+            return
+        try:
+            self._account_request(act, None, kv_pages)
+        except Exception:  # an accounting bug must not hang a handle
+            logger.warning("request cost record failed", exc_info=True)
+        _m_requests.inc(status="completed")
+        handle._finish(None)
+        self._delivered[1] += 1
+
+    def _drain(self, under_program: bool) -> Tuple[int, int]:
+        """Run everything owed, oldest first; returns the tokens and end
+        marks handed over. Delivery cannot fail a step: what an entry
+        raises is logged and the rest still runs."""
+        owed = self._owed
+        tokens, finished = self._delivered
+        while owed:
+            fn, args = owed.popleft()
+            try:
+                fn(*args)
+            except Exception:
+                logger.warning(
+                    "deferred delivery step %s failed",
+                    getattr(fn, "__name__", fn), exc_info=True,
+                )
+        tokens = self._delivered[0] - tokens
+        finished = self._delivered[1] - finished
+        if tokens:
+            _m_tokens.inc(tokens)
+            _m_delivered.inc(tokens)
+            if under_program:
+                _m_delivered_under_program.inc(tokens)
+        return tokens, finished
+
+    def _deliver_hosted(self) -> None:
+        """``after_issue`` of every step program: the program is issued,
+        the device works, and the thread is about to sleep on it with
+        the interpreter lock released — the streams' threads run then,
+        not in the gap before the next program."""
+        if self._owed:
+            t0 = time.perf_counter()
+            tokens, _ = self._drain(under_program=True)
+            self._hosted[0] += tokens
+            self._hosted[1] += time.perf_counter() - t0
+
+    def _note_hosted(self, sp) -> None:
+        """What the program whose wait just returned hosted, onto its
+        span (``delivered_tokens``, ``deliver_s``)."""
+        tokens, seconds = self._hosted
+        self._hosted = [0, 0.0]
+        if sp is not None:
+            sp.attrs["delivered_tokens"] = tokens
+            sp.attrs["deliver_s"] = seconds
+
+    def _deliver_now(self, chain: Optional[_SpanChain] = None) -> None:
+        """Deliver with no program to hide behind, in a span of its own
+        so that a device gap it causes has a name. Step lock held."""
+        if not self._owed:
+            return
+        with _span("serve.deliver", chain=chain) as sp:
+            tokens, finished = self._drain(under_program=False)
+            if sp is not None:
+                sp.attrs.update(tokens=tokens, finished=finished)
+
+    def _deliver_idle(self) -> None:
+        """The step loop has no next program in sight (it goes idle, or
+        hands control back to its caller): deliver as a phase of its
+        own."""
+        with self._step_lock:
+            self._deliver_now(chain=self._phases)
+
+    def _deliver_unless_wedged(self) -> None:
+        """The scheduler's ``before_release``: a request is about to be
+        failed, preempted, expired or closed, so its tokens go out
+        first. Whoever comes here holds the step lock or finds it free,
+        but for the fleet's fence of a wedged engine, which fails the
+        handles past a step that may never return: then what that step
+        owes stays undelivered for good (:meth:`_deliver_token`)."""
+        if self._step_lock.acquire(blocking=False):
+            try:
+                self._deliver_now()
+            finally:
+                self._step_lock.release()
 
     @staticmethod
     def _charge_flops(timings: dict, prog, share: float = 1.0) -> None:
@@ -2661,15 +2842,21 @@ class GenerationEngine:
                 timings.get("est_flops", 0.0) + float(flops) * share
             )
 
-    def _account_request(self, act: _Active, error) -> None:
-        """Scheduler finish hook: the request's terminal cost record
-        (``obs/requests.py``), taken while the slot still holds its
-        pages so holdings are countable. ``timings`` gets the same keys
-        so the HTTP response echoes them."""
+    def _account_request(
+        self, act: _Active, error, kv_pages: Optional[int] = None
+    ) -> None:
+        """The request's terminal cost record (``obs/requests.py``).
+        As the scheduler's finish hook (a failure) it is taken while the
+        slot still holds its pages, so holdings are countable; a
+        finished request's is owed, with the ``kv_pages`` the slot held
+        when it went back. ``timings`` gets the same keys so the HTTP
+        response echoes them."""
         req = act.req
         t = req.handle.timings
         t["tokens"] = req.emitted + len(act.generated)
-        t["kv_pages"] = max(int(t.get("kv_pages", 0)), len(act.seq.pages))
+        if kv_pages is None:
+            kv_pages = len(act.seq.pages)
+        t["kv_pages"] = max(int(t.get("kv_pages", 0)), kv_pages)
         if req.tenant:
             t["tenant"] = req.tenant
         _obs_requests.record_request(
@@ -2719,8 +2906,11 @@ class GenerationEngine:
         """Drive :meth:`step` until queue and slots are empty (the
         synchronous mode — tests and batch jobs)."""
         self._phases.reset()
-        while self._step_once():
-            pass
+        try:
+            while self._step_once():
+                pass
+        finally:
+            self._deliver_idle()
 
     def defragment(self):
         """Compact live KV pages to the lowest pool indices between steps
@@ -3064,6 +3254,8 @@ class GenerationEngine:
                     self._fail_inflight(e)
                     worked = False
                 if not worked:
+                    # no program follows: nothing waits out the sleep
+                    self._deliver_idle()
                     with _span(
                         "serve.idle_wait", chain=self._phases
                     ), self.scheduler._lock:

@@ -283,6 +283,13 @@ class Scheduler:
         #: or no hook falls through to :meth:`preempt` — preemption is
         #: always the fallback, never removed.
         self.on_pressure = None
+        #: optional ``fn()`` called before a request leaves its slot or
+        #: the queue any other way than the engine's own finish:
+        #: :meth:`finish`, :meth:`preempt`, :meth:`expire` with something
+        #: to evict, :meth:`fail_all`. The engine delivers there what its
+        #: steps still owe the handles (tokens produced and not yet
+        #: handed over), so no end mark overtakes a token
+        self.before_release = None
         #: why the last :meth:`admit` left requests waiting: ``"slots"``
         #: (every slot taken), ``"pages"`` (the pool could not supply
         #: the head's prompt pages), None (the queue drained)
@@ -573,6 +580,7 @@ class Scheduler:
         handle keeps streaming; re-admission emits only new tokens)."""
         act = self.slots[idx]
         assert act is not None
+        self._settle()
         self._drop_cow(act)
         act.seq.release()
         self.slots[idx] = None
@@ -612,12 +620,13 @@ class Scheduler:
 
     def detach(self, idx: int) -> _Active:
         """Release slot ``idx``'s pages WITHOUT closing its handle or
-        requeueing its request — the live-migration release
-        (``serve/tiers.py``): the caller has already serialized the
-        slot's state and will re-materialize it on another replica,
-        where the SAME handle keeps streaming. Unlike :meth:`finish`
-        this runs no terminal accounting (the destination engine
-        accounts the request when it actually finishes) and unlike
+        requeueing its request: the caller owns what happens to the
+        handle next. Live migration (``serve/tiers.py``) has already
+        serialized the slot's state and re-materializes it on another
+        replica, where the SAME handle keeps streaming; the engine's
+        own finish frees the slot for the next admission now and
+        closes the handle when it delivers (``engine._emit``). Unlike
+        :meth:`finish` this runs no terminal accounting and unlike
         :meth:`preempt` it records no preemption — nothing was lost.
         Returns the detached :class:`_Active` for the caller's
         bookkeeping."""
@@ -627,6 +636,15 @@ class Scheduler:
         act.seq.release()
         self.slots[idx] = None
         return act
+
+    def _settle(self) -> None:
+        """Run :attr:`before_release`; like the other hooks, a broken
+        one must not leak pages or hang a handle."""
+        if self.before_release is not None:
+            try:
+                self.before_release()
+            except Exception:
+                pass
 
     def _drop_cow(self, act: _Active) -> None:
         """Release a pending copy-on-write donor reference (taken by
@@ -644,6 +662,7 @@ class Scheduler:
         accounting bug must not leak pages or hang a handle."""
         act = self.slots[idx]
         assert act is not None
+        self._settle()
         if self.on_request_done is not None:
             try:
                 self.on_request_done(act, error)
@@ -675,6 +694,8 @@ class Scheduler:
                 if expired:
                     self._waiting = keep
                     self._lock.notify_all()  # queue shrank: wake submitters
+        if expired:
+            self._settle()
         for r in expired:
             r.handle._finish(
                 DeadlineExceededError(
@@ -707,6 +728,7 @@ class Scheduler:
         fail-fast path: a consumer must see a doomed engine's real error
         within a step, not hang to its timeout."""
         n = 0
+        self._settle()
         for i, a in enumerate(self.slots):
             if a is not None:
                 self.finish(i, error=error)

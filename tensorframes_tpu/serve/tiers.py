@@ -174,6 +174,10 @@ def export_slot(engine, request_id: int, reason: str = "handoff"):
     copy-on-write clone) — the caller falls back to its ordinary
     ladder. Raises only on a non-transient transfer failure."""
     with engine._step_lock:
+        # the handle is about to stream from another engine: what this
+        # one's steps still owe it goes out first, or the relay that
+        # carries it would be stale by the time it is delivered
+        engine._deliver_now()
         idx, act = _find_slot(engine, request_id)
         if act is None:
             return None

@@ -43,7 +43,12 @@ STEP_PHASES = [
 ]
 #: what a newcomer's single-shot prefill puts between admit and grow
 PREFILL_PHASES = ["serve.prefill", "serve.readback", "serve.emit"]
-ALL_SPANS = set(STEP_PHASES) | {"serve.prefill", "serve.idle_wait"}
+#: what the steps owe is delivered under the next program (ISSUE 29), so
+#: a step has no phase for it; ``serve.deliver`` is the phase of a
+#: delivery no program follows: before the loop's idle wait, and when
+#: ``step()`` / ``run_until_idle()`` hand control back
+IDLE_PHASES = ["serve.deliver", "serve.idle_wait"]
+ALL_SPANS = set(STEP_PHASES) | {"serve.prefill"} | set(IDLE_PHASES)
 
 
 @pytest.fixture(scope="module")
@@ -114,7 +119,8 @@ def test_step_loop_emits_leaf_phase_spans_in_order(lm, sink):
     while len(rest) >= len(STEP_PHASES) and "serve.decode_step" in rest[:7]:
         assert rest[:7] == STEP_PHASES
         rest = rest[7:]
-    assert "serve.decode_step" not in rest
+    # and the run ends on the one delivery no program hosted
+    assert rest == IDLE_PHASES[:1] and names.count("serve.deliver") == 1
     # leaves: none nested on the thread's stack, none overlapping in time,
     # and back to back: each phase begins where the last one ended
     assert all(e["depth"] == 0 and e["parent_id"] is None for e in events)
@@ -145,6 +151,18 @@ def test_phase_span_attrs(lm, sink):
     emits = by["serve.emit"]
     assert [e["attrs"]["tokens"] for e in emits] == [1, 1, 1, 1]
     assert [e["attrs"]["finished"] for e in emits] == [0, 0, 0, 1]
+    # each program hosts the delivery of the step before's token: the
+    # prefill none, the three decode steps one each, and the last token
+    # with the end mark goes out when run_until_idle returns
+    assert pre["delivered_tokens"] == 0 and pre["deliver_s"] == 0.0
+    hosted = [e["attrs"] for e in by["serve.decode_step"]]
+    assert [a["delivered_tokens"] for a in hosted] == [1, 1, 1]
+    assert all(0 < a["deliver_s"] < e["dur_s"] for a, e in zip(
+        hosted, by["serve.decode_step"]
+    ))
+    (last,) = by["serve.deliver"]
+    assert last["attrs"] == {"tokens": 1, "finished": 1}
+    assert list(h.result(timeout=5)) == list(h)
 
 
 def test_host_gap_lies_inside_the_wall_between_two_decode_steps(lm, sink):
