@@ -6,7 +6,7 @@ SHELL := /bin/bash
 
 PY ?= python
 
-.PHONY: test test-failfast test-fast test-attn test-chaos test-distjobs test-durability test-elastic test-fleet test-ha test-multihost test-obs test-obsfleet test-plan test-spec test-tenancy test-tiers test-tp test-tune soak verify bench bench-serve bench-attn bench-jobs bench-ingest bench-pipeline bench-autotune bench-check bench-check-update bench-all bench-attention dryrun install lint
+.PHONY: test test-failfast test-fast test-attn test-chaos test-distjobs test-durability test-elastic test-fleet test-ha test-multihost test-obs test-obsfleet test-plan test-spec test-tenancy test-tiers test-tp test-tune soak verify dryrun install lint
 
 install:
 	$(PY) -m pip install -e . --no-build-isolation
@@ -153,74 +153,6 @@ soak:
 test-multihost:
 	$(PY) -m pytest tests/test_multihost.py -q
 
-# headline metric (one JSON line; targets the attached TPU)
-bench:
-	$(PY) bench.py
-
-# serving trajectory: tokens/s + inter-token latency at 1/4/16 concurrency,
-# the fleet's aggregate tokens/s at 1/2/4 replicas, the
-# tensor-parallel axis — one replica spanning TP=1/2/4 simulated chips
-# with tok/s + aggregate KV pages per degree — and the speculative-
-# decoding axis (TFT_BENCH_SPEC, default 0,2,4: draft length k with
-# tok/s, inter-token p50/p99 and acceptance rate on a repeated-suffix
-# workload). (TFT_BENCH_REPLICAS=1,2, TFT_BENCH_TP=1,2 and
-# TFT_BENCH_SPEC=0,4 shrink axes for smoke runs; an empty value
-# disables that axis entirely)
-bench-serve:
-	$(PY) bench.py decode_serve
-
-# decode paged-KV read microbench: gather vs the fused ragged
-# paged-attention kernel — GB/s + tokens/s, one JSON line
-# (TFT_BENCH_ATTN_SLOTS / _PAGES / _PAGE_SIZE shape the batch)
-bench-attn:
-	$(PY) bench.py paged_attn
-
-# durable-job overhead: map_rows with the journal on vs off, plus the
-# K-subprocess distributed-drain workers axis (TFT_BENCH_JOB_WORKERS,
-# default 1,2,4; empty disables) — one JSON line
-bench-jobs:
-	$(PY) bench.py map_rows
-
-# streaming ingest/egress: monolithic vs chunked-overlapped h2d/d2h GB/s
-# on the 3.1 GB r05 scoring column, plus cold ingest→upload→score wall
-# clock (one JSON line; TFT_BENCH_INGEST_ROWS shrinks it for smoke runs)
-bench-ingest:
-	$(PY) bench.py ingest
-
-# logical-plan pipeline: a 3-op map chain + reduce, fused vs
-# op-at-a-time — rows/s, framework overhead per logical op, and the
-# h2d byte delta from column pruning (one JSON line;
-# TFT_BENCH_PIPELINE_ROWS / _OPS shrink it for smoke runs)
-bench-pipeline:
-	$(PY) bench.py pipeline
-
-# the self-tuning layer: cold-tune wall (trials included) vs
-# cached-tune wall (persisted winners, zero trials), plus
-# tuned-vs-static rows/s and tok/s on the map_rows / decode_serve
-# smoke shapes (one JSON line; TFT_BENCH_ROWS and
-# TFT_BENCH_TUNE_BUDGET_S shrink it)
-bench-autotune:
-	$(PY) bench.py autotune
-
-# the perf-regression gate: fresh smoke-sized `bench.py map_rows` +
-# `decode_serve` runs compared against BASELINE.json's bench_gate block
-# within tolerance (default 30%; TFT_BENCH_TOLERANCE_PCT overrides) —
-# non-zero exit on regression, so the bench trajectory is enforceable
-# instead of advisory. Re-record after a legitimate perf change with
-# bench-check-update (the diff then documents the move).
-bench-check:
-	$(PY) benchmarks/bench_check.py
-
-bench-check-update:
-	$(PY) benchmarks/bench_check.py --update
-
-# all BASELINE configs + extras
-bench-all:
-	$(PY) benchmarks/run_all.py
-
-bench-attention:
-	$(PY) benchmarks/attention_bench.py
-
 # the CPU sim-mesh check (self-provisions 8 virtual CPU devices; the
 # chip evidence is chip_smoke.py)
 dryrun:
@@ -228,4 +160,4 @@ dryrun:
 
 # compile-check every module (no external linter in this environment)
 lint:
-	$(PY) -m compileall -q tensorframes_tpu benchmarks examples tests bench.py __graft_entry__.py chip_smoke.py
+	$(PY) -m compileall -q tensorframes_tpu chipbench examples tests __graft_entry__.py chip_smoke.py

@@ -59,8 +59,8 @@ OUT_DIR = os.path.join(HERE, "chiprun_out")
 #: than this fraction of its largest logit magnitude.
 LOGIT_TOL_FRACTION = 0.05
 #: argmax agreement of chip predictions with the f32 numpy oracle on
-#: MNIST-LR scoring: same bf16-pass rounding, near-ties flip (bench.py has
-#: used this bar since r01)
+#: MNIST-LR scoring: same bf16-pass rounding, near-ties flip (the bar the
+#: r01 scoring rounds set)
 SCORING_AGREEMENT = 0.99
 
 

@@ -882,8 +882,8 @@ def map_blocks(
                     part_sizes.append(0)
                     continue
                 # NOTE: map_blocks keeps results device-resident so chained
-                # passes pipeline without host syncs (the 20x headline win in
-                # bench.py). Only errors raised at DISPATCH are retried here;
+                # passes pipeline without host syncs (why chained maps stay
+                # lazy). Only errors raised at DISPATCH are retried here;
                 # a failure during async execution surfaces later, at
                 # materialization — where _recover_lost_partitions re-runs
                 # just the partitions whose outputs were lost. map_rows

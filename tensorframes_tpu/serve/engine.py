@@ -574,7 +574,7 @@ class GenerationEngine:
             # the fused read's key tile, so the flash sweep's block_k —
             # ``paged_page_size_hint`` — is the default, clamped to the
             # sequence bound; the autotuner's ``serve.page_size`` winner
-            # (tuned by tune_serve_knobs / bench.py autotune) overrides
+            # (the measured search is tune.tune_serve_knobs) overrides
             # the hint. An EXPLICIT argument wins over both and is
             # taken verbatim (no clamp — callers pinning a page size
             # keep exactly the pool layout they asked for).

@@ -104,7 +104,7 @@ _warned_mode = set()
 def mode() -> str:
     """The active tuning mode: ``"off"`` | ``"cached"`` | ``"online"``.
     ``TFT_TUNE=0`` in the environment is the kill switch (checked live,
-    so the bench-regression gate can pin it per subprocess); then
+    so a harness can pin it per subprocess without a restart); then
     ``Config.autotune`` (master switch) and ``Config.tune_mode``."""
     if os.environ.get("TFT_TUNE", "") == "0":
         return "off"
@@ -202,8 +202,8 @@ class Tuner:
         one non-default candidate;
         surfaces with no safe in-process trial (the serving knobs at
         engine init) pass ``trial=None`` and stay cache-only — their
-        winners come from :func:`tune_serve_knobs` / ``bench.py
-        autotune`` / an operator pin."""
+        winners come from :func:`tune_serve_knobs` or an operator
+        pin (:meth:`Tuner.pin`)."""
         m = mode()
         if m == "off":
             return dict(default)
@@ -749,7 +749,7 @@ def tune_serve_knobs(
     prompt batch through a throwaway
     :class:`~tensorframes_tpu.serve.GenerationEngine`'s prefill +
     decode, and the median-wall winner is persisted for every later
-    engine with this signature (``bench.py autotune`` and operators
+    engine with this signature (operators and warm-up scripts
     call this; byte-identity of the streams across every candidate is
     a serve-suite invariant — page size, chunking, slot count, pool
     size, and draft length never change emitted tokens, only

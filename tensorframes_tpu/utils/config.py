@@ -190,8 +190,8 @@ class Config:
     #: (attention tiles, transfer chunk/streams, serve page size +
     #: prefill chunk, map-rows block-row budget) fall straight back to
     #: its static default. ``TFT_TUNE=0`` in the environment forces the
-    #: same off state regardless of this field (checked live — the
-    #: bench-regression gate pins it). See docs/tuning.md.
+    #: same off state regardless of this field (checked live, per
+    #: call, not once at import). See docs/tuning.md.
     autotune: bool = True
     #: tuning mode when ``autotune`` is on: ``"cached"`` (default)
     #: serves winners from the persisted tuning store but never runs a

@@ -73,7 +73,7 @@ def _tune_store_in_tmp(tmp_path_factory):
     """The self-tuning layer's persisted store (tensorframes_tpu/tune)
     reads/writes the test session's tmp dir: tests must neither pollute
     the developer's store nor inherit its stale winners (a tuned
-    block-row budget from a bench run would silently change every
+    block-row budget from an earlier run would silently change every
     map_rows plan under test). Unlike the debug/costs fixtures above
     this one FORCES the path — an inherited TFT_TUNE_FILE (e.g. the
     shared fleet store docs/tuning.md recommends exporting) would both

@@ -394,9 +394,9 @@ class TestServingUnderChaos:
     def test_disabled_chaos_adds_no_programs(self, lm):
         """The overhead half of the acceptance bar that is assertable in
         a unit test: with no schedule installed the sites are inert and
-        the engine still compiles exactly two step programs (the bench
-        half — decode_serve within noise — is measured by `make
-        bench-serve`, which reports the active chaos spec)."""
+        the engine still compiles exactly two step programs (the timing
+        half — serving within noise of a build without the sites — is
+        the serving cells' to show, PERF.md §4)."""
         assert not chaos.enabled()
         rng = np.random.default_rng(31)
         eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)

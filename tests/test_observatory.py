@@ -837,208 +837,18 @@ class TestEndpoints:
 
 
 # ---------------------------------------------------------------------------
-# bench-check gate logic
-# ---------------------------------------------------------------------------
-
-
-class TestBenchCheck:
-    @staticmethod
-    def _load_module():
-        import importlib.util
-        from pathlib import Path
-
-        path = (
-            Path(__file__).resolve().parent.parent
-            / "benchmarks"
-            / "bench_check.py"
-        )
-        spec = importlib.util.spec_from_file_location("bench_check", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def _gate(self, tmp_path, mod, baseline_value):
-        base = {
-            "bench_gate": {
-                "tolerance_pct": 20.0,
-                "env": {},
-                "metrics": {
-                    "map_rows_journaled_rows_per_sec": {
-                        "value": baseline_value,
-                        "unit": "rows/s",
-                        "config": "map_rows",
-                    }
-                },
-            }
-        }
-        target = tmp_path / "BASELINE.json"
-        target.write_text(json.dumps(base))
-        mod.BASELINE = str(target)
-        return target
-
-    def test_within_tolerance_passes(self, tmp_path, monkeypatch):
-        mod = self._load_module()
-        self._gate(tmp_path, mod, 1000.0)
-        monkeypatch.setattr(
-            mod, "_run_bench",
-            lambda config, env: {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 850.0,  # -15% with 20% tolerance
-            },
-        )
-        assert mod.check() == 0
-
-    def test_regression_fails_nonzero(self, tmp_path, monkeypatch):
-        mod = self._load_module()
-        self._gate(tmp_path, mod, 1000.0)
-        monkeypatch.setattr(
-            mod, "_run_bench",
-            lambda config, env: {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 700.0,  # -30% with 20% tolerance
-            },
-        )
-        assert mod.check() == 1
-
-    def test_tolerance_env_override(self, tmp_path, monkeypatch):
-        mod = self._load_module()
-        self._gate(tmp_path, mod, 1000.0)
-        monkeypatch.setenv("TFT_BENCH_TOLERANCE_PCT", "50")
-        monkeypatch.setattr(
-            mod, "_run_bench",
-            lambda config, env: {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 700.0,
-            },
-        )
-        assert mod.check() == 0
-
-    def test_per_metric_tolerance_overrides_global(
-        self, tmp_path, monkeypatch
-    ):
-        """A `tolerances[<metric>]` entry widens (or narrows) just that
-        metric's band — the fix for the false alarm where map_rows'
-        machine-to-machine variance is wider than the global band that
-        fits the decode bench."""
-        mod = self._load_module()
-        target = self._gate(tmp_path, mod, 1000.0)
-        base = json.loads(target.read_text())
-        base["bench_gate"]["tolerances"] = {
-            "map_rows_journaled_rows_per_sec": 45.0
-        }
-        target.write_text(json.dumps(base))
-        monkeypatch.setattr(
-            mod, "_run_bench",
-            lambda config, env: {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 600.0,  # -40%: fails at 20% global, ok at 45%
-            },
-        )
-        assert mod.check() == 0
-        # a metric WITHOUT an entry keeps the global band
-        base["bench_gate"]["tolerances"] = {"some_other_metric": 45.0}
-        target.write_text(json.dumps(base))
-        assert mod.check() == 1
-
-    def test_env_override_beats_per_metric_tolerance(
-        self, tmp_path, monkeypatch
-    ):
-        mod = self._load_module()
-        target = self._gate(tmp_path, mod, 1000.0)
-        base = json.loads(target.read_text())
-        base["bench_gate"]["tolerances"] = {
-            "map_rows_journaled_rows_per_sec": 45.0
-        }
-        target.write_text(json.dumps(base))
-        monkeypatch.setenv("TFT_BENCH_TOLERANCE_PCT", "10")
-        monkeypatch.setattr(
-            mod, "_run_bench",
-            lambda config, env: {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 700.0,  # -30%: inside 45%, outside env's 10%
-            },
-        )
-        assert mod.check() == 1
-
-    def test_update_preserves_per_metric_tolerances(
-        self, tmp_path, monkeypatch
-    ):
-        """--update re-measures values but must carry the `tolerances`
-        block forward: the bands encode measured host variance, not the
-        baseline numbers being replaced."""
-        mod = self._load_module()
-        target = self._gate(tmp_path, mod, 1000.0)
-        base = json.loads(target.read_text())
-        base["bench_gate"]["tolerances"] = {
-            "map_rows_journaled_rows_per_sec": 45.0
-        }
-        target.write_text(json.dumps(base))
-        results = {
-            "map_rows": {
-                "metric": "map_rows_journaled_rows_per_sec",
-                "value": 1234.5,
-                "unit": "rows/s",
-            },
-            "decode_serve": {
-                "metric": "decode_serve_tokens_per_sec",
-                "value": 99.0,
-                "unit": "tok/s",
-            },
-        }
-        monkeypatch.setattr(
-            mod, "_run_bench", lambda config, env: results[config]
-        )
-        assert mod.update() == 0
-        rewritten = json.loads(target.read_text())["bench_gate"]
-        assert rewritten["tolerances"] == {
-            "map_rows_journaled_rows_per_sec": 45.0
-        }
-        assert (
-            rewritten["metrics"]["map_rows_journaled_rows_per_sec"]["value"]
-            == 1234.5
-        )
-
-    def test_missing_gate_block_is_a_setup_error(self, tmp_path):
-        mod = self._load_module()
-        target = tmp_path / "BASELINE.json"
-        target.write_text(json.dumps({"metric": "x"}))
-        mod.BASELINE = str(target)
-        assert mod.check() == 2
-
-    def test_repo_baseline_has_a_recorded_gate(self):
-        """The committed BASELINE.json must actually carry the gate the
-        Makefile target reads (a fresh clone's `make bench-check` should
-        compare, not error)."""
-        from pathlib import Path
-
-        base = json.loads(
-            (Path(__file__).resolve().parent.parent / "BASELINE.json")
-            .read_text()
-        )
-        gate = base.get("bench_gate")
-        assert gate and gate["metrics"]
-        assert set(gate["metrics"]) == {
-            "map_rows_journaled_rows_per_sec",
-            "decode_serve_tokens_per_sec",
-        }
-        for entry in gate["metrics"].values():
-            assert entry["value"] > 0
-
-
-# ---------------------------------------------------------------------------
-# sampler overhead (the bench axis' assertable half)
+# sampler overhead
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.slow
 class TestSamplerOverhead:
     def test_sampler_overhead_within_budget(self):
-        """The ISSUE-12 ≤1% budget, asserted on the map_rows microbench
-        shape the bench measures (`detail.observability.sampler_*`):
-        interleaved best-of passes with the background sampler at a
-        0.25s cadence vs parked. The assert allows 5% — this shared
-        single-core CI host jitters more than the budget itself, and the
-        bench trajectory tracks the honest number every round; a wired
+        """The ISSUE-12 ≤1% budget, asserted on a map_rows scoring
+        pass: interleaved best-of passes with the background sampler at
+        a 0.25s cadence vs parked. The assert allows 5% — this shared
+        CI host jitters more than the budget itself, and no chip run
+        has taken the number (docs/observability.md); a wired
         per-dispatch cost (the failure this guards) shows up as tens of
         percent."""
         import time as _time

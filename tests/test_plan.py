@@ -605,9 +605,10 @@ class TestJournaledPipelines:
 
 class TestOverhead:
     def test_fused_framework_overhead_is_lower(self):
-        """The bench (`make bench-pipeline`) publishes the ≥2× number;
-        this test pins a conservative floor so a regression that erodes
-        the win fails loudly without making CI timing-flaky."""
+        """No benchmark cell measures the fused pipeline's win yet
+        (ROADMAP S6, W5–W7); this test pins a conservative floor so a
+        regression that erodes it fails loudly without making CI
+        timing-flaky."""
         import time
 
         set_config(**TOGGLE_SETS["all_on"])
@@ -616,8 +617,8 @@ class TestOverhead:
         y = rng.normal(size=(64, 8)).astype(np.float32)
         # one frame, built outside the timed loop: frame construction +
         # analyze cost the same in both modes and would swamp the
-        # per-op framework overhead being compared. The pipeline mirrors
-        # the bench's: a map_blocks chain + a dead decoy op + a hoisted
+        # per-op framework overhead being compared. The pipeline is
+        # a map_blocks chain + a dead decoy op + a hoisted
         # reduce — 5 logical ops collapsing to one program.
         df = tft.TensorFrame.from_columns({"x": x, "y": y}).analyze()
 
@@ -643,7 +644,7 @@ class TestOverhead:
         eager = best_of()
         # a deliberately loose floor: min-of-25 wall clocks on shared CI
         # boxes still jitter by tens of µs, and the honest ratio moves
-        # with workload shape (the bench's own config measures 2.3×).
+        # with workload shape (a sandbox-CPU round once read 2.3×).
         # What must never regress is the *direction*: the fused pipeline
         # strictly beats op-at-a-time on framework overhead.
         assert fused < eager / 1.1, (fused, eager)

@@ -23,8 +23,7 @@ Covers the three pieces and their wiring:
   served in this process with zero trials (asserted on the tuner's own
   counters);
 - satellites: the ``paged_page_size_hint`` serving default + /healthz
-  report, the ``bench_check`` gate pinning ``TFT_TUNE=0``, /statusz +
-  /varz export, ``explain(analyze=True)``.
+  report, /statusz + /varz export, ``explain(analyze=True)``.
 """
 
 import json
@@ -1188,18 +1187,6 @@ def _http(host, port, path):
 
 
 class TestExportSurfaces:
-    def test_bench_check_gate_pins_tune_kill_switch(self):
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "benchmarks",
-        ))
-        try:
-            import bench_check
-
-            assert bench_check.GATE_ENV["TFT_TUNE"] == "0"
-        finally:
-            sys.path.pop(0)
-
     def test_explain_analyze_appends_tuned_table(self, tune_env):
         set_config(autotune=True, tune_mode="cached")
         tune.pin("t.explain", "sig", {"n": 3})
