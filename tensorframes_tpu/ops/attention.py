@@ -490,6 +490,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, layer=None):
 #: table rows :func:`paged_attention_live` walks at a time, about
 LIVE_BLOCK_PAGES = 64
 
+#: slots that share one trip count in :func:`paged_attention_live`'s
+#: single-query walk of a table of several blocks
+LIVE_GROUP_SLOTS = 8
+
 
 def live_read_blocks(rows: int, block_pages: int = LIVE_BLOCK_PAGES):
     """``(blocks, rows per block)`` of a ``rows``-row page table under
@@ -498,6 +502,45 @@ def live_read_blocks(rows: int, block_pages: int = LIVE_BLOCK_PAGES):
     of 16) is one block."""
     n_blocks = max(1, rows // int(block_pages))
     return n_blocks, -(-rows // n_blocks)
+
+
+def live_read_group(slots: int) -> int:
+    """Slots that walk together under :func:`paged_attention_live`:
+    :data:`LIVE_GROUP_SLOTS` where ``slots`` is several whole groups of
+    that many, else all of them (one group: one trip count for the
+    batch)."""
+    group = LIVE_GROUP_SLOTS
+    return group if slots > group and slots % group == 0 else slots
+
+
+def live_read_trips(live, span: int, n_blocks: int, group: int):
+    """``(order, trips)`` of the walk over slots holding ``live`` keys
+    past their table's first row (``[S]`` integers, any order; a numpy
+    array on the host, a traced one inside the program — the same
+    arithmetic counts what the program reads): ``order`` ``[S]`` lists
+    the slots longest first, slots ``order[g * group:(g + 1) * group]``
+    are group ``g``, and ``trips[g]`` is the blocks of ``span`` positions
+    that group walks — up to the one that holds its own longest slot's
+    last key, at least one, at most the table's ``n_blocks``. The walk
+    reads ``trips.sum() * group * span`` positions."""
+    xp = np if isinstance(live, np.ndarray) else jnp
+    order = xp.argsort(-live)
+    lead = live[order[::group]]  # ordered: a group's first is its longest
+    return order, xp.clip(-(-lead // span), 1, n_blocks)
+
+
+def live_read_positions(lengths, slots: int, rows: int, page_size: int) -> int:
+    """Key positions a layer's single-query walk of a ``rows``-row table
+    touches for a batch of ``slots`` slots of which ``lengths`` hold
+    that many keys and the others are idle (one key, the trash page):
+    the host's account of what :func:`paged_attention_live` reads, by
+    the arithmetic its trip counts come from."""
+    n_blocks, bp = live_read_blocks(rows)
+    together = live_read_group(slots)
+    live = np.ones(slots, np.int64)
+    live[: len(lengths)] = lengths
+    _, trips = live_read_trips(live, bp * page_size, n_blocks, together)
+    return int(trips.sum()) * together * bp * page_size
 
 
 def paged_attention_live(
@@ -518,16 +561,29 @@ def paged_attention_live(
     and, with a ``window``, ``p - window < j``.
 
     The table is walked ``block_pages`` rows at a time under the
-    online-softmax recurrence, and the walk stops at the block that
-    holds the longest slot's last live key: the trip count is data, so
-    one program serves every mix of lengths, and a step moves the K and
-    V of the pages that are live (rows past a slot's own end name the
-    trash page: one page, read again and again, and masked) instead of
-    ``max_seq_len`` positions for every slot. No ``[S, T * page_size]``
-    copy of a slot's whole table is ever made, and the scores of a span
-    exist one block at a time. Scores, softmax and the accumulator are
-    float32 whatever the pool holds. Returns ``[S, C, n_kv * group *
-    hd]`` float32."""
+    online-softmax recurrence, and the trip counts are data, so one
+    program serves every mix of lengths. With one query a slot and
+    several whole groups of :data:`LIVE_GROUP_SLOTS` slots
+    (:func:`live_read_group`) the slots are ordered by live length on
+    the device, longest first, and each group of neighbours walks to the
+    block that holds ITS longest slot's last live key
+    (:func:`live_read_trips`): one loop over the ``(group, block)``
+    pairs that hold a live key, each trip gathering its group's rows of
+    the table and folding them into that group's rows of the carry; the
+    result goes back through the inverse of the order. A slot's own
+    blocks are visited in the same order with the same operands
+    whoever shares its group, and a block past its end adds exact
+    zeros, so its result does not depend on its neighbours. Any other
+    shape (a span of queries, fewer slots than two groups, a table of
+    one block) takes one trip count for the batch, the longest slot's.
+    Either way a step moves the K and V of about the pages that are
+    live (rows past a slot's own end name the trash page: one page,
+    read again and again, and masked) instead of ``max_seq_len``
+    positions for every slot. No ``[S, T * page_size]`` copy of a
+    slot's whole table is ever made, and the scores of a span exist one
+    block at a time. Scores, softmax and the accumulator are float32
+    whatever the pool holds. Returns ``[S, C, n_kv * group * hd]``
+    float32."""
     slots, c, n_kv, group, hd = q.shape
     ps = k_pages.shape[-2]
     t = table.shape[1]
@@ -551,8 +607,10 @@ def paged_attention_live(
     else:
         qc = q.astype(k_pages.dtype)
 
-    def block(j, carry):
+    def block(j, carry, qc, table, first, q_pos, lengths):
+        """Fold block ``j`` of these slots' tables into their carry."""
         m, l, acc = carry
+        slots = table.shape[0]
         rows = jax.lax.dynamic_slice_in_dim(table, j * bp, bp, axis=1)
         kb = k_pages[layer, rows].reshape(slots, span, n_kv * hd)
         vb = v_pages[layer, rows].reshape(slots, span, n_kv * hd)
@@ -602,14 +660,48 @@ def paged_attention_live(
         jnp.zeros(stat, jnp.float32),
         jnp.zeros(stat + (hd,), jnp.float32),
     )
+    per_slot = (qc, table, first, q_pos, lengths)
+    together = live_read_group(slots) if merged and n_blocks > 1 else slots
+    order = None  # the caller's slot numbering
     if n_blocks == 1:
-        carry = block(0, carry)
-    else:
+        carry = block(0, carry, *per_slot)
+    elif together == slots:
         live = jnp.max(lengths - first * ps)  # keys past the first row
         trips = jnp.clip(-(-live // span), 1, n_blocks)
-        carry = jax.lax.fori_loop(0, trips, block, carry)
+        carry = jax.lax.fori_loop(
+            0, trips, lambda j, carry: block(j, carry, *per_slot), carry
+        )
+    else:
+        order, trips = live_read_trips(
+            lengths - first * ps, span, n_blocks, together
+        )
+        per_slot = tuple(x[order] for x in per_slot)
+        ends = jnp.cumsum(trips)
+
+        def pair(i, carry):
+            """Trip ``i`` is block ``i - (trips before group g)`` of the
+            group ``g`` whose trips hold it."""
+            g = jnp.sum(i >= ends)
+            at = g * together
+
+            def take(x):
+                return jax.lax.dynamic_slice_in_dim(x, at, together)
+
+            folded = block(
+                i - (ends[g] - trips[g]),
+                tuple(take(x) for x in carry),
+                *(take(x) for x in per_slot),
+            )
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(x, y, at, 0)
+                for x, y in zip(carry, folded)
+            )
+
+        carry = jax.lax.fori_loop(0, ends[-1], pair, carry)
     _, l, acc = carry
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    if order is not None:  # back to the caller's slot numbering
+        out = out[jnp.argsort(order)]
     return out.reshape(slots, c, n_kv * group * hd)
 
 

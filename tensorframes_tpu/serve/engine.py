@@ -1329,11 +1329,11 @@ class GenerationEngine:
         """The decode program of a long-sequence model: the block walk
         and the sampling of :meth:`_decode_impl`, with the read bounded
         by what is live (``ops.paged_attention_live``: a window layer's
-        table is the window's pages, a full layer's walk stops at the
-        longest slot's last page), the window mask in that read, one
-        page table per cache kind, and the expert layers' routing
-        counts packed behind the tokens in the one array the host reads
-        back."""
+        table is the window's pages; the slots of a full layer's walk go
+        in groups by length, each to its own longest's last page), the
+        window mask in that read, one page table per cache kind, and the
+        expert layers' routing counts packed behind the tokens in the
+        one array the host reads back."""
         import jax
         import jax.numpy as jnp
 
@@ -2370,9 +2370,9 @@ class GenerationEngine:
         query can see, by cache kind (``kv_tokens_read_<kind>`` /
         ``kv_tokens_live_<kind>``) and, weighted by each kind's layers,
         for the mean layer. A window layer's table is gathered whole
-        for every slot; a full layer's is walked a block at a time up
-        to the longest slot's last."""
-        from ..ops.attention import live_read_blocks
+        for every slot; a full layer's is walked group by group, as
+        ``live_read_positions`` and the program's own trips count it."""
+        from ..ops.attention import live_read_positions
 
         ps = self.page_size
         lengths = [a.length for _, a in ready]
@@ -2384,9 +2384,9 @@ class GenerationEngine:
                 read = self.max_slots * width * ps
                 live = sum(min(l, kind.window) for l in lengths)
             else:
-                n_blocks, rows = live_read_blocks(width)
-                trips = min(-(-max(lengths) // (rows * ps)), n_blocks)
-                read = self.max_slots * trips * rows * ps
+                read = live_read_positions(
+                    lengths, self.max_slots, width, ps
+                )
                 live = sum(lengths)
             attrs[f"kv_tokens_read_{kind.name}"] = read
             attrs[f"kv_tokens_live_{kind.name}"] = live

@@ -17,7 +17,12 @@ if ROOT not in sys.path:
 
 from chipbench.models import mellum  # noqa: E402
 from tensorframes_tpu.models import transformer as tr  # noqa: E402
-from tensorframes_tpu.ops.attention import paged_attention_live  # noqa: E402
+from tensorframes_tpu.ops import attention  # noqa: E402
+from tensorframes_tpu.ops.attention import (  # noqa: E402
+    live_read_positions,
+    live_read_trips,
+    paged_attention_live,
+)
 from tensorframes_tpu.parallel.moe import (  # noqa: E402
     init_moe,
     moe_ffn,
@@ -360,40 +365,223 @@ def test_window_pages_are_released_behind_the_window():
 # ------------------------------------------------------ live-bounded read
 
 
-@pytest.mark.parametrize("window", [0, 24])
-@pytest.mark.parametrize("block_pages", [2, 64])
-def test_live_read_agrees_with_dense_attention(window, block_pages):
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(7)
-    slots, c, n_kv, group, hd, ps, pages = 3, 5, 2, 2, 8, 4, 12
-    lengths = np.asarray([37, 9, 20], np.int32)
+def _scattered_pool(rng, lengths, pages, idle, ps=4, n_kv=2, hd=8):
+    """Keys and values of ``len(lengths)`` slots, each slot's pages
+    scattered through layer 1 of a two-layer pool; the slots of ``idle``
+    name the trash page (the pool's last) in every row."""
+    slots = len(lengths)
     k = rng.normal(size=(slots, pages * ps, n_kv, hd)).astype(np.float32)
     v = rng.normal(size=k.shape).astype(np.float32)
-    q = rng.normal(size=(slots, c, n_kv, group, hd)).astype(np.float32)
-    # every slot's pages scattered through one pool, layer 1 of 2
-    order = rng.permutation(slots * pages)
-    table = order.reshape(slots, pages).astype(np.int32)
-    pool_k = np.zeros((2, slots * pages + 1, ps, n_kv * hd), np.float32)
+    trash = slots * pages
+    table = rng.permutation(trash).reshape(slots, pages).astype(np.int32)
+    pool_k = np.zeros((2, trash + 1, ps, n_kv * hd), np.float32)
     pool_v = np.zeros_like(pool_k)
     for s in range(slots):
         pool_k[1, table[s]] = k[s].reshape(pages, ps, n_kv * hd)
         pool_v[1, table[s]] = v[s].reshape(pages, ps, n_kv * hd)
+    # what idle slots and padding wrote there: finite, and never seen
+    pool_k[1, trash] = rng.normal(size=(ps, n_kv * hd))
+    pool_v[1, trash] = rng.normal(size=(ps, n_kv * hd))
+    for s in idle:
+        table[s] = trash
+        k[s] = pool_k[1, trash].reshape(ps, n_kv, hd)[0]
+        v[s] = pool_v[1, trash].reshape(ps, n_kv, hd)[0]
+    return k, v, table, pool_k, pool_v
+
+
+def _dense_attention(q, k, v, q_pos, length, window):
+    """One slot's attention the plain way: ``q`` ``[C, n_kv, group, hd]``
+    at positions ``q_pos`` ``[C]`` over its first ``length`` keys."""
+    import jax
+    import jax.numpy as jnp
+
+    pos = np.arange(k.shape[0])
+    out = []
+    for qi, p_i in zip(q, q_pos):
+        seen = (pos <= p_i) & (pos < length)
+        if window:
+            seen &= pos > p_i - window
+        sc = np.einsum("kgd,tkd->kgt", qi, k) / np.sqrt(q.shape[-1])
+        sc = np.where(seen[None, None], sc, -np.inf)
+        p = np.asarray(jax.nn.softmax(jnp.asarray(sc), -1))
+        out.append(np.einsum("kgt,tkd->kgd", p, v).reshape(-1))
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("block_pages", [2, 64])
+def test_live_read_agrees_with_dense_attention(window, block_pages):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(7)
+    slots, c, n_kv, group, hd, pages = 3, 5, 2, 2, 8, 12
+    lengths = np.asarray([37, 9, 20], np.int32)
+    k, v, table, pool_k, pool_v = _scattered_pool(rng, lengths, pages, ())
+    q = rng.normal(size=(slots, c, n_kv, group, hd)).astype(np.float32)
     q_pos = (lengths[:, None] - c + np.arange(c)[None]).astype(np.int32)
     got = paged_attention_live(
         jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
         jnp.asarray(table), jnp.zeros(slots, jnp.int32), jnp.asarray(q_pos),
         jnp.asarray(lengths), 1, window=window, block_pages=block_pages,
     )
-    pos = np.arange(pages * ps)
     for s in range(slots):
-        for i in range(c):
-            seen = (pos <= q_pos[s, i]) & (pos < lengths[s])
-            if window:
-                seen &= pos > q_pos[s, i] - window
-            sc = np.einsum("kgd,tkd->kgt", q[s, i], k[s]) / np.sqrt(hd)
-            sc = np.where(seen[None, None], sc, -np.inf)
-            p = np.asarray(jax.nn.softmax(jnp.asarray(sc), -1))
-            want = np.einsum("kgt,tkd->kgd", p, v[s]).reshape(-1)
-            np.testing.assert_allclose(np.asarray(got[s, i]), want, atol=2e-5)
+        want = _dense_attention(q[s], k[s], v[s], q_pos[s], lengths[s], window)
+        np.testing.assert_allclose(np.asarray(got[s]), want, atol=2e-5)
+
+
+# lengths in slot order (arbitrary, not by length), the group size the
+# walk is held to, idle slots (length 1, every row the trash page)
+GROUPED = {
+    "ragged-8-by-2": dict(lengths=[9, 37, 1, 20, 48, 5, 33, 12], group=2),
+    "ragged-8-by-4": dict(lengths=[9, 37, 1, 20, 48, 5, 33, 12], group=4),
+    "ragged-32-by-8": dict(
+        lengths=[int(x) for x in np.random.default_rng(3).integers(1, 49, 32)],
+        group=8,
+    ),
+    "ragged-32-by-16": dict(
+        lengths=[int(x) for x in np.random.default_rng(4).integers(1, 49, 32)],
+        group=16,
+    ),
+    "idle-slots": dict(
+        lengths=[1, 30, 1, 1, 44, 1, 17, 1], group=2, idle=(0, 2, 3, 5, 7),
+    ),
+    "all-idle": dict(lengths=[1] * 8, group=4, idle=tuple(range(8))),
+    "one-at-the-full-table": dict(lengths=[3, 6, 48, 2, 7, 4, 5, 1], group=4),
+    "all-equal": dict(lengths=[29] * 8, group=2),
+}
+
+
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_grouped_walk_gives_each_slot_what_it_had(case, window, monkeypatch):
+    """One query a slot over a table of several blocks: every slot's
+    result is dense attention over its own keys, and bit for bit what
+    the walk with one trip count for the batch gives — whoever shares
+    its group."""
+    import jax.numpy as jnp
+
+    spec = GROUPED[case]
+    lengths = np.asarray(spec["lengths"], np.int32)
+    slots, n_kv, group, hd, ps, pages = len(lengths), 2, 2, 8, 4, 12
+    rng = np.random.default_rng(11)
+    k, v, table, pool_k, pool_v = _scattered_pool(
+        rng, lengths, pages, spec.get("idle", ())
+    )
+    q = rng.normal(size=(slots, 1, n_kv, group, hd)).astype(np.float32)
+    q_pos = (lengths - 1)[:, None].astype(np.int32)
+
+    def walk(together):
+        monkeypatch.setattr(attention, "LIVE_GROUP_SLOTS", together)
+        assert attention.live_read_group(slots) == min(together, slots)
+        return np.asarray(paged_attention_live(
+            jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
+            jnp.asarray(table), jnp.zeros(slots, jnp.int32),
+            jnp.asarray(q_pos), jnp.asarray(lengths), 1, window=window,
+            block_pages=2,
+        ))
+
+    got = walk(spec["group"])
+    np.testing.assert_array_equal(got, walk(slots))
+    for s in range(slots):
+        want = _dense_attention(q[s], k[s], v[s], q_pos[s], lengths[s], window)
+        np.testing.assert_allclose(got[s], want, atol=2e-5)
+
+
+# live keys per slot, span, blocks of the table, group -> trips per group
+TRIPS = {
+    "descending": ([40, 30, 20, 10, 9, 8, 2, 1], 8, 6, 2, [5, 3, 2, 1]),
+    "any-order": ([9, 1, 40, 8, 20, 2, 30, 10], 8, 6, 2, [5, 3, 2, 1]),
+    "ties": ([16, 16, 17, 16], 8, 6, 2, [3, 2]),
+    "all-idle": ([1] * 8, 8, 6, 4, [1, 1]),
+    "longer-than-the-table": ([3, 100, 3, 3], 8, 6, 2, [6, 1]),
+    "one-group": ([5, 40, 7], 8, 6, 3, [5]),
+    "a-block-exactly": ([8, 16, 9, 24], 8, 6, 1, [3, 2, 2, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRIPS))
+def test_live_read_trips_counts_each_groups_blocks(case):
+    import jax.numpy as jnp
+
+    live, span, n_blocks, group, want = TRIPS[case]
+    order, trips = live_read_trips(np.asarray(live), span, n_blocks, group)
+    assert trips.tolist() == want
+    assert sorted(order.tolist()) == list(range(len(live)))
+    assert [live[i] for i in order] == sorted(live, reverse=True)
+    # the program's own trip counts: the same function, traced
+    _, traced = live_read_trips(
+        jnp.asarray(live, jnp.int32), span, n_blocks, group
+    )
+    assert np.asarray(traced).tolist() == want
+
+
+@pytest.mark.parametrize(
+    "together, want",
+    [
+        (2, (2 + 1) * 2 * 128),  # {150, 140} both blocks, {20, idle} one
+        (4, 2 * 4 * 128),  # one group: everyone walks the longest's two
+        (1, (2 + 2 + 1 + 1) * 128),  # each slot its own blocks
+    ],
+)
+def test_live_read_positions_counts_idle_slots_as_one_key(
+    together, want, monkeypatch
+):
+    """Four slots, three live, a 128-row table of two-position pages (two
+    blocks of 64 rows)."""
+    monkeypatch.setattr(attention, "LIVE_GROUP_SLOTS", together)
+    assert live_read_positions([150, 20, 140], 4, 128, 2) == want
+
+
+def test_decode_span_counts_what_the_grouped_walk_reads(params, monkeypatch):
+    """A small live engine whose full layers' table is two blocks wide,
+    four slots in groups of two: the span's ``kv_tokens_read_full`` is
+    each group's trips times its slots times a block's positions, the
+    window kind's count is what it was, and the tokens are those of the
+    walk with one trip count for the batch."""
+    import io
+    import json
+
+    from tensorframes_tpu import obs
+
+    def traced_steps():
+        sink = io.StringIO()
+        obs.set_trace_sink(sink)
+        try:
+            _, _, outs = serve(
+                params, prompts_of((150, 20, 140, 10)), (12, 12, 12, 12),
+                page_size=2, num_pages=1024,  # 128 rows: two blocks
+            )
+        finally:
+            obs.set_trace_sink(None)
+        events = [json.loads(l) for l in sink.getvalue().splitlines() if l.strip()]
+        return outs, [
+            e["attrs"] for e in events if e["name"] == "serve.decode_step"
+        ]
+
+    whole, before = traced_steps()
+    monkeypatch.setattr(attention, "LIVE_GROUP_SLOTS", 2)
+    grouped, steps = traced_steps()
+    assert grouped == whole
+    span = 64 * 2  # a block: 64 rows of two positions
+    assert [a["kv_tokens_read_window"] for a in steps] == [
+        a["kv_tokens_read_window"] for a in before
+    ]
+    assert [a["kv_tokens_live_full"] for a in steps] == [
+        a["kv_tokens_live_full"] for a in before
+    ]
+    assert any(a["occupancy"] == 4 for a in steps)
+    full = [(a, b) for a, b in zip(steps, before) if a["occupancy"] == 4]
+    assert full
+    for a, b in full:
+        # one trip count for the batch: the longest walks both blocks,
+        # and so does everyone
+        assert b["kv_tokens_read_full"] == 2 * 4 * span
+        # {150.., 140..} walk both blocks, {20.., 10..} the first
+        assert a["kv_tokens_read_full"] == (2 + 1) * 2 * span
+    for a, b in zip(steps, before):
+        assert a["kv_tokens_live_full"] <= a["kv_tokens_read_full"]
+        assert a["kv_tokens_read_full"] <= b["kv_tokens_read_full"]
+    # the two long requests alone: their pair walks both blocks, the idle
+    # pair (one key a slot) the first
+    assert steps[-1]["occupancy"] == 2
+    assert steps[-1]["kv_tokens_read_full"] == (2 + 1) * 2 * span
