@@ -339,24 +339,60 @@ def _hlo_computations(text):
     return comps
 
 
-def pool_relayouts(hlo_text, counts):
-    """Instructions of an optimised HLO module's entry computation that
-    only MOVE an array whose element count is one of ``counts`` (the
-    whole pool, one layer's slice of it, the gathered block): ``copy``,
-    ``transpose``, their asynchronous forms, and fusions made of nothing
-    but moves. Returns ``[(opcode, result shape), ...]`` — empty when the
-    program reads and writes the pool in the layout it was given."""
+def _elements(shape):
+    """Element counts of the arrays an HLO result shape names."""
     import re
 
-    def elements(shape):
-        return [
-            math.prod(int(d) for d in dims.split(",") if d)
-            for dims in re.findall(r"\w+\[([\d,]*)\]", shape)
-        ]
+    return [
+        math.prod(int(d) for d in dims.split(",") if d)
+        for dims in re.findall(r"\w+\[([\d,]*)\]", shape)
+    ]
+
+
+def _in_memory(comps):
+    """The computations of a parsed module whose instructions leave
+    arrays in memory: the entry, every loop body, branch and callee —
+    all but the bodies of fusions, whose values never leave the core."""
+    import re
+
+    fused = {
+        re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+        for body in comps.values()
+        for opcode, _, rest in body if opcode == "fusion"
+    }
+    return [
+        body for name, body in comps.items()
+        if name not in fused and name != "ENTRY"  # the entry's alias
+    ]
+
+
+def hlo_arrays(hlo_text):
+    """``(opcode, result shape)`` of every instruction of an optimised
+    HLO module that leaves an array in memory (:func:`_in_memory`)."""
+    import re
+
+    return [
+        (opcode, re.sub(r"\{[^}]*\}", "", shape))
+        for body in _in_memory(_hlo_computations(hlo_text))
+        for opcode, shape, _ in body
+    ]
+
+
+def pool_relayouts(hlo_text, counts, loops=False):
+    """Instructions of an optimised HLO module's entry computation (with
+    ``loops``, of every computation but a fusion's body: a walk gathers
+    its blocks inside a loop) that only MOVE an array whose element
+    count is one of ``counts`` (the whole pool, one layer's slice of it,
+    the gathered block): ``copy``, ``transpose``, their asynchronous
+    forms, and fusions made of nothing but moves. Returns ``[(opcode,
+    result shape), ...]`` — empty when the program reads and writes the
+    pool in the layout it was given."""
+    import re
 
     comps = _hlo_computations(hlo_text)
+    scope = _in_memory(comps) if loops else [comps.get("ENTRY", ())]
     found = []
-    for opcode, shape, rest in comps.get("ENTRY", ()):
+    for opcode, shape, rest in (i for body in scope for i in body):
         if opcode == "fusion":
             body = comps.get(
                 re.search(r"calls=%?([\w.\-]+)", rest).group(1), ()
@@ -365,9 +401,38 @@ def pool_relayouts(hlo_text, counts):
                 continue
         elif opcode not in ("copy", "transpose", "copy-start"):
             continue
-        if any(n in counts for n in elements(shape)):
+        if any(n in counts for n in _elements(shape)):
             found.append((opcode, re.sub(r"\{[^}]*\}", "", shape)))
     return found
+
+
+def span_attention_leaks(arrays, c, n_kv, group, spans):
+    """Of ``arrays`` (:func:`hlo_arrays` of a chunk program over ``c``
+    queries of ``n_kv`` K/V heads of ``group`` query heads each, its
+    tables walked in blocks of ``spans`` positions), those that show the
+    chunk's attention outside the fused fold: a float32 array of ``c x
+    heads x span`` elements (a block of scores or probabilities written
+    to memory) and online-softmax state with ``[.., c, n_kv, group]``
+    dimensions (whose two minor ones the chip pads to a whole (8, 128)
+    tile: 32 times the bytes at 4 x 8)."""
+    import re
+
+    scores = {c * n_kv * group * span for span in spans}
+    state = re.compile(rf"f32\[(?:\d+,)*{c},{n_kv},{group}\]")
+    return [
+        (opcode, shape) for opcode, shape in arrays
+        if opcode not in ("parameter", "get-tuple-element", "tuple")
+        and (
+            any(
+                n in scores
+                for n, dt in zip(
+                    _elements(shape), re.findall(r"(\w+)\[", shape)
+                )
+                if dt == "f32"
+            )
+            or state.search(shape)
+        )
+    ]
 
 
 def phase_pool_layout(run, S):
@@ -469,6 +534,7 @@ def phase_pool_layout_long(run, S):
     period of its layers, must compile for the chip and hold no copy or
     transpose of the pool or of a row of it (a pool page is as deep as
     the cache kinds' common divisor, ``serve/kv_pages.py``)."""
+    from tensorframes_tpu.ops.attention import live_read_blocks
     from tensorframes_tpu.serve import GenerationEngine
     from tensorframes_tpu.serve.kv_pages import SequencePages
 
@@ -501,25 +567,46 @@ def phase_pool_layout_long(run, S):
             ),
         ),
     }
+    # a chunk's walks: the blocks each cache kind's table is gathered in
+    c, m = eng._chunk_c, S.long_lm
+    spans = sorted({
+        live_read_blocks(width)[1] * S.page_size
+        for width in eng._kind_widths(c).values()
+    })
     for name, (fn, args) in programs.items():
         args = jax.tree.map(lambda a: spec(np.asarray(a)), args)
         compiled = fn.lower(eng._params_dev, pool, pool, *args).compile()
         mem = compiled.memory_analysis()
-        moved = pool_relayouts(
-            compiled.as_text(), (whole, whole // pool.shape[0])
-        )
+        text = compiled.as_text()
+        moved = pool_relayouts(text, (whole, whole // pool.shape[0]))
+        leaks = []
+        if name == "jit_chunk_step":
+            # the walks gather inside loops: a gathered block moved there
+            moved += pool_relayouts(
+                text, [span * pool.shape[-1] for span in spans], loops=True
+            )
+            leaks = span_attention_leaks(
+                hlo_arrays(text), c, m["kv_heads"],
+                m["heads"] // m["kv_heads"], spans,
+            )
         run.emit(
             phase=run.phase, program=name, pool=list(pool.shape),
             temp_bytes=getattr(mem, "temp_size_in_bytes", None),
             alias_bytes=getattr(mem, "alias_size_in_bytes", None),
-            relayouts=moved[:8],
+            relayouts=moved[:8], attention_leaks=leaks[:8],
         )
         if not run.rehearsal:
             run.check(
                 not moved,
-                f"{name} (long) neither copies nor transposes the pool or "
-                f"a row of it",
+                f"{name} (long) neither copies nor transposes the pool, "
+                f"a row of it or a gathered block",
                 found=len(moved),
+            )
+            run.check(
+                not leaks,
+                f"{name} (long) keeps a chunk's score blocks and softmax "
+                f"state on the chip",
+                found=len(leaks),
             )
             run.check(
                 mem.alias_size_in_bytes >= 2 * whole * pool.dtype.itemsize,

@@ -573,39 +573,41 @@ def paged_attention_live(
     result goes back through the inverse of the order. A slot's own
     blocks are visited in the same order with the same operands
     whoever shares its group, and a block past its end adds exact
-    zeros, so its result does not depend on its neighbours. Any other
-    shape (a span of queries, fewer slots than two groups, a table of
-    one block) takes one trip count for the batch, the longest slot's.
-    Either way a step moves the K and V of about the pages that are
-    live (rows past a slot's own end name the trash page: one page,
+    zeros, so its result does not depend on its neighbours. One query a
+    slot in fewer slots than two groups, or over a table of one block,
+    takes one trip count for the batch, the longest slot's. A span of
+    queries (``C > 1``: a prefill chunk) takes one trip count too
+    (:func:`live_span_trips`) and folds each gathered block into its
+    carry with ONE fused kernel (:func:`live_span_fold`): a score tile,
+    its mask, max, exponentials, sum and the product with V live in
+    fast memory and only the carry crosses HBM between blocks; its walk
+    is a jitted callee of its own, which takes the pool and ``layer``
+    as arguments, so a program of many layers traces and lowers it once
+    per cache kind (``window`` and the table's width), not once per
+    layer. Either way a step moves the K and V of about the pages that
+    are live (rows past a slot's own end name the trash page: one page,
     read again and again, and masked) instead of ``max_seq_len``
     positions for every slot. No ``[S, T * page_size]`` copy of a
     slot's whole table is ever made, and the scores of a span exist one
-    block at a time. Scores, softmax and the accumulator are float32
+    tile at a time. Scores, softmax and the accumulator are float32
     whatever the pool holds. Returns ``[S, C, n_kv * group * hd]``
     float32."""
     slots, c, n_kv, group, hd = q.shape
-    ps = k_pages.shape[-2]
-    t = table.shape[1]
-    n_blocks, bp = live_read_blocks(t, block_pages)
-    if n_blocks * bp != t:  # whole blocks: the tail rows name nothing
-        table = jnp.pad(
-            table, ((0, 0), (0, n_blocks * bp - t)), mode="edge"
+    if c > 1:
+        return _live_span_walk(
+            q, k_pages, v_pages, table, first, q_pos, lengths, layer,
+            window=window, block_pages=block_pages,
         )
+    ps = k_pages.shape[-2]
+    table, n_blocks, bp = _whole_blocks(table, block_pages)
     scale = 1.0 / float(np.sqrt(hd))
     span = bp * ps
     in_block = jnp.arange(span, dtype=jnp.int32)
-    # one query a slot: the products run ON the rows' merged lanes, the
-    # query laid over its head's lanes (``paged_attention``'s form: all
-    # heads against a slot's block in one MXU product, and the gathered
-    # block is never re-tiled from 512 lanes into 4 x 128). A span of
-    # queries splits the lanes into heads instead: four times fewer
-    # FLOPs, and the re-tiling is shared by the span's rows.
-    merged = c == 1
-    if merged:
-        qc = _heads_on_lanes(q[:, 0]).astype(k_pages.dtype)  # [S, H, lanes]
-    else:
-        qc = q.astype(k_pages.dtype)
+    # the products run ON the rows' merged lanes, the query laid over
+    # its head's lanes (``paged_attention``'s form: all heads against a
+    # slot's block in one MXU product, and the gathered block is never
+    # re-tiled from 512 lanes into 4 x 128)
+    qc = _heads_on_lanes(q[:, 0]).astype(k_pages.dtype)  # [S, H, lanes]
 
     def block(j, carry, qc, table, first, q_pos, lengths):
         """Fold block ``j`` of these slots' tables into their carry."""
@@ -615,17 +617,9 @@ def paged_attention_live(
         kb = k_pages[layer, rows].reshape(slots, span, n_kv * hd)
         vb = v_pages[layer, rows].reshape(slots, span, n_kv * hd)
         k_pos = (first[:, None] + j * bp) * ps + in_block[None, :]  # [S, T]
-        if merged:
-            s = jnp.einsum(
-                "shc,stc->sht", qc, kb, preferred_element_type=jnp.float32
-            ).reshape(slots, 1, n_kv, group, span) * scale
-        else:
-            kb = kb.reshape(slots, span, n_kv, hd)
-            vb = vb.reshape(slots, span, n_kv, hd)
-            s = jnp.einsum(
-                "scngd,stnd->scngt", qc, kb,
-                preferred_element_type=jnp.float32,
-            ) * scale
+        s = jnp.einsum(
+            "shc,stc->sht", qc, kb, preferred_element_type=jnp.float32
+        ).reshape(slots, 1, n_kv, group, span) * scale
         seen = (k_pos[:, None, :] <= q_pos[:, :, None]) & (
             k_pos < lengths[:, None]
         )[:, None, :]
@@ -638,20 +632,15 @@ def paged_attention_live(
         p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         l = alpha * l + p.sum(axis=-1)
-        if merged:  # every lane for every head; each keeps its own
-            ctx = _heads_off_lanes(
-                jnp.einsum(
-                    "sht,stc->shc",
-                    p.reshape(slots, n_kv * group, span).astype(vb.dtype),
-                    vb, preferred_element_type=jnp.float32,
-                ),
-                n_kv, hd,
-            )[:, None]
-        else:
-            ctx = jnp.einsum(
-                "scngt,stnd->scngd", p.astype(vb.dtype), vb,
-                preferred_element_type=jnp.float32,
-            )
+        # every lane for every head; each keeps its own
+        ctx = _heads_off_lanes(
+            jnp.einsum(
+                "sht,stc->shc",
+                p.reshape(slots, n_kv * group, span).astype(vb.dtype),
+                vb, preferred_element_type=jnp.float32,
+            ),
+            n_kv, hd,
+        )[:, None]
         return m_new, l, alpha[..., None] * acc + ctx
 
     stat = (slots, c, n_kv, group)
@@ -661,13 +650,12 @@ def paged_attention_live(
         jnp.zeros(stat + (hd,), jnp.float32),
     )
     per_slot = (qc, table, first, q_pos, lengths)
-    together = live_read_group(slots) if merged and n_blocks > 1 else slots
+    together = live_read_group(slots) if n_blocks > 1 else slots
     order = None  # the caller's slot numbering
     if n_blocks == 1:
         carry = block(0, carry, *per_slot)
     elif together == slots:
-        live = jnp.max(lengths - first * ps)  # keys past the first row
-        trips = jnp.clip(-(-live // span), 1, n_blocks)
+        trips = live_span_trips(lengths - first * ps, span, n_blocks)
         carry = jax.lax.fori_loop(
             0, trips, lambda j, carry: block(j, carry, *per_slot), carry
         )
@@ -703,6 +691,275 @@ def paged_attention_live(
     if order is not None:  # back to the caller's slot numbering
         out = out[jnp.argsort(order)]
     return out.reshape(slots, c, n_kv * group * hd)
+
+
+def _whole_blocks(table, block_pages: int):
+    """``(table, blocks, rows per block)``: the table padded to whole
+    blocks of :func:`live_read_blocks` (the tail rows name nothing)."""
+    t = table.shape[1]
+    n_blocks, bp = live_read_blocks(t, block_pages)
+    if n_blocks * bp != t:
+        table = jnp.pad(
+            table, ((0, 0), (0, n_blocks * bp - t)), mode="edge"
+        )
+    return table, n_blocks, bp
+
+
+#: the span fold's name in a device trace
+LIVE_SPAN_KERNEL = "live_span_fold"
+
+#: bytes of one head group's accumulator tile in :func:`live_span_fold`
+#: (256 queries at 8 heads of 128 lanes): with a score tile of as many
+#: rows the kernel's buffers stay well inside 16 MB of fast memory
+_SPAN_TILE_BYTES = 1 << 20
+
+#: most keys a grid step of :func:`live_span_fold` takes
+_SPAN_KEY_TILE = 2048
+
+
+def live_span_trips(live, span: int, n_blocks: int):
+    """Blocks of ``span`` positions that a walk with one trip count for
+    its slots (a span of queries; one query a slot in a single group)
+    visits of a table of ``n_blocks`` blocks whose slots hold ``live``
+    keys past their first row (``[S]`` integers; a numpy array on the
+    host, a traced one inside the program — the same arithmetic counts
+    what the program visits): up to the block that holds the longest
+    slot's last key, at least one."""
+    xp = np if isinstance(live, np.ndarray) else jnp
+    return xp.clip(-(-xp.max(live) // span), 1, n_blocks)
+
+
+def _span_tile(length: int, cap: int) -> int:
+    """Rows a grid step of :func:`live_span_fold` takes of ``length``:
+    the largest divisor of it within ``cap`` that is whole sublane tiles
+    (16 rows: a bfloat16 tile); all of it when it has none."""
+    fits = [t for t in range(16, min(length, cap) + 1, 16) if length % t == 0]
+    return max(fits) if fits else length
+
+
+def _live_span_kernel(
+    k0_ref, len_ref, qlo_ref, qhi_ref,
+    q_ref, qpos_ref, k_ref, v_ref, m_in, l_in, acc_in,
+    m_out, l_out, acc_out,
+    *, group, hd, window, scale,
+):
+    """Grid = (slots, query tiles, K/V heads, key tiles), the keys
+    innermost: one step folds ``[tk, hd]`` keys and values of K/V head
+    ``n`` into the carry of that head's ``group`` query heads for one
+    tile of queries, one head a turn of a loop (rolled: the body is
+    traced and lowered once, which is most of what a kernel costs a
+    program's set-up), through :func:`online_block_update` (the flash
+    kernels' recurrence). The query tile ``[tq, group * hd]`` and its
+    accumulator are the ``n``-th lane block of their rows, the K and V
+    tile the ``n``-th ``hd`` lanes of the gathered rows — picked by the
+    block index, nothing is re-tiled. The accumulator tile stays
+    resident across a head's key tiles and ``m`` and ``l`` ``[tq,
+    heads]`` across a query tile's heads; a head's column goes in and
+    out by a lane mask.
+
+    The mask comes from positions: key ``t`` of the block sits at
+    ``k0 + t``, visible to the query at ``q_pos`` iff it is at or
+    before it, exists (``< length``) and, with a window, lies inside
+    it. Three regimes a step, decided from scalars (the key tile's first
+    position, the slot's length, the query tile's lowest and highest
+    position): no query of the tile sees any key (nothing to do: the
+    masked fold would add exact zeros), every query sees every key (no
+    mask work), else the masked fold."""
+    from jax.experimental import pallas as pl
+
+    si, qi, n, ki = (pl.program_id(a) for a in range(4))
+    tq, tk = q_ref.shape[1], k_ref.shape[1]
+    heads = m_in.shape[2]
+
+    @pl.when(jnp.logical_and(n == 0, ki == 0))
+    def _():
+        m_out[...] = m_in[...]
+        l_out[...] = l_in[...]
+
+    @pl.when(ki == 0)
+    def _():
+        acc_out[...] = acc_in[...]
+
+    k_lo = k0_ref[si] + ki * tk
+    k_hi = k_lo + (tk - 1)
+    length = len_ref[si]
+    q_lo, q_hi = qlo_ref[si, qi], qhi_ref[si, qi]
+    visible = jnp.logical_and(k_lo <= q_hi, k_lo < length)
+    interior = jnp.logical_and(k_hi <= q_lo, k_hi < length)
+    if window:
+        visible = jnp.logical_and(visible, k_hi > q_lo - window)
+        interior = jnp.logical_and(interior, k_lo > q_hi - window)
+
+    def fold(with_mask):
+        mask = None
+        if with_mask:
+            k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+            q_pos = qpos_ref[0]  # [tq, 1]
+            mask = jnp.logical_and(k_pos <= q_pos, k_pos < length)
+            if window:
+                mask = jnp.logical_and(mask, k_pos > q_pos - window)
+        head = jax.lax.broadcasted_iota(jnp.int32, (tq, heads), 1)
+        kj, vj = k_ref[0], v_ref[0]
+
+        def member(g, stats):
+            m_all, l_all = stats
+            mine = head == n * group + g
+            lanes = pl.ds(pl.multiple_of(g * hd, 128), hd)
+            m, l, acc = online_block_update(
+                q_ref[0, :, lanes], kj, vj,
+                jnp.sum(jnp.where(mine, m_all, 0.0), axis=1, keepdims=True),
+                jnp.sum(jnp.where(mine, l_all, 0.0), axis=1, keepdims=True),
+                acc_out[0, :, lanes], scale, mask,
+            )
+            acc_out[0, :, lanes] = acc
+            return jnp.where(mine, m, m_all), jnp.where(mine, l, l_all)
+
+        m_out[0], l_out[0] = jax.lax.fori_loop(
+            0, group, member, (m_out[0], l_out[0])
+        )
+
+    @pl.when(interior)
+    def _():
+        fold(with_mask=False)
+
+    @pl.when(jnp.logical_and(visible, jnp.logical_not(interior)))
+    def _():
+        fold(with_mask=True)
+
+
+def live_span_fold(
+    q, q_pos, kb, vb, k0, lengths, m, l, acc, *, n_kv: int, window: int = 0,
+    scale: Optional[float] = None, interpret: Optional[bool] = None,
+):
+    """Fold one gathered block of keys and values into the
+    online-softmax carry of a span of queries, in one fused kernel.
+
+    ``q`` ``[S, C, heads * hd]`` (the pool's dtype; head ``h`` = K/V
+    head ``h // group`` x group member ``h % group`` on lanes ``h * hd
+    ..``) at positions ``q_pos`` ``[S, C]`` int32; ``kb`` / ``vb`` ``[S,
+    span, n_kv * hd]`` as gathered, key ``t`` of slot ``s`` at position
+    ``k0[s] + t`` and real iff below ``lengths[s]`` (``k0``, ``lengths``
+    ``[S]`` int32). The carry is float32 and dense on the lanes: ``m``
+    (running max, base-2 domain like every carry of
+    :func:`online_block_update`) and ``l`` ``[S, C, heads]``, ``acc``
+    ``[S, C, heads * hd]``; start from ``m = _NEG_BIG``, ``l = acc =
+    0`` and finish as ``acc / l``. Returns the updated ``(m, l, acc)``
+    in the carry's own buffers. ``hd`` must be whole 128-lane tiles
+    (the caller pads narrower heads); ``scale`` defaults to ``1 /
+    sqrt(hd)``. ``interpret`` defaults to True off the TPU, so the CPU
+    tests run the kernel itself."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, c, width = q.shape
+    span = kb.shape[1]
+    hd = kb.shape[2] // n_kv
+    heads = width // hd
+    group = heads // n_kv
+    if interpret is None:
+        interpret = jax.devices()[0].platform != "tpu"
+    tq = _span_tile(c, max(16, _SPAN_TILE_BYTES // (4 * group * hd)))
+    tk = _span_tile(span, _SPAN_KEY_TILE)
+    tiles = q_pos.reshape(slots, c // tq, tq)
+    kernel = functools.partial(
+        _live_span_kernel, group=group, hd=hd, window=window,
+        scale=1.0 / float(np.sqrt(hd)) if scale is None else scale,
+    )
+    q_spec = pl.BlockSpec(
+        (1, tq, group * hd), lambda s, i, n, j, *_: (s, i, n)
+    )
+    kv_spec = pl.BlockSpec((1, tk, hd), lambda s, i, n, j, *_: (s, j, n))
+    stat_spec = pl.BlockSpec(
+        (1, tq, heads), lambda s, i, n, j, *_: (s, i, 0)
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(slots, c // tq, n_kv, span // tk),
+            in_specs=[
+                q_spec,
+                pl.BlockSpec((1, tq, 1), lambda s, i, n, j, *_: (s, i, 0)),
+                kv_spec, kv_spec, stat_spec, stat_spec, q_spec,
+            ],
+            out_specs=[stat_spec, stat_spec, q_spec],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(m.shape, jnp.float32),
+            jax.ShapeDtypeStruct(l.shape, jnp.float32),
+            jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        ],
+        # the carry is updated in place (operands count the scalars)
+        input_output_aliases={8: 0, 9: 1, 10: 2},
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "arbitrary", "arbitrary",
+            )
+        ),
+        interpret=interpret,
+        name=LIVE_SPAN_KERNEL,
+    )(
+        k0, lengths, tiles.min(axis=-1), tiles.max(axis=-1),
+        q, q_pos[..., None], kb, vb, m, l, acc,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("window", "block_pages"))
+def _live_span_walk(
+    q, k_pages, v_pages, table, first, q_pos, lengths, layer, *,
+    window, block_pages,
+):
+    """:func:`paged_attention_live` for a span of queries: the walk of
+    the table's blocks with one trip count (:func:`live_span_trips`),
+    each block gathered by XLA as the rows lie and folded into the carry
+    by :func:`live_span_fold`. Jitted on its own with the pool and
+    ``layer`` as operands: every layer of one cache kind in a step
+    program is the same callee, traced and lowered once."""
+    slots, c, n_kv, group, hd = q.shape
+    ps = k_pages.shape[-2]
+    table, n_blocks, bp = _whole_blocks(table, block_pages)
+    span = bp * ps
+    heads = n_kv * group
+    # heads narrower than a lane tile are padded to one (zeros add
+    # nothing to a product), so that every shape tiles; at whole tiles
+    # the gathered rows go to the kernel as they lie
+    pad = -hd % 128
+    wide = hd + pad
+
+    def widen(x, n):  # [.., n * hd] -> [.., n * wide]
+        if not pad:
+            return x
+        x = x.reshape(x.shape[:-1] + (n, hd))
+        x = jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+        return x.reshape(x.shape[:-2] + (n * wide,))
+
+    qc = widen(q.astype(k_pages.dtype).reshape(slots, c, heads * hd), heads)
+
+    def block(j, carry):
+        rows = jax.lax.dynamic_slice_in_dim(table, j * bp, bp, axis=1)
+        kb, vb = (
+            widen(pages[layer, rows].reshape(slots, span, n_kv * hd), n_kv)
+            for pages in (k_pages, v_pages)
+        )
+        return tuple(live_span_fold(
+            qc, q_pos, kb, vb, (first + j * bp) * ps, lengths, *carry,
+            n_kv=n_kv, window=window, scale=1.0 / float(np.sqrt(hd)),
+        ))
+
+    carry = (
+        jnp.full((slots, c, heads), _NEG_BIG, jnp.float32),
+        jnp.zeros((slots, c, heads), jnp.float32),
+        jnp.zeros((slots, c, heads * wide), jnp.float32),
+    )
+    if n_blocks == 1:
+        carry = block(0, carry)
+    else:
+        trips = live_span_trips(lengths - first * ps, span, n_blocks)
+        carry = jax.lax.fori_loop(0, trips, block, carry)
+    _, l, acc = carry
+    out = acc.reshape(slots, c, heads, wide)[..., :hd]
+    out = out / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.reshape(slots, c, heads * hd)
 
 
 def paged_page_size_hint(dtype, head_dim: int) -> int:

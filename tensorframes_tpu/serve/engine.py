@@ -2145,6 +2145,7 @@ class GenerationEngine:
         recompute = max(0, min(start + valid, req.computed) - start)
         t0 = time.perf_counter()
         routed = valid * self._pairs_per_token
+        walked = self._chunk_walk_attrs(act.seq, start + valid)
         with _use_trace(req.trace), _span(
             "serve.prefill_chunk",
             chain=self._phases,
@@ -2154,6 +2155,7 @@ class GenerationEngine:
             recompute=recompute,
             pad_share=1.0 - valid / c,
             tokens_routed=routed,
+            **walked,
         ) as sp:
             pool.k, pool.v, tok = run_with_retries(
                 dispatch,
@@ -2170,6 +2172,34 @@ class GenerationEngine:
         if act.prefill_pos >= plen:
             self._register_prefix(act)
             self._emit(idx, act, int(tok))
+
+    def _chunk_walk_attrs(self, seq, end: int) -> dict:
+        """What a live ``serve.prefill_chunk`` span says of its
+        attention walks: ``attn_blocks``, the blocks of keys the chunk
+        program visits for a chunk whose last real token sits before
+        ``end``, all layers — per cache kind the trip count of
+        ``ops.paged_attention_live``'s span walk
+        (``live_span_trips``, the arithmetic the program's own count
+        comes from) times the kind's layers — and ``attn_blocks_fused``,
+        those of them the fused kernel folds: every one, the span walk
+        has no other fold. Both feed their counters. Nothing for an
+        engine whose chunk program reads the gathered table."""
+        if not self._long:
+            return {}
+        from ..ops.attention import live_read_blocks, live_span_trips
+
+        ps = self.page_size
+        widths = self._kind_widths(self._chunk_c)
+        blocks = 0
+        for ki, kind in enumerate(self._pool_layout.kinds):
+            n_blocks, bp = live_read_blocks(widths[kind.name])
+            live = np.asarray([end - seq.first[ki] * ps])
+            blocks += len(kind.layers) * int(
+                live_span_trips(live, bp * ps, n_blocks)
+            )
+        _m_chunk_blocks.inc(blocks)
+        _m_chunk_blocks_fused.inc(blocks)
+        return {"attn_blocks": blocks, "attn_blocks_fused": blocks}
 
     def _chunk_args(
         self, tokens, start, valid, plen, seq, temperature, seed, top_p
@@ -3325,3 +3355,19 @@ class GenerationEngine:
             self.run_until_idle()
         timeout = get_config().serve_result_timeout_s
         return [h.result(timeout=timeout) for h in handles]
+
+
+# registered down here, below every step program's code, so that no
+# source position of a compiled program moves (a program's cache key
+# holds them)
+_m_chunk_blocks = _counter(
+    "serve.chunk_attention_blocks_total",
+    "Blocks of keys the live prefill-chunk programs' attention walks "
+    "visited, all layers (the serve.prefill_chunk span's attn_blocks, "
+    "summed)",
+)
+_m_chunk_blocks_fused = _counter(
+    "serve.chunk_attention_blocks_fused_total",
+    "Of serve.chunk_attention_blocks_total, the blocks folded into the "
+    "online-softmax carry by the fused kernel (ops.live_span_fold)",
+)

@@ -445,3 +445,69 @@ def test_pool_relayouts_reads_optimised_hlo():
         if not any(k in l for k in ("%copy.1", "%fusion.1", "%transpose.1"))
     )
     assert _chip_smoke().pool_relayouts(clean, (8 * 1024 * 1600,)) == []
+
+
+_CHUNK_HLO = """HloModule jit_chunk_step
+
+%fused_scores (p: bf16[1,1024,4,8,128]) -> f32[1,1024,4,8,1056] {
+  %p = bf16[1,1024,4,8,128]{4,3,2,1,0} parameter(0)
+  ROOT %s = f32[1,1024,4,8,1056]{4,3,2,1,0} convolution(%p, %p)
+}
+
+%fused_block_copy (p: bf16[1,1056,512]) -> bf16[1,1056,4,128] {
+  %p = bf16[1,1056,512]{2,1,0} parameter(0)
+  ROOT %r = bf16[1,1056,4,128]{3,2,1,0} reshape(%p)
+}
+
+%walk_body (w: (s32[], f32[1,1024,32], f32[1,1024,4096])) -> (s32[], f32[1,1024,32], f32[1,1024,4096]) {
+  %w = (s32[], f32[1,1024,32]{2,1,0}, f32[1,1024,4096]{2,1,0}) parameter(0)
+  %g = bf16[1,1056,512]{2,1,0} gather(%pool, %rows)
+  %fold = (f32[1,1024,32]{2,1,0}, f32[1,1024,32]{2,1,0}, f32[1,1024,4096]{2,1,0}) custom-call(%g), custom_call_target="tpu_custom_call"
+  ROOT %t = (s32[], f32[1,1024,32]{2,1,0}, f32[1,1024,4096]{2,1,0}) tuple(%i, %m, %acc)
+}
+
+%old_body (w: (s32[], f32[1,1024,4,8])) -> (s32[], f32[1,1024,4,8]) {
+  %w = (s32[], f32[1,1024,4,8]{3,2,1,0}) parameter(0)
+  %g = bf16[1,1056,512]{2,1,0} gather(%pool, %rows)
+  %retiled = bf16[1,1056,4,128]{3,2,1,0} fusion(%g), kind=kLoop, calls=%fused_block_copy
+  %scores = f32[1,1024,4,8,1056]{4,3,2,1,0} fusion(%q), kind=kOutput, calls=%fused_scores
+  %m = f32[1,1024,4,8]{3,2,1,0} reduce(%scores, %neg), dimensions={4}, to_apply=%max
+  ROOT %t = (s32[], f32[1,1024,4,8]{3,2,1,0}) tuple(%i, %m)
+}
+
+ENTRY %main (pool: bf16[3,16385,16,512]) -> f32[1,1024,4096] {
+  %pool = bf16[3,16385,16,512]{3,2,1,0} parameter(0)
+  %q = f32[1,1024,4,8,128]{4,3,2,1,0} fusion(%x), kind=kLoop, calls=%rotary
+  %while.1 = (s32[], f32[1,1024,32]{2,1,0}, f32[1,1024,4096]{2,1,0}) while(%init), condition=%cond, body=%walk_body
+  ROOT %out = f32[1,1024,4096]{2,1,0} get-tuple-element(%while.1), index=2
+}
+"""
+
+
+def test_the_chunk_guard_reads_loop_bodies_and_not_fusion_insides():
+    smoke = _chip_smoke()
+    arrays = smoke.hlo_arrays(_CHUNK_HLO)
+    shapes = [shape for _, shape in arrays]
+    # a loop body's gather is an array in memory, a fusion's inside is not
+    assert "bf16[1,1056,512]" in shapes
+    assert ("convolution", "f32[1,1024,4,8,1056]") not in arrays
+    leaks = smoke.span_attention_leaks(arrays, 1024, 4, 8, (1040, 1056))
+    assert leaks == [
+        ("fusion", "f32[1,1024,4,8,1056]"), ("reduce", "f32[1,1024,4,8]"),
+    ]
+    # the same module without the walk that keeps its scores in memory
+    # (and the two fusions only it calls)
+    cut = lambda text, a, b: text.replace(text[text.index(a):text.index(b)], "")
+    clean = cut(
+        cut(_CHUNK_HLO, "%fused_scores", "%walk_body"), "%old_body", "ENTRY"
+    )
+    # the fold's dense carry and the query's own [.., 4, 8, 128] are fine
+    assert smoke.span_attention_leaks(
+        smoke.hlo_arrays(clean), 1024, 4, 8, (1040, 1056)
+    ) == []
+    block = (1056 * 512,)
+    assert smoke.pool_relayouts(_CHUNK_HLO, block) == []  # entry only
+    assert smoke.pool_relayouts(_CHUNK_HLO, block, loops=True) == [
+        ("fusion", "bf16[1,1056,4,128]")
+    ]
+    assert smoke.pool_relayouts(clean, block, loops=True) == []

@@ -585,3 +585,288 @@ def test_decode_span_counts_what_the_grouped_walk_reads(params, monkeypatch):
     # pair (one key a slot) the first
     assert steps[-1]["occupancy"] == 2
     assert steps[-1]["kv_tokens_read_full"] == (2 + 1) * 2 * span
+
+
+# ------------------------------------------- a span of queries: the fused fold
+
+
+def _chunk_inputs(rng, start, valid, c, first, rows, ps=4, n_kv=2, group=2,
+                  hd=8, junk=1.0):
+    """One slot's chunk as the engine lays it out: queries at ``start ..
+    start + c`` of which ``valid`` are real, keys at ``0 .. start +
+    valid`` of which those from position-page ``first`` on sit in a
+    ``rows``-row table (scattered through layer 1 of a two-layer pool;
+    rows past the last key name the trash page, the last page's rows past
+    the last key hold what padding wrote there, ``junk`` times noise)."""
+    end = start + valid
+    total = (first + rows) * ps
+    k = rng.normal(size=(total, n_kv, hd)).astype(np.float32)
+    v = rng.normal(size=k.shape).astype(np.float32)
+    trash = rows
+    table = rng.permutation(rows).astype(np.int32)
+    pool_k = np.zeros((2, rows + 1, ps, n_kv * hd), np.float32)
+    pool_v = np.zeros_like(pool_k)
+    noise = rng.normal(size=(2,) + k.shape).astype(np.float32) * junk
+    for r in range(rows):
+        at = (first + r) * ps
+        if at >= end:
+            table[r] = trash
+            continue
+        rows_k, rows_v = k[at : at + ps].copy(), v[at : at + ps].copy()
+        past = np.arange(at, at + ps) >= end
+        rows_k[past], rows_v[past] = noise[0, :ps][past], noise[1, :ps][past]
+        pool_k[1, table[r]] = rows_k.reshape(ps, n_kv * hd)
+        pool_v[1, table[r]] = rows_v.reshape(ps, n_kv * hd)
+    pool_k[1, trash] = noise[0, ps : 2 * ps].reshape(ps, n_kv * hd)
+    pool_v[1, trash] = noise[1, ps : 2 * ps].reshape(ps, n_kv * hd)
+    q = rng.normal(size=(1, c, n_kv, group, hd)).astype(np.float32)
+    q_pos = (start + np.arange(c))[None].astype(np.int32)
+    return dict(
+        q=q, k=k, v=v, q_pos=q_pos, end=end, first=first,
+        args=(
+            q, pool_k, pool_v, table[None], np.asarray([first], np.int32),
+            q_pos, np.asarray([end], np.int32), 1,
+        ),
+    )
+
+
+def _span_read(case, window, block_pages):
+    import jax.numpy as jnp
+
+    *arrays, layer = case["args"]
+    return np.asarray(paged_attention_live(
+        *(jnp.asarray(a) for a in arrays), layer, window=window,
+        block_pages=block_pages,
+    ))[0]
+
+
+# window (0 = a full layer), the chunk's first position, its real tokens
+# of 16, the table's first position-page and rows (pages of 4 positions)
+CHUNKS = {
+    "full-first-chunk": dict(window=0, start=0, valid=16, first=0, rows=24),
+    "full-mid-table": dict(window=0, start=40, valid=16, first=0, rows=24),
+    "full-last-chunk-ends-inside-a-page": dict(
+        window=0, start=64, valid=7, first=0, rows=24,
+    ),
+    "full-to-the-tables-end": dict(
+        window=0, start=80, valid=16, first=0, rows=24,
+    ),
+    "window-first-chunk": dict(window=24, start=0, valid=16, first=0, rows=11),
+    "window-released-pages": dict(
+        window=24, start=64, valid=16, first=10, rows=11,
+    ),
+    "window-last-chunk-ends-inside-a-page": dict(
+        window=24, start=80, valid=5, first=14, rows=11,
+    ),
+    "window-narrower-than-the-chunk": dict(
+        window=6, start=32, valid=13, first=6, rows=7,
+    ),
+}
+
+
+@pytest.mark.parametrize("block_pages", [64, 6, 2])  # one, two.., many blocks
+@pytest.mark.parametrize("case", sorted(CHUNKS))
+def test_span_fold_agrees_with_dense_attention(case, block_pages):
+    """A chunk's queries through the fused fold against dense masked
+    attention in float32: every real query, and the padding rows past
+    ``valid`` wherever they still see a key."""
+    spec = dict(CHUNKS[case])
+    window = spec.pop("window")
+    chunk = _chunk_inputs(np.random.default_rng(13), c=16, **spec)
+    got = _span_read(chunk, window, block_pages)
+    assert np.isfinite(got).all()
+    sees = np.ones(16, bool)
+    if window:  # a padding row wholly past the window sees no key
+        sees = chunk["q_pos"][0] - window < chunk["end"]
+    want = _dense_attention(
+        chunk["q"][0][sees], chunk["k"], chunk["v"], chunk["q_pos"][0][sees],
+        chunk["end"], window,
+    )
+    np.testing.assert_allclose(got[sees], want, atol=2e-5)
+    assert not got[~sees].any()  # and reads zero
+
+
+@pytest.mark.parametrize("window", [0, 24])
+def test_span_fold_never_reads_the_trash_page_or_padding_rows(window):
+    """What idle rows of the table name, and what padding wrote behind
+    the prompt's last token, is masked to exact zeros: another filling
+    gives the same bits."""
+    spec = dict(start=64, valid=7, first=10 if window else 0, rows=24)
+    one = _chunk_inputs(np.random.default_rng(17), c=16, junk=1.0, **spec)
+    two = _chunk_inputs(np.random.default_rng(17), c=16, junk=-300.0, **spec)
+    np.testing.assert_array_equal(
+        _span_read(one, window, 6), _span_read(two, window, 6)
+    )
+
+
+@pytest.mark.parametrize("hd", [8, 128])
+def test_span_fold_widths_and_slots(hd):
+    """Three slots of different lengths in one span (the function's
+    contract, not the engine's one slot), heads narrower than a lane
+    tile and exactly one."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(19)
+    slots, c, n_kv, group, pages = 3, 16, 2, 2, 12
+    lengths = np.asarray([37, 16, 20], np.int32)
+    k, v, table, pool_k, pool_v = _scattered_pool(
+        rng, lengths, pages, (), hd=hd
+    )
+    q = rng.normal(size=(slots, c, n_kv, group, hd)).astype(np.float32)
+    q_pos = (lengths[:, None] - c + np.arange(c)[None]).astype(np.int32)
+    got = paged_attention_live(
+        jnp.asarray(q), jnp.asarray(pool_k), jnp.asarray(pool_v),
+        jnp.asarray(table), jnp.zeros(slots, jnp.int32), jnp.asarray(q_pos),
+        jnp.asarray(lengths), 1, window=9, block_pages=4,
+    )
+    for s in range(slots):
+        want = _dense_attention(q[s], k[s], v[s], q_pos[s], lengths[s], 9)
+        np.testing.assert_allclose(np.asarray(got[s]), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [0, 10])
+def test_span_fold_carry_after_each_block(window):
+    """``live_span_fold`` block by block against a float32 reference's
+    carry: the running max (base 2), the normaliser and the accumulator
+    after every block, rows that have seen no key yet included."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(23)
+    c, n_kv, group, hd, span, blocks = 16, 2, 2, 128, 12, 4
+    heads, length, start = n_kv * group, 41, 27
+    q = rng.normal(size=(1, c, heads, hd)).astype(np.float32)
+    k = rng.normal(size=(blocks * span, n_kv, hd)).astype(np.float32)
+    v = rng.normal(size=k.shape).astype(np.float32)
+    q_pos = (start + np.arange(c))[None].astype(np.int32)
+    scale = hd ** -0.5
+    m = np.full((1, c, heads), attention._NEG_BIG, np.float32)
+    l = np.zeros((1, c, heads), np.float32)
+    acc = np.zeros((1, c, heads * hd), np.float32)
+    scores = np.einsum(
+        "chd,thd->cht", q[0], np.repeat(k, group, axis=1)
+    ) * (scale * np.log2(np.e))
+    pos = np.arange(blocks * span)
+    seen = (pos[None] <= q_pos[0][:, None]) & (pos[None] < length)
+    if window:
+        seen &= pos[None] > q_pos[0][:, None] - window
+    for j in range(blocks):
+        at = slice(j * span, (j + 1) * span)
+        m, l, acc = (np.asarray(x) for x in attention.live_span_fold(
+            jnp.asarray(q.reshape(1, c, heads * hd)), jnp.asarray(q_pos),
+            jnp.asarray(k[at].reshape(1, span, n_kv * hd)),
+            jnp.asarray(v[at].reshape(1, span, n_kv * hd)),
+            jnp.asarray([j * span], jnp.int32),
+            jnp.asarray([length], jnp.int32),
+            jnp.asarray(m), jnp.asarray(l), jnp.asarray(acc),
+            n_kv=n_kv, window=window,
+        ))
+        upto = seen & (pos[None] < (j + 1) * span)
+        masked = np.where(upto[:, None, :], scores, -np.inf)
+        want_m = masked.max(-1)
+        p = np.where(
+            upto[:, None, :], np.exp2(scores - want_m[..., None]), 0.0
+        )
+        none = ~upto.any(-1)
+        assert (m[0][none] == np.float32(attention._NEG_BIG)).all()
+        np.testing.assert_allclose(m[0][~none], want_m[~none], rtol=1e-5)
+        np.testing.assert_allclose(l[0], p.sum(-1), rtol=2e-5)
+        np.testing.assert_allclose(
+            acc[0].reshape(c, heads, hd),
+            np.einsum("cht,thd->chd", p, np.repeat(v, group, axis=1)),
+            atol=2e-4,
+        )
+
+
+def test_span_trips_count_the_blocks_to_the_longest_slots_last_key():
+    import jax.numpy as jnp
+
+    for live, want in (([1], 1), ([128], 1), ([129], 2), ([40, 300], 3),
+                       ([9999], 6), ([0], 1)):
+        assert attention.live_span_trips(np.asarray(live), 128, 6) == want
+        traced = attention.live_span_trips(jnp.asarray(live, jnp.int32), 128, 6)
+        assert int(traced) == want
+
+
+TWELVE = dict(
+    TINY, num_hidden_layers=12,
+    layer_types=(["sliding_attention"] * 3 + ["full_attention"]) * 3,
+)
+
+
+def test_chunk_program_traces_the_span_walk_once_per_cache_kind():
+    """Twelve layers, nine window and three full: the chunk program calls
+    the jitted walk twelve times and holds two copies of it, one per
+    cache kind (counted in the lowered module, not timed)."""
+    import re
+
+    params = mellum.init_params(3, TWELVE, "float32")
+    eng = GenerationEngine(
+        params, max_slots=4, page_size=16, num_pages=64, max_seq_len=256,
+        queue_capacity=4, prefill_chunk_tokens=32,
+    )
+    args = eng._chunk_args(
+        np.zeros(1, np.int32), 0, 1, 1, SequencePages(eng.pool, eng.layout),
+        0.0, 0, 1.0,
+    )
+    text = eng._prefill_chunk_jit.lower(
+        eng._params_dev, eng.pool.k, eng.pool.v, *args
+    ).as_text()
+    defined = re.findall(r"func\.func private @(_live_span_walk\w*)\(", text)
+    called = re.findall(r"call @(_live_span_walk\w*)\(", text)
+    assert len(defined) == 2 and len(called) == 12
+    assert sorted(called.count(name) for name in defined) == [3, 9]
+
+
+def test_chunk_span_counts_the_blocks_the_program_walks(params, monkeypatch):
+    """A small live engine whose full layers' table is two blocks wide:
+    every ``serve.prefill_chunk`` span's ``attn_blocks`` is what the
+    trip counts give by hand, their sum is the folds the program itself
+    ran (counted on the device's side, one callback a fold), the fused
+    count is all of them and the counters carry the sums."""
+    import io
+    import json
+
+    import jax
+
+    from tensorframes_tpu import obs
+
+    ran = []
+    fold = attention.live_span_fold
+
+    def counted(*a, **kw):
+        jax.debug.callback(lambda: ran.append(1))
+        return fold(*a, **kw)
+
+    monkeypatch.setattr(attention, "live_span_fold", counted)
+    attention._live_span_walk.clear_cache()
+    before = obs.registry().snapshot()
+    sink = io.StringIO()
+    obs.set_trace_sink(sink)
+    try:
+        serve(
+            params, prompts_of((150, 20, 140)), (4, 4, 4), page_size=2,
+            num_pages=1024,  # 128 rows a full layer: two blocks of 64
+        )
+        jax.effects_barrier()
+    finally:
+        obs.set_trace_sink(None)
+        attention._live_span_walk.clear_cache()
+    events = [json.loads(l) for l in sink.getvalue().splitlines() if l.strip()]
+    chunks = [e["attrs"] for e in events if e["name"] == "serve.prefill_chunk"]
+    assert len(chunks) == 5 + 1 + 5
+    for a in chunks:
+        end = a["start"] + a["tokens"]
+        # three window layers of one block, one full layer to the block
+        # of 128 positions that holds the chunk's last token
+        assert a["attn_blocks"] == 3 * 1 + -(-end // 128)
+        assert a["attn_blocks_fused"] == a["attn_blocks"]
+    assert {a["attn_blocks"] for a in chunks} == {4, 5}
+    assert sum(a["attn_blocks"] for a in chunks) == len(ran)
+    after = obs.registry().snapshot()
+
+    def rose(name):
+        total = lambda snap: sum(snap[name]["values"].values())
+        return total(after) - (total(before) if name in before else 0)
+
+    assert rose("serve.chunk_attention_blocks_total") == len(ran)
+    assert rose("serve.chunk_attention_blocks_fused_total") == len(ran)
