@@ -754,45 +754,45 @@ def transformer_step(params, tok, positions, attend, moe_top_k: int = 1,
         return _head(spec, params, h)
 
 
-def transformer_prefill(params, tokens, store, moe_top_k: int = 1):
+def transformer_prefill(params, tokens, store, moe_top_k: int = 1,
+                        lengths=None):
     """Batched causal prompt pass that hands each layer's k/v to the
     caller: ``tokens`` [B, P] -> ``logits [B, P, vocab]``, with
     ``store(li, k, v)`` called once per layer on that layer's ``k`` /
     ``v`` ``[B, P, n_kv, hd]`` (the per-token rows as the projection
-    produced them).
+    produced them). ``lengths`` ``[B]`` int32 counts each row's real
+    tokens (None: every row is whole); positions past it are padding,
+    whose outputs are whatever attention gives them, and no real
+    position depends on them.
 
     This is the prefill half of serving decode: the whole prompt runs as
     dense MXU matmuls in one pass (instead of P sequential cache steps),
     the callback writes the rows wherever the caller keeps its cache —
     layer by layer, so no ``[L, B, P, ...]`` stack of all layers' k/v is
     ever held — and the caller continues with :func:`transformer_step`.
-    Attention uses the same grouped-query einsum family as the step
-    path."""
+    Attention is one fused kernel a layer
+    (:func:`~tensorframes_tpu.ops.attention.causal_prompt_attention`):
+    score tiles, mask and softmax state stay in fast memory, and tiles
+    above the diagonal, past ``lengths`` or outside a window layer's
+    window are skipped; no ``[heads, P, P]`` array of scores exists in
+    the program."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops.attention import causal_prompt_attention
 
     tokens = jnp.asarray(tokens, dtype=jnp.int32)
     bsz, plen = tokens.shape
     spec = model_spec(params)
-    scale = 1.0 / float(np.sqrt(spec.head_dim))
-    neg = jnp.finfo(jnp.float32).min * 0.7
-    qi, ki = jnp.arange(plen)[:, None], jnp.arange(plen)[None, :]
-    causal = qi >= ki  # [P(q), P(k)]
+    if lengths is None:
+        lengths = jnp.full((bsz,), plen, jnp.int32)
 
     def attend(li, q, k, v):
         store(li, k, v)
-        window = spec.layer_window(li)
-        visible = causal & (ki > qi - window) if window else causal
-        # [B, n_kv, P, hd] — what the decode step's dense twin reads
-        kc = k.transpose(0, 2, 1, 3)
-        vc = v.transpose(0, 2, 1, 3)
-        qh = q.transpose(0, 2, 3, 1, 4)
-        s = jnp.einsum("bkgqd,bktd->bkgqt", qh, kc) * scale
-        s = jnp.where(visible[None, None, None], s, neg)
-        att = jnp.einsum(
-            "bkgqt,bktd->bkgqd", jax.nn.softmax(s, axis=-1), vc
+        # every layer of one window is the same jitted callee
+        return causal_prompt_attention(
+            q, k, v, lengths, window=spec.layer_window(li)
         )
-        return att.transpose(0, 3, 1, 2, 4).reshape(bsz, plen, -1)
 
     h = _embed(spec, params, tokens, None)
     positions = jnp.arange(plen)[None]
@@ -822,8 +822,8 @@ def transformer_prefill_chunk(params, tokens, positions, attend,
     scatters k/v wherever it keeps its cache and reads the visible
     history under its own causal mask. The per-row math (LN, MLP,
     residuals, head split) is token-local and identical to
-    :func:`transformer_prefill`'s, so a prompt prefilled in chunks
-    produces byte-identical k/v and logits to one dense pass. Returns
+    :func:`transformer_prefill`'s; its attention sums in another order,
+    so chunks and one pass agree to float associativity. Returns
     logits ``[B, C, vocab]`` — or, with ``head_at`` (a traced row of the
     chunk), ``[B, vocab]`` for that row alone: the output head is as wide
     as the vocabulary, and a prefill needs it once."""

@@ -1121,7 +1121,7 @@ class GenerationEngine:
                     )
 
             logits = transformer_prefill(
-                full, prompt, store, moe_top_k=moe_top_k
+                full, prompt, store, moe_top_k, jnp.reshape(length, (1,))
             )
             kp, vp = state
             with jax.named_scope("sample"):
@@ -1150,8 +1150,8 @@ class GenerationEngine:
         positions ``start .. start + C``, attending to the pages already
         written (earlier chunks, or a shared-prefix cache hit) plus
         itself causally. The per-position math is
-        :func:`transformer_prefill_chunk`'s block walk — byte-identical
-        k/v and logits to the one-pass prefill — and the sampled token
+        :func:`transformer_prefill_chunk`'s block walk — the one-pass
+        prefill's to float associativity — and the sampled token
         mirrors the full program's (folded at the LAST prompt position),
         so only the final chunk's token is consumed."""
         import jax
@@ -2292,11 +2292,13 @@ class GenerationEngine:
             request=req.request_id,
             prompt_len=plen,
         ) as sp:
+            tiles = self._prompt_tile_attrs(plen)
             if sp is not None:
                 sp.attrs.update(
                     padded_len=self.max_seq_len,
                     pad_share=1.0 - plen / self.max_seq_len,
                     recompute=recompute,
+                    **tiles,
                 )
             pool.k, pool.v, tok = run_with_retries(
                 dispatch, what=f"serve.prefill request {req.request_id}"
@@ -2317,6 +2319,24 @@ class GenerationEngine:
                 sp.attrs["finished"] = int(
                     self.scheduler.slots[idx] is not act
                 )
+
+    def _prompt_tile_attrs(self, plen: int) -> dict:
+        """What a ``serve.prefill`` span says of its attention:
+        ``attn_tiles``, the pairs of a query and a key tile that the
+        one-pass prefill's kernel computes for a prompt of ``plen``
+        tokens in its row, all layers, and ``attn_tiles_skipped``, those
+        it skips (above the diagonal, past the prompt, outside a window)
+        — ``ops.prompt_attention_tiles``, the arithmetic of the kernel's
+        own regimes. Both feed their counters."""
+        from ..ops.attention import prompt_attention_tiles
+
+        computed, skipped = prompt_attention_tiles(
+            plen, self.max_seq_len,
+            [k.window for k in self._pool_layout.kinds for _ in k.layers],
+        )
+        _m_prompt_tiles.inc(computed)
+        _m_prompt_tiles_skipped.inc(skipped)
+        return {"attn_tiles": computed, "attn_tiles_skipped": skipped}
 
     def _decode_batch(self, ready: List[Tuple[int, _Active]]) -> None:
         s = self.max_slots
@@ -3370,4 +3390,17 @@ _m_chunk_blocks_fused = _counter(
     "serve.chunk_attention_blocks_fused_total",
     "Of serve.chunk_attention_blocks_total, the blocks folded into the "
     "online-softmax carry by the fused kernel (ops.live_span_fold)",
+)
+_m_prompt_tiles = _counter(
+    "serve.prefill_attention_tiles_total",
+    "Pairs of a query and a key tile the one-pass prefill's attention "
+    "kernel computed, all layers (the serve.prefill span's attn_tiles, "
+    "summed)",
+)
+_m_prompt_tiles_skipped = _counter(
+    "serve.prefill_attention_tiles_skipped_total",
+    "Pairs of a query and a key tile the one-pass prefill's attention "
+    "kernel skipped: above the causal diagonal, past the prompt or "
+    "outside a window (the serve.prefill span's attn_tiles_skipped, "
+    "summed)",
 )

@@ -157,19 +157,21 @@ def tp_prefill_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
     sharded along KV heads (ROADMAP 1 follow-on — it used to compute
     full heads replicated, sharding only the KV scatter): the prompt
     runs the delegated chunk walk at positions ``0 .. P-1``, and each
-    shard computes the dense causal attention for ITS head slice only —
-    the head axis is a pure batch axis in both einsums, so every local
-    head's scores/softmax/weighted-sum are bit-for-bit the solo
-    program's for that head, and the tiled all-gather reassembles the
-    solo context exactly. Per-chip prefill attention FLOPs and the
-    ``O(P^2)`` score matrix both scale ~1/N. The shard's own k/v slice
-    scatters straight into its pool shard (no full-head tensor is ever
-    materialized), and sampling mirrors
+    shard runs the solo program's attention kernel
+    (:func:`~tensorframes_tpu.ops.attention.causal_prompt_attention`)
+    on ITS head slice only — the kernel computes every head on its own,
+    so every local head's scores/softmax/weighted-sum are the solo
+    program's for that head (a head narrower than a lane tile may sit
+    in other lanes of it, so its dot products may sum in another
+    order), and the tiled all-gather reassembles the solo context.
+    Per-chip prefill attention FLOPs scale ~1/N. The shard's own k/v
+    slice scatters straight into its pool shard (no full-head tensor is
+    ever materialized), and sampling mirrors
     :meth:`GenerationEngine._prefill_impl` exactly."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.attention import _NEG_BIG
+    from ..ops.attention import causal_prompt_attention
 
     trash = engine.pool.trash_page
     top_k = engine.top_k
@@ -190,20 +192,13 @@ def tp_prefill_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
             vl = _local_heads(v[0], axis, kloc, 1)
             state[0] = write_prompt(state[0], li, ptab, length, kl, trash)
             state[1] = write_prompt(state[1], li, ptab, length, vl, trash)
-            hd = kl.shape[2]
-            scale = 1.0 / float(np.sqrt(hd))
-            # dense causal attention WITHIN the prompt, local heads:
-            # the same einsum family as transformer_prefill's, minus
-            # its batch axis — per head, bit-exact
-            s = jnp.einsum("qkgd,tkd->kgqt", ql, kl) * scale
-            causal = pos[:, None] >= pos[None, :]
-            s = jnp.where(causal[None, None], s, _NEG_BIG)
-            att = jnp.einsum(
-                "kgqt,tkd->kgqd", jax.nn.softmax(s, axis=-1), vl
+            att = causal_prompt_attention(
+                ql[None], kl[None], vl[None], jnp.reshape(length, (1,))
             )
-            att = jax.lax.all_gather(att, axis, axis=0, tiled=True)
-            # [n_kv, g, P, hd] -> [1, P, n_kv * g * hd]
-            return att.transpose(2, 0, 1, 3).reshape(1, plen, -1)
+            # [1, P, kloc * g * hd] -> [1, P, n_kv * g * hd]
+            return jax.lax.all_gather(
+                att.reshape(plen, kloc, -1), axis, axis=1, tiled=True
+            ).reshape(1, plen, -1)
 
         logits = transformer_prefill_chunk(
             full, prompt, pos, attend, moe_top_k=moe_top_k

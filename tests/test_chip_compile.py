@@ -9,6 +9,7 @@ once, inside the fixture, and keeps it."""
 import functools
 import importlib.util
 import os
+import re
 
 import pytest
 
@@ -107,3 +108,110 @@ def test_span_walk_compiles_for_the_chip_at_mellums_widths(
     # carry, one gathered block of K and V and the table (the XLA walk
     # it replaced kept a block of float32 scores, 138 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 40 * 2**20
+
+
+def _prefill_program(one_chip, n_layers, attend=None):
+    """The one-pass prefill at gpt2-xl's widths (a ``[1, 1024]`` row, 25
+    heads of 64, float32, the pool written as the engine writes it)
+    compiled for the chip from shapes alone; with ``attend`` the same
+    block walk under that attention instead of the kernel's."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorframes_tpu.models.transformer import (
+        init_transformer, transformer_prefill, transformer_prefill_chunk,
+    )
+    from tensorframes_tpu.serve.kv_pages import write_prompt
+
+    rows, d, heads, pages = 1024, 1600, 25, 177
+    shapes = jax.eval_shape(lambda: {
+        k: v for k, v in init_transformer(
+            0, 512, d_model=d, n_heads=heads, n_layers=n_layers,
+            max_len=rows,
+        ).items() if k != "n_heads"
+    })
+    of = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree.map(lambda s: of(s.shape, s.dtype), shapes)
+    pool = of((n_layers, pages, 16, d), "float32")
+
+    def prefill(p, kp, vp, prompt, length, ptab):
+        full = {**p, "n_heads": heads}
+        state = [kp, vp]
+
+        def store(li, k, v):
+            for n, x in enumerate((k, v)):
+                state[n] = write_prompt(
+                    state[n], li, ptab, length, x[0], pages - 1
+                )
+
+        if attend is None:
+            logits = transformer_prefill(
+                full, prompt, store, 1, jnp.reshape(length, (1,))
+            )
+        else:
+            logits = transformer_prefill_chunk(
+                full, prompt, jnp.arange(rows),
+                lambda li, q, k, v: (store(li, k, v), attend(q, k, v))[1],
+            )
+        return state[0], state[1], jnp.argmax(logits[0, length - 1])
+
+    return jax.jit(prefill, donate_argnums=(1, 2)).lower(
+        params, pool, pool, of((1, rows), "int32"), of((), "int32"),
+        of((64,), "int32"),
+    ).compile()
+
+
+def _dense_causal(q, k, v):
+    """Attention as the one-pass prefill computed it before the kernel:
+    the scores of every head over the whole row, masked, softmax."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, hd = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bqngd,btnd->bngqt", q, k) / float(np.sqrt(hd))
+    causal = jnp.tril(jnp.ones((rows, rows), bool))
+    s = jnp.where(causal, s, jnp.finfo(jnp.float32).min * 0.7)
+    att = jnp.einsum("bngqt,btnd->bqngd", jax.nn.softmax(s, axis=-1), v)
+    return att.reshape(q.shape[0], rows, -1)
+
+
+def test_one_pass_prefill_compiles_for_the_chip_at_gpt2_xls_widths(
+    one_chip, compile_settings, monkeypatch
+):
+    from tensorframes_tpu.ops import attention
+
+    monkeypatch.setattr(
+        attention, "prompt_attention",
+        functools.partial(attention.prompt_attention, interpret=False),
+    )
+    attention.causal_prompt_attention.clear_cache()
+    try:
+        kernel = _prefill_program(one_chip, 3)
+    finally:
+        attention.causal_prompt_attention.clear_cache()
+    dense = _prefill_program(one_chip, 3, _dense_causal)
+    smoke = _chip_smoke()
+    scores = 25 * 1024 * 1024
+
+    def score_blocks(compiled):
+        return [
+            (op, shape) for op, shape in smoke.hlo_arrays(compiled.as_text())
+            if scores in smoke._elements(shape)
+        ]
+
+    # the compiler took the kernel's tiles (a kernel that asks for more
+    # fast memory than it may hold fails to compile): one call a layer
+    calls = re.findall(
+        rf"%{attention.PROMPT_KERNEL}[.\d]* = .* custom-call\(",
+        kernel.as_text(),
+    )
+    assert len(calls) == 3
+    # no heads x P x P array anywhere in the program; the dense arm at
+    # the same shapes writes its scores to memory
+    assert score_blocks(kernel) == []
+    assert score_blocks(dense) != []
+    assert (
+        kernel.memory_analysis().temp_size_in_bytes
+        < dense.memory_analysis().temp_size_in_bytes
+    )
