@@ -574,11 +574,17 @@ class InstrumentedProgram:
     switch on (``TFT_OBS=0``) wrapping and calling leave the registry —
     and the persisted JSONL — completely untouched (``record`` stays
     ``None``). Attribute access (``.lower`` for ``precompile``)
-    delegates to the wrapped jit."""
+    delegates to the wrapped jit.
+
+    The last enabled call's wall splits at its two seams: ``issue_s``,
+    from the call to the jitted function's return (Python dispatch and
+    argument transfer), what ``after_issue`` reports it spent, and
+    ``wait_s``, the rest (the wait for the device and the wake-up
+    after it)."""
 
     __slots__ = (
         "_fn", "_sync", "_after_issue", "_estimated", "record", "_key",
-        "_name", "_kind", "_meta", "_cache_size",
+        "_name", "_kind", "_meta", "_cache_size", "issue_s", "wait_s",
     )
 
     def __init__(
@@ -601,6 +607,8 @@ class InstrumentedProgram:
         #: prefill width) as a dispatch would poison achieved-FLOP/s
         #: with seconds of compile wall
         self._cache_size = -1
+        self.issue_s = 0.0
+        self.wait_s = 0.0
 
     def __call__(self, *args, **kwargs):
         after_issue = self._after_issue
@@ -618,10 +626,12 @@ class InstrumentedProgram:
             _listen()
         _tls.loading = rec
         t0 = time.perf_counter()
+        hosted = None
         try:
             out = self._fn(*args, **kwargs)
+            issued = time.perf_counter()
             if after_issue is not None:
-                after_issue()
+                hosted = after_issue()
             if self._sync:
                 import jax
 
@@ -630,6 +640,8 @@ class InstrumentedProgram:
             _tls.loading = None
             _book_trace(rec)  # a trace that no lowering followed
         dt = time.perf_counter() - t0
+        self.issue_s = issued - t0
+        self.wait_s = dt - self.issue_s - (hosted or 0.0)
         try:
             size = self._fn._cache_size()
         except Exception:
@@ -674,7 +686,9 @@ def instrument(
     that needs nothing of this program's result runs there beside the
     device instead of between two programs (the serving engine delivers
     the last step's tokens there). It runs inside the timed window and
-    under the kill switch too, and what it raises fails the call."""
+    under the kill switch too, and what it raises fails the call. What
+    it returns, if a number, is the seconds it spent: the wrapper's
+    ``wait_s`` leaves them out."""
     return InstrumentedProgram(fn, key, name, kind, meta, sync, after_issue)
 
 

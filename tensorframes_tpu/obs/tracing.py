@@ -5,7 +5,11 @@ stack (each serving connection / decode worker gets its own tree), records
 wall duration and — when a pytree is attached via the ``sync`` argument or
 ``Span.sync`` — a device-synchronized duration as well, and forwards to
 ``jax.profiler.TraceAnnotation`` so spans appear as named slices inside
-Perfetto/TensorBoard traces captured by ``utils.profiling.trace()``.
+Perfetto/TensorBoard traces captured by ``utils.profiling.trace()``; the
+slice keeps the span's name and carries its ``span_id`` and, at exit,
+its ``cpu_s`` and ``dur_s`` as event stats (a chained phase's ``dur_s``
+reaches back before the slice begins), so the trace's host events are
+the spans on the device's clock with their CPU time.
 
 **Identity** (the distributed layer, PR 10): every span carries a
 ``trace_id`` (32 lowercase hex chars — one END-TO-END request or job),
@@ -36,18 +40,22 @@ environment variable. Event schema (stable; documented in
      "parent_id": "16hex" | null, "depth": int,
      "ts": float epoch-seconds at entry,
      "t_mono": float time.monotonic() at entry, "dur_s": float wall,
+     "cpu_s": float this thread's CPU time over the same interval,
      "dur_synced_s": float (only when a sync tree was attached),
      "thread": str, "attrs": {str: json-value}}
 
-Events are written when a span CLOSES, so children appear before their
-parents — consumers reconstruct the tree from ``parent_id`` and group
-requests by ``trace_id``. ``t_mono`` is the system-wide monotonic clock
-at entry — the clock load generators and benchmarks stamp, so a span
-lies beside their times without a conversion (``ts`` stays for readers
-that want wall-clock time). :func:`event` emits a point event (``dur_s``
-0, written immediately) — the record a crash cannot destroy, used by the
-distributed-job lease claims so a kill -9'd worker's claim is still in
-the trace.
+``dur_s - cpu_s`` is the time the thread spent off the CPU inside the
+span: waiting for the interpreter lock, a sleep, a device wait, the OS
+scheduler (where the thread clock ticks coarsely, only a sum over many
+spans says it). Events are written when a span CLOSES, so children appear
+before their parents — consumers reconstruct the tree from
+``parent_id`` and group requests by ``trace_id``. ``t_mono`` is the
+system-wide monotonic clock at entry — the clock load generators and
+benchmarks stamp, so a span lies beside their times without a
+conversion (``ts`` stays for readers that want wall-clock time).
+:func:`event` emits a point event (``dur_s`` 0, written immediately) —
+the record a crash cannot destroy, used by the distributed-job lease
+claims so a kill -9'd worker's claim is still in the trace.
 
 A path-configured sink **rotates by size**: when the file would exceed
 ``max_bytes`` (default 64 MiB, ``TFT_TRACE_FILE_MAX_BYTES``), it is
@@ -360,14 +368,17 @@ class SpanChain:
     they cover — the span machinery between two ``with`` blocks (closing
     one event, opening the next) and whatever else ran there is inside
     the phase that follows, not in a hole between two spans. Only the
-    sink event is backdated; a ``TraceAnnotation`` starts when entered.
-    The owner calls :meth:`reset` where the loop was not running (a
-    caller's pause between two steps)."""
+    sink event is backdated, its ``cpu_s`` with it; a ``TraceAnnotation``
+    starts when entered. The owner calls :meth:`reset` where the loop was
+    not running (a caller's pause between two steps); a link made on
+    another thread is not followed (the CPU clock is the thread's)."""
 
-    __slots__ = ("t", "gen")
+    __slots__ = ("t", "cpu", "thread", "gen")
 
     def __init__(self):
         self.t: Optional[float] = None  # perf_counter at the last end
+        self.cpu = 0.0  # thread_time at the last end
+        self.thread = 0  # the thread that made the last link
         self.gen = -1
 
     def reset(self) -> None:
@@ -382,7 +393,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "depth", "attrs",
-        "sync", "ts", "t_mono", "_t0", "_ann", "_chain",
+        "sync", "ts", "t_mono", "_t0", "_c0", "_ann", "_chain",
     )
 
     def __init__(self, name, sync, attrs, chain=None):
@@ -396,6 +407,7 @@ class Span:
         self.ts = 0.0
         self.t_mono = 0.0
         self._t0 = 0.0
+        self._c0 = 0.0
         self._ann = None
         self._chain: Optional[SpanChain] = chain
 
@@ -419,7 +431,7 @@ class Span:
         if _annotations_on:
             ann_cls = _annotation_cls()
             if ann_cls is not None:
-                self._ann = ann_cls(self.name)
+                self._ann = ann_cls(self.name, span_id=self.span_id)
                 self._ann.__enter__()
         self.ts = time.time()
         self.t_mono = time.monotonic()
@@ -429,20 +441,29 @@ class Span:
             chain is not None
             and chain.t is not None
             and chain.gen == _consumers_gen
-        ):
+            and chain.thread == threading.get_ident()
+            and self._t0 > chain.t
+        ):  # begin where the chain's last span ended
             back = self._t0 - chain.t
-            if back > 0.0:  # begin where the chain's last span ended
-                self.ts -= back
-                self.t_mono -= back
-                self._t0 = chain.t
+            self.ts -= back
+            self.t_mono -= back
+            self._t0 = chain.t
+            self._c0 = chain.cpu
+        else:
+            self._c0 = time.thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        cpu_end = time.thread_time()
         end = time.perf_counter()
         wall = end - self._t0
-        if self._chain is not None:
-            self._chain.t = end
-            self._chain.gen = _consumers_gen
+        cpu = cpu_end - self._c0
+        chain = self._chain
+        if chain is not None:
+            chain.t = end
+            chain.cpu = cpu_end
+            chain.thread = threading.get_ident()
+            chain.gen = _consumers_gen
         synced = None
         if self.sync is not None:
             try:
@@ -452,12 +473,15 @@ class Span:
                 synced = time.perf_counter() - self._t0
             except Exception:
                 pass  # sync is best-effort diagnostics, never a failure
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
+        ann = self._ann
+        if ann is not None:
+            if _ann_metadata:  # dur_s: a chained phase began before it
+                ann.set_metadata(cpu_s=cpu, dur_s=wall)
+            ann.__exit__(None, None, None)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
-        _emit(self, wall, synced)
+        _emit(self, wall, cpu, synced)
         return False
 
 
@@ -479,7 +503,7 @@ def _set_flight_capture(on: bool) -> None:
     _consumers_gen += 1
 
 
-def _emit(s: Span, wall: float, synced: Optional[float]) -> None:
+def _emit(s: Span, wall: float, cpu: float, synced: Optional[float]) -> None:
     if _flight_spans_on:
         from . import flight as _flight
 
@@ -495,6 +519,7 @@ def _emit(s: Span, wall: float, synced: Optional[float]) -> None:
         "ts": s.ts,
         "t_mono": s.t_mono,
         "dur_s": wall,
+        "cpu_s": cpu,
         "thread": threading.current_thread().name,
         "attrs": s.attrs,
     }
@@ -559,6 +584,9 @@ def event(name: str, **attrs) -> Optional[TraceContext]:
 
 _ann_cls = None
 _ann_tried = False
+#: the installed jax's annotation takes stats at exit (``set_metadata``):
+#: the span's ``cpu_s`` and ``dur_s`` go there
+_ann_metadata = False
 #: forward spans to jax.profiler.TraceAnnotation only while someone is
 #: actually capturing a trace: an annotation inside a dispatching pass
 #: measures ~5-10 µs (TraceMe + pybind crossing on a cold cache), which is
@@ -579,13 +607,14 @@ def set_annotations(on: bool) -> None:
 def _annotation_cls():
     """``jax.profiler.TraceAnnotation`` resolved once (or ``None`` when
     jax/its profiler is unavailable — spans must work without it)."""
-    global _ann_cls, _ann_tried
+    global _ann_cls, _ann_tried, _ann_metadata
     if not _ann_tried:
         _ann_tried = True
         try:
             import jax
 
             _ann_cls = jax.profiler.TraceAnnotation
+            _ann_metadata = hasattr(_ann_cls, "set_metadata")
         except Exception:
             _ann_cls = None
     return _ann_cls

@@ -963,10 +963,10 @@ class GenerationEngine:
         #: replica kill lands at a step boundary instead of racing a
         #: step in progress
         self._poison: Optional[BaseException] = None
-        #: ``time.perf_counter()`` when the last step program's wait
-        #: returned (prefill, chunk, decode, draft or verify): the next
-        #: decode dispatch reads its ``host_gap_s`` off it
+        #: when the last step program's wait returned: perf_counter, and
+        #: (thread, thread_time) if its span was live (_note_hosted)
         self._wait_returned_t: Optional[float] = None
+        self._wait_returned_cpu: Optional[Tuple[int, float]] = None
         #: the step loop's phase spans run back to back on this chain
         #: (each begins where the last ended), so their walls add up to
         #: the host time between two dispatches with nothing in between
@@ -2145,7 +2145,6 @@ class GenerationEngine:
         recompute = max(0, min(start + valid, req.computed) - start)
         t0 = time.perf_counter()
         routed = valid * self._pairs_per_token
-        walked = self._chunk_walk_attrs(act.seq, start + valid)
         with _use_trace(req.trace), _span(
             "serve.prefill_chunk",
             chain=self._phases,
@@ -2155,14 +2154,16 @@ class GenerationEngine:
             recompute=recompute,
             pad_share=1.0 - valid / c,
             tokens_routed=routed,
-            **walked,
         ) as sp:
+            if sp is not None and self._long:
+                sp.attrs["attn_blocks"] = self._chunk_attn_blocks(
+                    act.seq, start + valid
+                )
             pool.k, pool.v, tok = run_with_retries(
                 dispatch,
                 what=f"serve.prefill_chunk request {req.request_id}",
             )
-            self._wait_returned_t = time.perf_counter()
-            self._note_hosted(sp)
+            self._note_hosted(sp, self._prefill_chunk_jit)
         self._owe(
             self._charge_prefill, req.handle.timings,
             self._prefill_chunk_jit, valid, recompute,
@@ -2173,19 +2174,14 @@ class GenerationEngine:
             self._register_prefix(act)
             self._emit(idx, act, int(tok))
 
-    def _chunk_walk_attrs(self, seq, end: int) -> dict:
-        """What a live ``serve.prefill_chunk`` span says of its
-        attention walks: ``attn_blocks``, the blocks of keys the chunk
-        program visits for a chunk whose last real token sits before
-        ``end``, all layers — per cache kind the trip count of
-        ``ops.paged_attention_live``'s span walk
-        (``live_span_trips``, the arithmetic the program's own count
-        comes from) times the kind's layers — and ``attn_blocks_fused``,
-        those of them the fused kernel folds: every one, the span walk
-        has no other fold. Both feed their counters. Nothing for an
-        engine whose chunk program reads the gathered table."""
-        if not self._long:
-            return {}
+    def _chunk_attn_blocks(self, seq, end: int) -> int:
+        """``attn_blocks`` of a live engine's ``serve.prefill_chunk``
+        span: the blocks of keys the chunk program's attention walks
+        visit for a chunk whose last real token sits before ``end``, all
+        layers — per cache kind the trip count of
+        ``ops.paged_attention_live``'s span walk (``live_span_trips``,
+        the arithmetic the program's own count comes from) times the
+        kind's layers."""
         from ..ops.attention import live_read_blocks, live_span_trips
 
         ps = self.page_size
@@ -2197,9 +2193,7 @@ class GenerationEngine:
             blocks += len(kind.layers) * int(
                 live_span_trips(live, bp * ps, n_blocks)
             )
-        _m_chunk_blocks.inc(blocks)
-        _m_chunk_blocks_fused.inc(blocks)
-        return {"attn_blocks": blocks, "attn_blocks_fused": blocks}
+        return blocks
 
     def _chunk_args(
         self, tokens, start, valid, plen, seq, temperature, seed, top_p
@@ -2292,19 +2286,17 @@ class GenerationEngine:
             request=req.request_id,
             prompt_len=plen,
         ) as sp:
-            tiles = self._prompt_tile_attrs(plen)
             if sp is not None:
                 sp.attrs.update(
                     padded_len=self.max_seq_len,
                     pad_share=1.0 - plen / self.max_seq_len,
                     recompute=recompute,
-                    **tiles,
+                    **self._prompt_tile_attrs(plen),
                 )
             pool.k, pool.v, tok = run_with_retries(
                 dispatch, what=f"serve.prefill request {req.request_id}"
             )
-            self._wait_returned_t = time.perf_counter()
-            self._note_hosted(sp)
+            self._note_hosted(sp, self._prefill_jit)
         with _span("serve.readback", chain=self._phases):
             tok = int(tok)
         with _span("serve.emit", chain=self._phases, tokens=1) as sp:
@@ -2327,15 +2319,13 @@ class GenerationEngine:
         tokens in its row, all layers, and ``attn_tiles_skipped``, those
         it skips (above the diagonal, past the prompt, outside a window)
         — ``ops.prompt_attention_tiles``, the arithmetic of the kernel's
-        own regimes. Both feed their counters."""
+        own regimes."""
         from ..ops.attention import prompt_attention_tiles
 
         computed, skipped = prompt_attention_tiles(
             plen, self.max_seq_len,
             [k.window for k in self._pool_layout.kinds for _ in k.layers],
         )
-        _m_prompt_tiles.inc(computed)
-        _m_prompt_tiles_skipped.inc(skipped)
         return {"attn_tiles": computed, "attn_tiles_skipped": skipped}
 
     def _decode_batch(self, ready: List[Tuple[int, _Active]]) -> None:
@@ -2366,8 +2356,7 @@ class GenerationEngine:
             pool.k, pool.v, nxt = run_with_retries(
                 dispatch, what="serve.decode_step"
             )
-            self._wait_returned_t = time.perf_counter()
-            self._note_hosted(sp)
+            self._note_hosted(sp, self._decode_jit)
             if self._pairs_per_token:
                 # the expert layers' pair counts came back behind the
                 # tokens, in the one array the host reads anyway
@@ -2397,13 +2386,27 @@ class GenerationEngine:
     ) -> None:
         """What a live ``serve.decode_step`` span says of its batch: who
         is in it, how long the host took since the last step program's
-        wait returned, and how many KV positions the configured read
-        touches per layer against how many are live — ``gather`` reads
-        every slot's whole page table whatever the lengths, ``fused``
-        the live pages."""
+        wait returned — up to where this span began where that
+        program's span was live too, and then how much of it the thread
+        spent off the CPU — and how many KV positions the configured
+        read touches per layer against how many are live — ``gather``
+        reads every slot's whole page table whatever the lengths,
+        ``fused`` the live pages."""
         attrs["requests"] = [a.req.request_id for _, a in ready]
-        if self._wait_returned_t is not None:
-            attrs["host_gap_s"] = time.perf_counter() - self._wait_returned_t
+        waited, mark = self._wait_returned_t, self._wait_returned_cpu
+        chain = self._phases
+        if (
+            mark is not None
+            and mark[0] == chain.thread == threading.get_ident()
+            and chain.t is not None
+            and chain.t >= waited
+        ):
+            # up to where this span began, the end of the phase before
+            # it, whose span read both clocks already
+            gap = attrs["host_gap_s"] = chain.t - waited
+            attrs["host_gap_offcpu_s"] = gap - (chain.cpu - mark[1])
+        elif waited is not None:
+            attrs["host_gap_s"] = time.perf_counter() - waited
         live = sum(a.length for _, a in ready)
         if self._long:  # by cache kind, and their mean over the layers
             read, live = self._live_read_attrs(attrs, ready)
@@ -2602,8 +2605,7 @@ class GenerationEngine:
             g.k, g.v, out = run_with_retries(
                 dispatch, what="serve.draft"
             )
-            self._wait_returned_t = time.perf_counter()
-            self._note_hosted(sp)
+            self._note_hosted(sp, self._draft_jit)
         # no _charge_collectives: the draft program is replicated —
         # it runs no cross-chip gathers even under a TP mesh
         for idx, act in ready:
@@ -2672,8 +2674,7 @@ class GenerationEngine:
             pool.k, pool.v, u = run_with_retries(
                 dispatch, what="serve.verify"
             )
-            self._wait_returned_t = time.perf_counter()
-            self._note_hosted(sp)
+            self._note_hosted(sp, self._verify_jit)
         verify_wall = self._wait_returned_t - t0
         _m_verify_s.observe(verify_wall)
         self._charge_collectives()
@@ -2828,25 +2829,39 @@ class GenerationEngine:
                 _m_delivered_under_program.inc(tokens)
         return tokens, finished
 
-    def _deliver_hosted(self) -> None:
+    def _deliver_hosted(self) -> float:
         """``after_issue`` of every step program: the program is issued,
         the device works, and the thread is about to sleep on it with
         the interpreter lock released — the streams' threads run then,
-        not in the gap before the next program."""
-        if self._owed:
-            t0 = time.perf_counter()
-            tokens, _ = self._drain(under_program=True)
-            self._hosted[0] += tokens
-            self._hosted[1] += time.perf_counter() - t0
+        not in the gap before the next program. Returns the seconds it
+        took (the program's ``wait_s`` leaves them out)."""
+        if not self._owed:
+            return 0.0
+        t0 = time.perf_counter()
+        tokens, _ = self._drain(under_program=True)
+        seconds = time.perf_counter() - t0
+        self._hosted[0] += tokens
+        self._hosted[1] += seconds
+        return seconds
 
-    def _note_hosted(self, sp) -> None:
-        """What the program whose wait just returned hosted, onto its
-        span (``delivered_tokens``, ``deliver_s``)."""
+    def _note_hosted(self, sp, prog) -> None:
+        """The wait of ``prog`` (an instrumented step program) just
+        returned: note when, for the next decode's ``host_gap_s``, and
+        onto its span how its wall split — ``issue_s``, what it hosted
+        (``delivered_tokens``, ``deliver_s``) and ``wait_s``, which sum
+        to the registry's dispatch wall of the call — and, on a live
+        span only, this thread's CPU clock, for ``host_gap_offcpu_s``."""
+        self._wait_returned_t = time.perf_counter()
         tokens, seconds = self._hosted
         self._hosted = [0, 0.0]
-        if sp is not None:
-            sp.attrs["delivered_tokens"] = tokens
-            sp.attrs["deliver_s"] = seconds
+        if sp is None:
+            self._wait_returned_cpu = None
+            return
+        self._wait_returned_cpu = (threading.get_ident(), time.thread_time())
+        sp.attrs.update(
+            issue_s=prog.issue_s, delivered_tokens=tokens,
+            deliver_s=seconds, wait_s=prog.wait_s,
+        )
 
     def _deliver_now(self, chain: Optional[_SpanChain] = None) -> None:
         """Deliver with no program to hide behind, in a span of its own
@@ -3375,32 +3390,3 @@ class GenerationEngine:
             self.run_until_idle()
         timeout = get_config().serve_result_timeout_s
         return [h.result(timeout=timeout) for h in handles]
-
-
-# registered down here, below every step program's code, so that no
-# source position of a compiled program moves (a program's cache key
-# holds them)
-_m_chunk_blocks = _counter(
-    "serve.chunk_attention_blocks_total",
-    "Blocks of keys the live prefill-chunk programs' attention walks "
-    "visited, all layers (the serve.prefill_chunk span's attn_blocks, "
-    "summed)",
-)
-_m_chunk_blocks_fused = _counter(
-    "serve.chunk_attention_blocks_fused_total",
-    "Of serve.chunk_attention_blocks_total, the blocks folded into the "
-    "online-softmax carry by the fused kernel (ops.live_span_fold)",
-)
-_m_prompt_tiles = _counter(
-    "serve.prefill_attention_tiles_total",
-    "Pairs of a query and a key tile the one-pass prefill's attention "
-    "kernel computed, all layers (the serve.prefill span's attn_tiles, "
-    "summed)",
-)
-_m_prompt_tiles_skipped = _counter(
-    "serve.prefill_attention_tiles_skipped_total",
-    "Pairs of a query and a key tile the one-pass prefill's attention "
-    "kernel skipped: above the causal diagonal, past the prompt or "
-    "outside a window (the serve.prefill span's attn_tiles_skipped, "
-    "summed)",
-)

@@ -192,6 +192,54 @@ def test_host_gap_lies_inside_the_wall_between_two_decode_steps(lm, sink):
     assert gaps - between < max(0.15 * gaps, 3e-4)
 
 
+def test_decode_wall_splits_into_issue_delivery_and_wait(
+    lm, sink, monkeypatch
+):
+    """Every ``serve.decode_step`` says how its program's wall split:
+    ``issue_s`` + ``deliver_s`` + ``wait_s`` is the registry's dispatch
+    wall of that call."""
+    walls = []
+    record = programs.ProgramRecord.add_dispatch
+
+    def add_dispatch(self, seconds):
+        if self.name.startswith("serve.decode["):
+            walls.append(seconds)
+        record(self, seconds)
+
+    monkeypatch.setattr(programs.ProgramRecord, "add_dispatch", add_dispatch)
+    eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
+    eng.generate([[1, 2, 3]], max_new_tokens=2)  # compile outside the count
+    walls.clear()
+    sink.lines.clear()
+    eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=6)
+    steps = [
+        e["attrs"] for e in sink.events() if e["name"] == "serve.decode_step"
+    ]
+    assert len(steps) == len(walls) == 5
+    for a, wall in zip(steps, walls):
+        assert a["issue_s"] > 0 and a["wait_s"] > 0
+        split = a["issue_s"] + a["deliver_s"] + a["wait_s"]
+        assert abs(split - wall) < 1e-3, (a, wall)
+    for e in sink.events():
+        if e["name"] == "serve.prefill":
+            assert {"issue_s", "deliver_s", "wait_s"} <= set(e["attrs"])
+
+
+def test_host_gap_splits_into_work_and_waiting(lm, sink):
+    eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
+    eng.submit([1, 2, 3], 8)
+    eng.run_until_idle()
+    steps = [
+        e["attrs"] for e in sink.events() if e["name"] == "serve.decode_step"
+    ]
+    assert len(steps) == 7
+    for a in steps:
+        assert 0.0 <= a["host_gap_offcpu_s"] <= a["host_gap_s"], a
+    # every span carries the thread's CPU time beside its wall
+    for e in sink.events():
+        assert 0.0 <= e["cpu_s"] <= e["dur_s"] + 1e-4, e
+
+
 def test_a_callers_pause_between_steps_is_no_phase(lm, sink):
     eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
     eng.submit([1, 2, 3], 4)
