@@ -12,7 +12,8 @@ user would call, and checks what comes out by the repo's own means:
 2. serving plane — GPT-2 small widths behind ``ScoringServer(engine=...)``:
    eight ``POST /generate`` requests over the socket, then ``/metrics`` and
    ``/healthz``;
-3. kernels — the fused ragged paged-attention engine, three flash-attention
+3. kernels — the decode read's ragged paged-attention kernel against its
+   gather oracle, three flash-attention
    training steps, flash forward + grad against the reference; then the KV
    pool's layout: the decode and prefill programs compiled at 25 heads of
    64 must hold no copy or transpose of the pool, of a layer of it or of
@@ -448,7 +449,7 @@ def phase_pool_layout(run, S):
     lm = TransformerLM.init(0, S.layout_vocab, **S.layout_lm)
     eng = GenerationEngine(
         lm, max_slots=S.max_slots, page_size=S.page_size,
-        num_pages=S.layout_pages, attention_impl="gather",
+        num_pages=S.layout_pages,
     )
     pool, decode_args, prefill_args = _step_specs(eng)
     whole = math.prod(pool.shape)
@@ -812,13 +813,15 @@ def phase_serve(run, S, state):
         init_s=round(time.perf_counter() - t0, 2),
     )
     state["lm"] = lm
-    eng = GenerationEngine(
-        lm, max_slots=S.max_slots, page_size=S.page_size,
-        attention_impl="gather",
-    )
-    served = _serve_over_http(run, eng, S, "gather engine")
-    _engine_checks(run, eng, "gather engine")
-    _memory(run, "gather engine resident")
+    eng = GenerationEngine(lm, max_slots=S.max_slots, page_size=S.page_size)
+    served = _serve_over_http(run, eng, S, "engine")
+    text = _engine_checks(run, eng, "engine")
+    if not run.rehearsal:
+        run.check(
+            "tpu_custom_call" in text,
+            "the decode program holds the read's tpu_custom_call",
+        )
+    _memory(run, "engine resident")
     del eng
     gc.collect()
     state["served"] = served
@@ -893,30 +896,9 @@ def phase_kernels(run, S, state):
         paged_attention,
         ragged_paged_attention,
     )
-    from tensorframes_tpu.serve import GenerationEngine
 
     lm = state["lm"]
-    # (a) the fused ragged paged-attention read, in the engine and alone
-    eng = GenerationEngine(
-        lm, max_slots=S.max_slots, page_size=S.page_size,
-        attention_impl="fused",
-    )
-    fused = _serve_over_http(run, eng, S, "fused engine")
-    text = _engine_checks(run, eng, "fused engine")
-    if not run.rehearsal:
-        run.check(
-            "tpu_custom_call" in text,
-            "the fused decode program holds a tpu_custom_call",
-        )
-    del eng
-    gc.collect()
-    if state.get("served") and all(t is not None for t in fused):
-        _compare_streams(
-            run, "fused engine vs gather engine", fused, state["served"],
-            state["kinds"],
-        )
-    state["fused"] = fused
-
+    # (a) the decode read's kernel alone, against its gather oracle
     hd = S.lm["d_model"] // S.lm["n_heads"]
     n_kv, ps = S.lm["n_heads"], S.page_size
     max_pages = S.lm["max_len"] // ps
@@ -1079,7 +1061,7 @@ def phase_tp(run, S, state):
     lm, served = _one_chip_streams(state)
     eng = GenerationEngine(
         lm, max_slots=S.max_slots, page_size=S.page_size,
-        attention_impl="gather", mesh=par.make_mesh({"tp": 4}),
+        mesh=par.make_mesh({"tp": 4}),
     )
     tp = _serve_over_http(run, eng, S, "tp=4 engine")
     _engine_checks(run, eng, "tp=4 engine")
@@ -1097,7 +1079,6 @@ def phase_fleet(run, S, state):
     lm, served = _one_chip_streams(state)
     fleet = Fleet(
         lm, replicas=4, max_slots=S.max_slots, page_size=S.page_size,
-        attention_impl="gather",
     )
     got = _serve_over_http(run, fleet, S, "fleet of 4")
     _spread(run, "fleet of 4")

@@ -470,8 +470,8 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, layer=None):
     This is the REFERENCE formulation: it materializes two
     ``[S, max_pages * page_size, n_kv * hd]`` gathered copies per call,
     so a ragged batch pays max-length bandwidth for every slot.
-    :func:`ragged_paged_attention` is the fused kernel that walks the
-    page table in-kernel instead; this gather stays as its oracle."""
+    :func:`ragged_paged_attention`, the serving engine's decode read,
+    copies each slot's live pages instead; this gather is its oracle."""
     _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer)
     slots, n_kv, group, hd = q.shape
     # [S, T, n_kv*hd]: pages in table order ARE position order (page i
@@ -963,173 +963,284 @@ def _live_span_walk(
 
 
 def paged_page_size_hint(dtype, head_dim: int) -> int:
-    """The measured-best key-tile width for the fused paged read, from
-    the flash sweep's ``_BEST_BLOCKS``: the ragged kernel's key tile IS
-    one page (page indirection makes multi-page tiles non-contiguous in
-    the pool, so the tile cannot grow past a page), which makes
-    ``page_size`` the paged analog of ``block_k``. Pools sized with this
-    page size run the kernel at the sweep's best key tile; smaller pages
-    trade kernel efficiency for finer allocation granularity (the old
-    serving default of 16 leaned all the way toward granularity — this
-    hint is now the engine's default, clamped to ``max_seq_len``, with
-    the autotuner's ``serve.page_size`` winner overriding it)."""
+    """The flash sweep's measured-best key tile (``_BEST_BLOCKS``'
+    ``block_k``), the engine's default page size (clamped to
+    ``max_seq_len``) when neither the caller nor the autotuner's
+    ``serve.page_size`` winner names one. Larger pages mean fewer
+    copies for the decode read (:func:`ragged_paged_attention` copies
+    whole pages, as many a step as fit :data:`_DECODE_STEP_BYTES`),
+    smaller ones a finer allocation and less of a last page read past a
+    sequence's end."""
     return _static_best_blocks(dtype, head_dim, 0)[2][1]
 
 
-def _ragged_paged_kernel(
-    ptab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, page_size, scale,
-):
-    """Grid = (slots, max_pages); the page axis is innermost and
-    sequential, so the VMEM scratch carries the online-softmax state
-    (``online_block_update`` — the same recurrence the flash kernel and
-    the ring step fold with) across a slot's pages. One grid step streams
-    ONE page — a ``[page_size, n_kv * hd]`` slab, contiguous in the
-    pool's layout and (8, 128)-tileable as it lies — through the carry:
-    the page table is a scalar-prefetch input, so the BlockSpec index
-    maps chase the indirection and only this slot's OWN pages cross
-    HBM->VMEM — no [slots, max_pages * page_size] gather is ever
-    materialized. The heads are NOT a loop: the query block holds every
-    head laid over its KV head's lanes (``_heads_on_lanes``), so one
-    ``[n_heads, n_kv*hd] x [n_kv*hd, page_size]`` product scores all
-    heads against the page and one ``[n_heads, page_size] x [page_size,
-    n_kv*hd]`` product accumulates their contexts over every lane; the
-    wrapper keeps each head's own lanes.
+#: the decode read's kernel's name in a device trace
+DECODE_KERNEL = "ragged_paged_attention"
 
-    Pages at or past ``lengths[s]`` are skipped entirely (``pl.when``),
-    so a 1-token sequence in a ragged batch does one page of work while
-    its max-length neighbor does them all — compute scales with LIVE
-    tokens. (Their table entries point at the trash page, so the
-    prefetch pipeline still fetches a page-sized tile, but always the
-    same hot one.) The boundary page masks ``position >= length`` to
-    ``_NEG_BIG`` before the update, exactly like the gather oracle."""
+#: most bytes of K (and as many of V) one step of
+#: :func:`ragged_paged_attention` folds: a step takes the most pages, a
+#: power of two, that fit, so that its double-buffered K and V stay in
+#: fast memory whatever the page
+_DECODE_STEP_BYTES = 1 << 20
+
+
+def decode_read_pages(lengths, page_size: int):
+    """Pages of a slot holding ``lengths`` keys (``[S]`` or a scalar;
+    numpy on the host, traced in a program) that
+    :func:`ragged_paged_attention` copies: every page that holds a key
+    of the slot, none past its end. The same arithmetic makes the
+    kernel's schedule and the host's account
+    (:func:`decode_read_positions`)."""
+    return (lengths + (page_size - 1)) // page_size
+
+
+def decode_block_pages(page_bytes: int, rows: int) -> int:
+    """Pages one step of :func:`ragged_paged_attention` folds: the
+    largest power of two whose bytes fit :data:`_DECODE_STEP_BYTES`, no
+    more than a table's ``rows`` (gpt2-xl's pages of 16 x 1,600 float32,
+    100 KB: 8)."""
+    fit = max(1, _DECODE_STEP_BYTES // max(1, int(page_bytes)))
+    return min(1 << (fit.bit_length() - 1), max(1, int(rows)))
+
+
+def decode_read_positions(
+    lengths, rows: int, page_size: int, page_bytes: int,
+    block_pages: Optional[int] = None,
+) -> int:
+    """Key positions a layer's :func:`ragged_paged_attention` copies for
+    slots holding ``lengths`` keys (an idle slot holds one, on the trash
+    page) over tables of ``rows`` pages of ``page_bytes`` bytes, folding
+    ``block_pages`` pages a step (:func:`decode_block_pages` when not
+    given): each slot's :func:`decode_read_pages`, and, while no slot
+    holds that many pages, one page more for each page buffer that none
+    fills (its one copy, of a live page, at the walk's start)."""
+    bp = block_pages or decode_block_pages(page_bytes, rows)
+    pages = decode_read_pages(np.asarray(lengths, np.int64), page_size)
+    idle = max(0, bp - int(pages.max(initial=0)))
+    return (int(pages.sum()) + idle) * page_size
+
+
+def _decode_schedule(table, lengths, page_size: int, bp: int, steps: int):
+    """The walk of :func:`ragged_paged_attention` as scalar-prefetch data:
+    ``(slot, trip, fetch, total)``. Step ``g < total`` folds trip
+    ``trip[g]`` (pages ``trip * bp ..``) of slot ``slot[g]``, the slots
+    in order, each over ``max(1, ceil(pages / bp))`` steps; the steps
+    from ``total`` to the grid's static ``steps`` do nothing.
+    ``fetch[g * bp + i]`` is the pool page the step's ``i``-th page
+    buffer holds: the slot's page ``trip * bp + i`` where the slot has
+    it, else the next page that buffer will hold (else the last it
+    held), so that a buffer's page changes only to a page the walk
+    needs, and the pipeline, which copies a block only when its index
+    changes, copies each live page once and no page past a slot's end."""
+    slots, rows = table.shape
+    pages = decode_read_pages(lengths, page_size)
+    trips = jnp.maximum(-(-pages // bp), 1)
+    ends = jnp.cumsum(trips)
+    total = ends[-1]
+    g = jnp.arange(steps, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, g, side="right"), slots - 1)
+    trip = jnp.minimum(g - (ends[slot] - trips[slot]), trips[slot] - 1)
+    page = trip[:, None] * bp + jnp.arange(bp, dtype=jnp.int32)[None, :]
+    valid = (g[:, None] < total) & (page < pages[slot][:, None])
+    row = table[slot[:, None], jnp.minimum(page, rows - 1)]  # [G, bp]
+    at = g[:, None]
+    nxt = jax.lax.cummin(jnp.where(valid, at, steps), axis=0, reverse=True)
+    prev = jax.lax.cummax(jnp.where(valid, at, -1), axis=0)
+    src = jnp.where(nxt < steps, nxt, jnp.maximum(prev, 0))
+    fetch = jnp.take_along_axis(row, src, axis=0)
+    # a buffer no slot fills holds the walk's first page
+    fetch = jnp.where(valid.any(axis=0)[None, :], fetch, row[0, 0])
+    return (
+        slot.astype(jnp.int32), trip.astype(jnp.int32),
+        fetch.reshape(-1).astype(jnp.int32), total.astype(jnp.int32),
+    )
+
+
+def _ragged_paged_kernel(
+    meta_ref, slot_ref, trip_ref, fetch_ref, len_ref, q_ref, *refs,
+    pages, scale,
+):
+    """Grid = (steps,), in order: step ``g`` folds ``pages`` pages of
+    slot ``slot_ref[g]`` (:func:`_decode_schedule`) into the
+    online-softmax state (``online_block_update``, the flash kernels'
+    recurrence) of every head of the slot at once. The query block
+    holds every head laid over its K/V head's lanes
+    (``_heads_on_lanes``), so one ``[heads, lanes] x [pages *
+    page_size, lanes]`` product scores them all and one ``[heads, pages
+    * page_size] x [pages * page_size, lanes]`` product adds their
+    contexts over every lane (the wrapper keeps each head's own); ``m``
+    and ``l`` stay in fast memory and the output block, which is the
+    slot's until its last step, is the accumulator.
+
+    Each of the ``pages`` K (and V) inputs is one page of the pool in
+    HBM, ``pool[layer, fetch]``, which the pipeline copies while the
+    step before folds, and only where its page changes: each live page
+    once. A slot's last step masks the positions at or past its
+    ``length`` (their scores dropped, their values zeroed: a buffer the
+    slot did not fill holds a page of the slot after it)."""
     from jax.experimental import pallas as pl
 
-    si = pl.program_id(0)
-    pi = pl.program_id(1)
-    npg = pl.num_programs(1)
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_scr, l_scr = refs[2 * pages:]
+    g = pl.program_id(0)
+    span = pages * k_refs[0].shape[2]
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    @pl.when(g < meta_ref[1])
+    def _():
+        trip = trip_ref[g]
+        length = len_ref[slot_ref[g]]
+        base = trip * span
 
-    length = lens_ref[si]
-    base = pi * page_size
-    n_heads = q_ref.shape[1]
+        @pl.when(trip == 0)
+        def _():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_BIG, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
-    def update(with_mask):
-        mask = None
-        if with_mask:
-            pos = base + jax.lax.broadcasted_iota(
-                jnp.int32, (n_heads, page_size), 1
+        def fold(with_mask):
+            k, v = (
+                jnp.concatenate([r[0, 0] for r in refs], axis=0)
+                for refs in (k_refs, v_refs)
             )
-            mask = pos < length
-        m, l, acc = online_block_update(
-            q_ref[0],        # [n_heads, n_kv*hd]
-            k_ref[0, 0],     # [page_size, n_kv*hd]
-            v_ref[0, 0],
-            m_scr[...], l_scr[...], acc_scr[...], scale, mask,
-        )
-        m_scr[...] = m
-        l_scr[...] = l
-        acc_scr[...] = acc
+            mask = None
+            if with_mask:
+                pos = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (q_ref.shape[1], span), 1
+                )
+                mask = pos < length
+                real = base + jax.lax.broadcasted_iota(
+                    jnp.int32, v.shape, 0
+                ) < length
+                v = jnp.where(real, v, 0)
+            m, l, acc = online_block_update(
+                q_ref[0], k, v, m_scr[...], l_scr[...], o_ref[0], scale, mask
+            )
+            m_scr[...] = m
+            l_scr[...] = l
+            o_ref[0] = acc
 
-    # three regimes per page, mirroring the flash kernel's causal tiles:
-    # fully past the sequence (skip — the ragged win), fully visible
-    # interior (no mask work), and the boundary page (masked)
-    interior = base + page_size <= length
-    boundary = jnp.logical_and(base < length, jnp.logical_not(interior))
+        @pl.when(base + span <= length)
+        def _():
+            fold(with_mask=False)
 
-    @pl.when(interior)
-    def _():
-        update(with_mask=False)
+        @pl.when(base + span > length)
+        def _():
+            fold(with_mask=True)
+            o_ref[0] = _finalize(l_scr[...], o_ref[0])
 
-    @pl.when(boundary)
-    def _():
-        update(with_mask=True)
-
-    @pl.when(pi == npg - 1)
-    def _emit():
-        o_ref[0] = _finalize(l_scr[...], acc_scr[...]).astype(o_ref.dtype)
+        @pl.when(base + span == length)
+        def _():
+            o_ref[0] = _finalize(l_scr[...], o_ref[0])
 
 
 def ragged_paged_attention(
-    q, k_pages, v_pages, page_table, lengths, layer=None,
-    interpret: Optional[bool] = None,
+    q, k_pages, v_pages, page_table, lengths, layer=None, *,
+    block_pages: Optional[int] = None, interpret: Optional[bool] = None,
 ):
-    """Fused single-token paged-attention read: the Pallas kernel that
-    replaces :func:`paged_attention`'s gather for the serving decode step
-    (Ragged Paged Attention, PAPERS.md arXiv:2604.15464).
+    """The serving decode step's paged read: attention of one query a
+    slot over the keys its page table names, in one Pallas kernel that
+    copies each slot's live pages and nothing else (Ragged Paged
+    Attention, PAPERS.md arXiv:2604.15464).
 
-    Same contract as the gather oracle — ``q`` [S, n_kv, group, hd],
-    ``k_pages``/``v_pages`` [pool_pages, page_size, n_kv * hd] in the
-    pool's merged-lane layout (``serve/kv_pages.py``; or the whole
-    ``[n_layers, ...]`` pool with ``layer=li``, which the index maps
-    address directly), ``page_table`` [S, max_pages] int32, ``lengths``
-    [S] int32 (valid positions INCLUDING the token just written) — and
-    agrees with it to float tolerance (online softmax vs one-shot
-    softmax associativity). Returns [S, n_kv, group, hd] in ``q``'s
-    dtype.
+    Same contract as the gather oracle :func:`paged_attention` — ``q``
+    [S, n_kv, group, hd], ``k_pages``/``v_pages`` [pool_pages,
+    page_size, n_kv * hd] in the pool's merged-lane layout
+    (``serve/kv_pages.py``), or the whole ``[n_layers, ...]`` pool with
+    ``layer=li``, ``page_table`` [S, max_pages] int32, ``lengths`` [S]
+    int32 (valid positions INCLUDING the token just written) — and
+    agrees with it to float associativity (an online softmax against a
+    one-shot one). Returns [S, n_kv, group, hd] in ``q``'s dtype.
 
-    Why it wins: the gather reads ``max_pages * page_size`` positions
-    per slot regardless of the slot's real length; this kernel walks
-    each slot's page table in-kernel with scalar prefetch and stops the
-    COMPUTE at the slot's boundary page, so a ragged batch's bandwidth
-    and FLOPs scale with live tokens. The key tile is one page (see
-    :func:`paged_page_size_hint` for the measured-best width); the
-    online-softmax carry is the flash kernel's own recurrence
-    (:func:`online_block_update`), held in VMEM scratch across the
-    sequential page axis. Shapes are static, so the serving engine's
-    no-recompile property is untouched. ``interpret`` defaults to True
-    off-TPU so tests run on CPU."""
+    The gather copies ``max_pages`` pages of every slot whatever its
+    length. The kernel (:func:`_ragged_paged_kernel`) walks each slot's
+    :func:`decode_read_pages` pages, ``block_pages`` a step
+    (:func:`decode_block_pages` of the page's bytes when not given), the
+    next step's pages copied while a step folds, into an online-softmax
+    carry in fast memory: a step's bytes follow the batch's live keys
+    (:func:`decode_read_positions`). The grid has a static number of
+    steps (a table's worth of every slot); the steps past the walk copy
+    and compute nothing. A page is one block of the pool as
+    it lies (a copy of a part of a page's 1,600 lanes, not whole
+    128-lane tiles, is one the chip's copy engine does not take). The
+    products take
+    float32 operands as the ambient precision takes them (the gather
+    arm's). The table, the lengths and ``layer`` are scalar-prefetch
+    DATA to the kernel's jitted callee (:func:`_ragged_paged_read`),
+    so every layer of a program calls one function: a 48-layer decode
+    program traces and lowers it once. ``interpret`` defaults to True
+    off the TPU, so the CPU tests run the kernel itself."""
+    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer)
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    return _ragged_paged_read(
+        q, k_pages, v_pages, page_table, lengths, layer,
+        block_pages=block_pages, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
+def _ragged_paged_read(
+    q, k_pages, v_pages, page_table, lengths, layer, *, block_pages,
+    interpret,
+):
+    """:func:`ragged_paged_attention` over layer ``layer`` of the whole
+    pool ``[n_layers, pool_pages, page_size, lanes]``, jitted on its own
+    with the layer as data: every layer of a program is one callee."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    _check_paged_inputs(q, k_pages, v_pages, page_table, lengths, layer)
     slots, n_kv, group, hd = q.shape
-    if layer is None:
-        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    mp = page_table.shape[1]
-    ps, kvd = k_pages.shape[-2:]
+    rows = page_table.shape[1]
+    ps, lanes = k_pages.shape[-2:]
+    bp = block_pages or decode_block_pages(
+        ps * lanes * k_pages.dtype.itemsize, rows
+    )
+    steps = slots * -(-rows // bp)
+    slot, trip, fetch, total = _decode_schedule(
+        page_table, lengths, ps, bp, steps
+    )
     n_heads = n_kv * group
-    scale = 1.0 / float(np.sqrt(hd))
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    kernel = functools.partial(
-        _ragged_paged_kernel, page_size=ps, scale=scale
+    block = pl.BlockSpec(
+        (1, n_heads, lanes), lambda g, meta, slot, *_: (slot[g], 0, 0)
     )
-    # index maps receive the scalar-prefetch refs after the grid indices:
-    # the k/v maps dereference the page table, so the pipeline fetches
-    # exactly the pages the table names, in table (= position) order
-    q_spec = pl.BlockSpec(
-        (1, n_heads, kvd), lambda s, p, ptab, lens: (s, 0, 0)
-    )
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, kvd), lambda s, p, ptab, lens: (layer, ptab[s, p], 0, 0)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, mp),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((n_heads, 1), jnp.float32),
-            pltpu.VMEM((n_heads, 1), jnp.float32),
-            pltpu.VMEM((n_heads, kvd), jnp.float32),
-        ],
-    )
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, 1, ps, lanes),
+            lambda g, meta, slot, trip, fetch, lens: (
+                meta[0], fetch[g * bp + i], 0, 0
+            ),
+        )
+
+    pages = [page_spec(i) for i in range(bp)]
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, n_heads, kvd), q.dtype),
+        functools.partial(
+            _ragged_paged_kernel, pages=bp, scale=1.0 / float(np.sqrt(hd))
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(steps,),
+            in_specs=[block] + pages + pages,
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((n_heads, 1), jnp.float32),
+                pltpu.VMEM((n_heads, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((slots, n_heads, lanes), jnp.float32),
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-    )(page_table, lengths, _heads_on_lanes(q), k_pages, v_pages)
-    return _heads_off_lanes(out, n_kv, hd)
+        name=DECODE_KERNEL,
+    )(
+        jnp.stack([jnp.asarray(layer, jnp.int32), total]),
+        slot, trip, fetch, lengths, _heads_on_lanes(q),
+        *[k_pages] * bp, *[v_pages] * bp,
+    )
+    return _heads_off_lanes(out, n_kv, hd).astype(q.dtype)
 
 
 def _flash_kernel(

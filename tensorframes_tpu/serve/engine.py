@@ -12,10 +12,9 @@ lifetime —
   sampled from the last real position's logits;
 - **decode** ``[max_slots]``: one token per occupied slot through the
   shared per-token step (:func:`~tensorframes_tpu.models.transformer_step`)
-  with attention delegated to the paged read —
-  :func:`~tensorframes_tpu.ops.paged_attention` (gather reference) or
-  :func:`~tensorframes_tpu.ops.ragged_paged_attention` (the fused
-  Pallas kernel, ``attention_impl="fused"``);
+  with attention delegated to the paged read,
+  :func:`~tensorframes_tpu.ops.ragged_paged_attention` (one Pallas
+  kernel a layer that copies each slot's live pages);
 - **prefill-chunk** ``[1, chunk]`` (dispatched only when chunked
   prefill or a shared-prefix cache hit needs it): one mid-prompt span
   through :func:`~tensorframes_tpu.models.transformer_prefill_chunk`,
@@ -108,6 +107,7 @@ from ..obs.metrics import (
     gauge as _gauge,
     histogram as _histogram,
 )
+from ..ops.attention import decode_read_positions, ragged_paged_attention
 from ..utils import chaos as _chaos
 from ..utils.config import get_config
 from ..utils.failures import (
@@ -298,6 +298,14 @@ _m_collective_s = _counter(
 _AUTO_CHUNK_ABOVE = 2048
 _AUTO_CHUNK_TOKENS = 1024
 
+#: the decode programs' paged read of a layer and its account of what it
+#: copies: ``(read, positions)``, ``read`` called as
+#: :func:`~tensorframes_tpu.ops.ragged_paged_attention` is, and
+#: ``positions(lengths, rows, page_size, page_bytes)`` the key positions
+#: it copies a layer for a batch's ``lengths`` (idle slots at one key)
+#: over tables of ``rows`` pages. The tests put the gather oracle here.
+_DECODE_READ = (ragged_paged_attention, decode_read_positions)
+
 _engine_seq_lock = threading.Lock()
 _engine_seq = 0
 
@@ -419,16 +427,12 @@ class GenerationEngine:
     docs/serving_llm.md):
 
     - ``page_size``: KV page granularity. Default (``None``) is the
-      measured-best mapping ``ops.paged_page_size_hint`` (one page IS
-      the fused read's key tile) clamped to ``max_seq_len``, or the
+      flash sweep's best key tile ``ops.paged_page_size_hint`` clamped
+      to ``max_seq_len``, or the
       autotuner's ``serve.page_size`` winner when one is stored
       (docs/tuning.md); an explicit argument always wins, and
       ``/healthz`` reports the chosen size;
 
-    - ``attention_impl``: ``"gather"`` (reference read,
-      ``ops.paged_attention``) or ``"fused"`` (the ragged
-      paged-attention Pallas kernel — decode bandwidth scales with live
-      tokens in a ragged batch);
     - ``prefill_chunk_tokens``: > 0 prefills prompts longer than this in
       chunks of this size, one per step, interleaved with decode — a
       long prompt no longer stalls the whole batch for its full prefill;
@@ -493,7 +497,6 @@ class GenerationEngine:
         top_k: int = 0,
         eos_id: Optional[int] = None,
         moe_top_k: Optional[int] = None,
-        attention_impl: Optional[str] = None,
         prefill_chunk_tokens: Optional[int] = None,
         prefix_cache: Optional[bool] = None,
         draft_params=None,
@@ -629,14 +632,6 @@ class GenerationEngine:
         elif self._device is not None:
             kv_sharding = jax.sharding.SingleDeviceSharding(self._device)
         cfg = get_config()
-        if attention_impl is None:
-            attention_impl = cfg.serve_attention_impl
-        if attention_impl not in ("gather", "fused"):
-            raise ValueError(
-                f"attention_impl must be 'gather' or 'fused'; got "
-                f"{attention_impl!r}"
-            )
-        self.attention_impl = attention_impl
         if prefill_chunk_tokens is None:
             prefill_chunk_tokens = cfg.serve_prefill_chunk_tokens
             if prefill_chunk_tokens == 0:
@@ -844,7 +839,6 @@ class GenerationEngine:
         mmeta = dict(
             max_slots=self.max_slots, page_size=self.page_size,
             max_seq_len=self.max_seq_len, d_model=d_model,
-            attention_impl=self.attention_impl,
             tp_degree=self.tp_degree,
         )
         if mesh is not None:
@@ -1227,12 +1221,9 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops import paged_attention, ragged_paged_attention
-
         ps = self.page_size
-        d_model = self._d_model
         top_k = self.top_k
-        fused = self.attention_impl == "fused"
+        read = _DECODE_READ[0]
 
         def decode(p, kp, vp, toks, positions, ptabs, temps, seeds, top_ps):
             full = {**p, **self._static}
@@ -1247,19 +1238,11 @@ class GenerationEngine:
                     write_rows(vp, li, page, off, v),
                 )
 
-            def read(kp, vp, li, q):
-                # the materialized gather (reference) or the fused
-                # ragged kernel (bandwidth scales with live tokens)
-                impl = ragged_paged_attention if fused else paged_attention
-                return impl(q, kp, vp, ptabs, positions + 1, layer=li)
-
-            # the layer index is data to a scatter and a gather, so each
-            # body is traced once, not once per layer: 48 traced copies
-            # were 1.5 s of every engine's set-up (PERF.md §6, PR 26).
-            # The fused kernel's index maps need the layer static.
+            # the layer index is data to the scatter and to the read (a
+            # jitted callee of its own), so each is traced once, not
+            # once per layer: 48 traced copies were 1.5 s of every
+            # engine's set-up (PERF.md §6, PR 26)
             write = jax.jit(write)
-            if not fused:
-                read = jax.jit(read)
 
             def attend(li, q, k, v):
                 # write this token's k/v into its page, then read the
@@ -1267,7 +1250,10 @@ class GenerationEngine:
                 with jax.named_scope("kv_write"):
                     state[0], state[1] = write(state[0], state[1], li, k, v)
                 with jax.named_scope("kv_read"):
-                    ctx = read(state[0], state[1], li, q)
+                    ctx = read(
+                        q, state[0], state[1], ptabs, positions + 1,
+                        layer=li,
+                    )
                 return ctx.reshape(slots, -1)
 
             logits = transformer_step(
@@ -1587,8 +1573,6 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        from ..ops import paged_attention
-
         ps = self.page_size
         trash = self.pool.trash_page
         top_k = self.top_k
@@ -1638,7 +1622,7 @@ class GenerationEngine:
                     off = posn_c % ps
                     inner[0] = write_rows(inner[0], li, page, off, k)
                     inner[1] = write_rows(inner[1], li, page, off, v)
-                    read = paged_attention(
+                    read = _DECODE_READ[0](
                         q, inner[0], inner[1], ptabs, posn_c + 1,
                         layer=li,
                     )
@@ -2388,10 +2372,10 @@ class GenerationEngine:
         is in it, how long the host took since the last step program's
         wait returned — up to where this span began where that
         program's span was live too, and then how much of it the thread
-        spent off the CPU — and how many KV positions the configured
-        read touches per layer against how many are live — ``gather``
-        reads every slot's whole page table whatever the lengths,
-        ``fused`` the live pages."""
+        spent off the CPU — and how many KV positions the read copies
+        per layer against how many are live: each slot's pages up to
+        its end (``ops.attention.decode_read_positions``, the
+        arithmetic of the kernel's own schedule)."""
         attrs["requests"] = [a.req.request_id for _, a in ready]
         waited, mark = self._wait_returned_t, self._wait_returned_cpu
         chain = self._phases
@@ -2410,10 +2394,15 @@ class GenerationEngine:
         live = sum(a.length for _, a in ready)
         if self._long:  # by cache kind, and their mean over the layers
             read, live = self._live_read_attrs(attrs, ready)
-        elif self.attention_impl == "fused":
-            read = sum(len(a.seq.pages) for _, a in ready) * self.page_size
-        else:
-            read = self.max_slots * self._max_pages * self.page_size
+        else:  # the read's own account; an idle slot holds one key
+            lengths = np.ones(self.max_slots, np.int64)
+            for idx, act in ready:
+                lengths[idx] = act.length
+            pool = self.pool.k
+            read = _DECODE_READ[1](
+                lengths, self._max_pages, self.page_size,
+                self.page_size * pool.shape[-1] * pool.dtype.itemsize,
+            )
         attrs["kv_tokens_read"] = read
         attrs["kv_tokens_live"] = live
         attrs["kv_read_amplification"] = read / live

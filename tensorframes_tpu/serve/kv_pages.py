@@ -261,7 +261,7 @@ def read_pages(pages, li: int, page_table):
     """Gather layer ``li``'s pages named by ``page_table`` ``[...,
     max_pages]`` into position order: ``[..., max_pages * page_size,
     n_kv * hd]`` — :func:`~tensorframes_tpu.ops.attention.gather_pages`,
-    the one gather the decode read uses too."""
+    the one gather the decode read's oracle uses too."""
     from ..ops.attention import gather_pages
 
     return gather_pages(pages, page_table, li)
@@ -271,7 +271,8 @@ def split_heads(rows, head_dim: int):
     """``[..., n_kv * hd] -> [..., n_kv, hd]``: the per-head view of
     gathered rows, for the span programs' many-query einsums. This DOES
     re-tile the block on the TPU (lanes split 25 x 64); the decode read
-    (:func:`~tensorframes_tpu.ops.paged_attention`) never calls it."""
+    (:func:`~tensorframes_tpu.ops.ragged_paged_attention`) never calls
+    it."""
     return rows.reshape(rows.shape[:-1] + (-1, int(head_dim)))
 
 

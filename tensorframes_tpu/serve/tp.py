@@ -377,20 +377,26 @@ def tp_verify_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
 
 def tp_decode_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
     """The TP decode ``[max_slots]`` body: each shard writes its heads'
-    k/v into its pool shard, runs the paged read (gather reference or
-    the fused ragged kernel — both are head-batched, so the local walk
-    is bit-exact) over its heads only, and all-gathers the per-head
-    context. Residuals, MLP, logits, and sampling run replicated and
-    match the solo decode bit-for-bit."""
+    k/v into its pool shard, runs the engine's paged read (the ragged
+    kernel: head-batched, so a head's context does not depend on the
+    other heads' lanes) over its heads only, as many pages a step as
+    the whole pool's pages give the solo engine, and all-gathers the
+    per-head context. Residuals, MLP, logits, and sampling run
+    replicated and match the solo decode bit-for-bit."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops import paged_attention, ragged_paged_attention
+    from ..ops.attention import decode_block_pages
+    from .engine import _DECODE_READ
 
     ps = engine.page_size
     d_model = engine._d_model
     top_k = engine.top_k
-    fused = engine.attention_impl == "fused"
+    read = _DECODE_READ[0]
+    pool = engine.pool.k
+    pages = decode_block_pages(
+        ps * pool.shape[-1] * pool.dtype.itemsize, engine._max_pages
+    )
     tp = int(mesh.devices.size)
     kloc = engine.pool.n_kv_heads // tp
 
@@ -407,9 +413,9 @@ def tp_decode_impl(engine, mesh, axis: str, n_heads: int, moe_top_k: int):
             off = positions % ps
             state[0] = write_rows(state[0], li, page, off, kl)
             state[1] = write_rows(state[1], li, page, off, vl)
-            read = ragged_paged_attention if fused else paged_attention
             ctx = read(
-                ql, state[0], state[1], ptabs, positions + 1, layer=li
+                ql, state[0], state[1], ptabs, positions + 1, layer=li,
+                block_pages=pages,
             )
             ctx = jax.lax.all_gather(ctx, axis, axis=1, tiled=True)
             return ctx.reshape(slots, d_model)

@@ -89,15 +89,6 @@ class Config:
     #: doomed stream is failed within a step, so this is a last-resort
     #: backstop, not the primary failure path (docs/serving_llm.md).
     serve_result_timeout_s: float = 300.0
-    #: decode-step paged-attention implementation for the serving engine
-    #: (``serve/engine.py``): ``"gather"`` — the reference formulation
-    #: (materialized page gather + one-shot softmax, ``ops.paged_attention``)
-    #: — or ``"fused"`` — the Pallas ragged paged-attention kernel
-    #: (``ops.ragged_paged_attention``: in-kernel page-table walk,
-    #: compute scales with live tokens). Per-engine override:
-    #: ``GenerationEngine(attention_impl=...)``. The two agree to float
-    #: tolerance; gather stays the default because it is the oracle.
-    serve_attention_impl: str = "gather"
     #: chunked prefill: prompts longer than this many tokens prefill in
     #: fixed chunks of this size, one chunk per engine step, interleaved
     #: with decode steps — bounding the stall one long prompt imposes on

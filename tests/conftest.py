@@ -9,6 +9,7 @@ with explicit multi-partition RDDs
 Env vars must be set before jax initializes its backends, hence here.
 """
 
+import contextlib
 import os
 import tempfile
 
@@ -87,3 +88,30 @@ def _tune_store_in_tmp(tmp_path_factory):
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def gather_read(monkeypatch):
+    """``with gather_read():`` — the serving engines built and run inside
+    decode through the gather oracle ``ops.paged_attention`` in place of
+    the ragged kernel (the engine's private ``_DECODE_READ`` hook), and
+    count what it reads: every row of every slot's table."""
+    import jax
+
+    from tensorframes_tpu.ops import paged_attention
+    from tensorframes_tpu.serve import engine
+
+    def read(q, k_pages, v_pages, table, lengths, layer=None,
+             block_pages=None):
+        return paged_attention(q, k_pages, v_pages, table, lengths, layer)
+
+    def positions(lengths, rows, page_size, page_bytes):
+        return len(lengths) * rows * page_size
+
+    @contextlib.contextmanager
+    def use():
+        with monkeypatch.context() as m:
+            m.setattr(engine, "_DECODE_READ", (jax.jit(read), positions))
+            yield
+
+    return use

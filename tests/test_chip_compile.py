@@ -215,3 +215,102 @@ def test_one_pass_prefill_compiles_for_the_chip_at_gpt2_xls_widths(
         kernel.memory_analysis().temp_size_in_bytes
         < dense.memory_analysis().temp_size_in_bytes
     )
+
+
+def _decode_program(one_chip, n_layers, read):
+    """The decode step at gpt2-xl's widths (8 slots, a 64-row table, 25
+    heads of 64, a float32 pool of 177 pages) as the engine builds it —
+    each layer writes its token's k/v into its page, then ``read`` — and
+    compiled for the chip from shapes alone; returns the lowered and the
+    compiled program."""
+    import jax
+    import jax.numpy as jnp
+
+    from tensorframes_tpu.models.transformer import (
+        init_transformer, transformer_step,
+    )
+    from tensorframes_tpu.serve.kv_pages import write_rows
+
+    slots, rows, d, heads, pages = 8, 64, 1600, 25, 177
+    shapes = jax.eval_shape(lambda: {
+        k: v for k, v in init_transformer(
+            0, 512, d_model=d, n_heads=heads, n_layers=n_layers,
+            max_len=rows * 16,
+        ).items() if k != "n_heads"
+    })
+    of = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = jax.tree.map(lambda s: of(s.shape, s.dtype), shapes)
+    pool = of((n_layers, pages, 16, d), "float32")
+
+    def decode(p, kp, vp, toks, positions, ptabs):
+        state = [kp, vp]
+        page = ptabs[jnp.arange(slots), positions // 16]
+
+        def attend(li, q, k, v):
+            for n, x in enumerate((k, v)):
+                state[n] = write_rows(state[n], li, page, positions % 16, x)
+            ctx = read(
+                q, state[0], state[1], ptabs, positions + 1, layer=li
+            )
+            return ctx.reshape(slots, -1)
+
+        logits = transformer_step(
+            {**p, "n_heads": heads}, toks, positions, attend
+        )
+        return state[0], state[1], jnp.argmax(logits, axis=-1)
+
+    lowered = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        params, pool, pool, of((slots,), "int32"), of((slots,), "int32"),
+        of((slots, rows), "int32"),
+    )
+    return lowered, lowered.compile()
+
+
+def test_decode_read_compiles_for_the_chip_at_gpt2_xls_widths(
+    one_chip, compile_settings
+):
+    import jax
+
+    from tensorframes_tpu.ops import attention
+
+    n_layers = 4
+    lowered, kernel = _decode_program(
+        one_chip, n_layers, functools.partial(
+            attention.ragged_paged_attention, interpret=False
+        ),
+    )
+    _, gather = _decode_program(
+        one_chip, n_layers, jax.jit(attention.paged_attention)
+    )
+    smoke = _chip_smoke()
+    # one kernel call a layer, all of one lowered callee (the layer is
+    # data to it), and the compiler took the kernel's buffers
+    calls = re.findall(
+        rf"%{attention.DECODE_KERNEL}[.\d]* = .* custom-call\(",
+        kernel.as_text(),
+    )
+    assert len(calls) == n_layers
+    text = lowered.as_text()
+    callee = r"@_ragged_paged_read\b"
+    assert len(re.findall(rf"func\.func private {callee}", text)) == 1
+    assert len(re.findall(rf"call {callee}", text)) == n_layers
+    # no [slots x max_pages, page_size, lanes] copy of the tables' pages
+    # anywhere; the gather arm at the same shapes makes one a layer
+    gathered = 8 * 64 * 16 * 1600
+
+    def gathers(compiled):
+        return [
+            (op, shape) for op, shape in smoke.hlo_arrays(compiled.as_text())
+            if gathered in smoke._elements(shape)
+        ]
+
+    assert gathers(kernel) == []
+    assert gathers(gather) != []
+    assert (
+        kernel.memory_analysis().temp_size_in_bytes
+        <= gather.memory_analysis().temp_size_in_bytes
+    )
+    # nor a copy of the pool or a layer of it around the kernel
+    whole = n_layers * 177 * 16 * 1600
+    moved = smoke.pool_relayouts(kernel.as_text(), (whole, whole // 4))
+    assert moved == []

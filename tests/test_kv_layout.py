@@ -18,6 +18,7 @@ contract pinned here, on the CPU:
   chip's: ``chip_smoke.py`` phase ``pool_layout``).
 """
 
+import contextlib
 import importlib.util
 import math
 import os
@@ -261,19 +262,19 @@ class TestDecodeThroughThePool:
         )
 
     @pytest.mark.parametrize("impl", ["gather", "fused"])
-    def test_streams_equal_the_dense_cache(self, lm, impl):
+    def test_streams_equal_the_dense_cache(self, lm, impl, gather_read):
         rng = np.random.default_rng(5)
         prompts = [
             rng.integers(1, 61, size=n).astype(np.int32).tolist()
             for n in (3, 9, 17)
         ]
-        eng = GenerationEngine(
-            lm, max_slots=2, page_size=4, max_seq_len=48,
-            attention_impl=impl,
-        )
-        assert eng.pool.k.shape == (2, eng.pool.num_pages + 1, 4, 2 * 8)
-        handles = [eng.submit(p, 10) for p in prompts]
-        eng.run_until_idle()
+        with gather_read() if impl == "gather" else contextlib.nullcontext():
+            eng = GenerationEngine(
+                lm, max_slots=2, page_size=4, max_seq_len=48,
+            )
+            assert eng.pool.k.shape == (2, eng.pool.num_pages + 1, 4, 2 * 8)
+            handles = [eng.submit(p, 10) for p in prompts]
+            eng.run_until_idle()
         for p, h in zip(prompts, handles):
             solo = np.asarray(lm.generate(np.asarray([p]), 10))[0, len(p):]
             np.testing.assert_array_equal(h.result(timeout=1), solo)

@@ -16,6 +16,8 @@ Three correctness bars:
   program is the prefill chunk).
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -154,6 +156,186 @@ class TestRaggedKernelOracle:
         # for every (dtype, head_dim) bucket
         assert paged_page_size_hint(jnp.bfloat16, 128) == 1024
         assert paged_page_size_hint(jnp.float32, 64) == 1024
+
+
+class TestDecodeReadKernel:
+    """The decode read's walk of each slot's live pages, several a step,
+    against the gather oracle in float32 at ``highest``."""
+
+    def _pool(self, rng, n_layers, pages, ps, lanes):
+        def one():
+            return jnp.asarray(rng.normal(
+                size=(n_layers, pages + 1, ps, lanes)
+            ).astype(np.float32))
+        return one(), one()
+
+    def _tables(self, rng, lengths, rows, ps, trash):
+        """Distinct live pages for each slot, the rest of its row the
+        trash page (as the engine lays an idle slot's row)."""
+        need = [-(-int(n) // ps) for n in lengths]
+        free = iter(rng.permutation(trash))
+        table = np.full((len(lengths), rows), trash, np.int32)
+        for s, n in enumerate(need):
+            table[s, :n] = [next(free) for _ in range(n)]
+        return table
+
+    def _check(self, q, kp, vp, table, lengths, layer, **kw):
+        with jax.default_matmul_precision("highest"):
+            want = paged_attention(q, kp, vp, table, lengths, layer=layer)
+            got = ragged_paged_attention(
+                q, kp, vp, table, lengths, layer=layer, **kw
+            )
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
+        return np.asarray(got)
+
+    @pytest.mark.parametrize("block_pages", [None, 1, 3, 8])
+    def test_every_length_regime(self, rng, block_pages):
+        # a key, the last row of a page, a whole page, one past it, 8 and
+        # 9 pages' worth, and every row of a 64-row table but one, and all
+        ps, rows = 16, 64
+        lengths = np.asarray(
+            [1, 15, 16, 17, 128, 129, 1023, 1024], np.int32
+        )
+        kp, vp = self._pool(rng, 1, 8 * rows, ps, 2 * 8)
+        table = self._tables(rng, lengths, rows, ps, 8 * rows)
+        q = rng.normal(size=(8, 2, 1, 8)).astype(np.float32)
+        self._check(q, kp, vp, table, lengths, 0, block_pages=block_pages)
+
+    @pytest.mark.parametrize("block_pages", [None, 2])
+    def test_idle_and_full_slots_mixed(self, rng, block_pages):
+        # idle slots (one key on the trash page) between full ones
+        ps, rows, pages = 4, 8, 40
+        lengths = np.asarray([32, 1, 1, 32, 1, 13], np.int32)
+        kp, vp = self._pool(rng, 2, pages, ps, 2 * 8)
+        table = self._tables(rng, lengths, rows, ps, pages)
+        for s in (1, 2, 4):
+            table[s, 0] = pages  # an idle slot's row names the trash page
+        q = rng.normal(size=(6, 2, 2, 8)).astype(np.float32)
+        self._check(q, kp, vp, table, lengths, 1, block_pages=block_pages)
+
+    @pytest.mark.parametrize(
+        "n_kv, group, hd", [(25, 1, 64), (2, 2, 64)], ids=["gpt2-xl", "gqa2"]
+    )
+    def test_heads_on_lanes(self, rng, n_kv, group, hd):
+        # 25 heads of 64 on 1,600 lanes (gpt2-xl's row), and a GQA group
+        ps, rows, pages = 16, 8, 24
+        lengths = np.asarray([5, 128, 70], np.int32)
+        kp, vp = self._pool(rng, 1, pages, ps, n_kv * hd)
+        table = self._tables(rng, lengths, rows, ps, pages)
+        q = rng.normal(size=(3, n_kv, group, hd)).astype(np.float32)
+        self._check(q, kp, vp, table, lengths, 0)
+
+    def test_layers_of_one_pool_through_one_callee(self, rng):
+        # the layer is data: every layer of one pool is the same traced
+        # callee, under one jit as in a step program
+        from tensorframes_tpu.ops import attention
+
+        ps, rows, pages, n_layers = 4, 6, 30, 3
+        lengths = np.asarray([9, 24, 1], np.int32)
+        kp, vp = self._pool(rng, n_layers, pages, ps, 2 * 8)
+        table = self._tables(rng, lengths, rows, ps, pages)
+        q = rng.normal(size=(n_layers, 3, 2, 1, 8)).astype(np.float32)
+
+        def step(q, kp, vp):
+            return [
+                ragged_paged_attention(q[li], kp, vp, table, lengths,
+                                       layer=li)
+                for li in range(n_layers)
+            ]
+
+        text = jax.jit(step).lower(q, kp, vp).as_text()
+        assert text.count("func.func private @_ragged_paged_read") == 1
+        assert text.count("call @_ragged_paged_read") == n_layers
+        with jax.default_matmul_precision("highest"):
+            got = jax.jit(step)(q, kp, vp)
+            for li in range(n_layers):
+                want = paged_attention(
+                    q[li], kp, vp, table, lengths, layer=li
+                )
+                np.testing.assert_allclose(
+                    np.asarray(got[li]), np.asarray(want),
+                    rtol=2e-5, atol=2e-5, err_msg=f"layer {li}",
+                )
+        assert attention._ragged_paged_read._cache_size() >= 1
+
+    @pytest.mark.parametrize("fill", [np.nan, 3e38, -3e38])
+    @pytest.mark.parametrize("block_pages", [None, 3])
+    def test_pages_past_the_end_are_never_read(self, rng, fill,
+                                               block_pages):
+        # every page no slot holds a key in, the trash page and the rows
+        # of the last pages past each slot's end: the result's bits are
+        # those of a pool with ordinary numbers there
+        ps, rows, pages = 4, 8, 48
+        lengths = np.asarray([3, 17, 32, 6], np.int32)
+        kp, vp = self._pool(rng, 1, pages, ps, 2 * 8)
+        table = self._tables(rng, lengths, rows, ps, pages)
+        q = rng.normal(size=(4, 2, 1, 8)).astype(np.float32)
+        keep = np.zeros((pages + 1, ps), bool)
+        for s, n in enumerate(lengths):
+            for t in range(n):
+                keep[table[s, t // ps], t % ps] = True
+        wild = (
+            jnp.where(keep[None, :, :, None], x, fill) for x in (kp, vp)
+        )
+        with jax.default_matmul_precision("highest"):
+            clean = ragged_paged_attention(
+                q, kp, vp, table, lengths, layer=0, block_pages=block_pages
+            )
+            dirty = ragged_paged_attention(
+                q, *wild, table, lengths, layer=0, block_pages=block_pages
+            )
+        assert np.isfinite(np.asarray(dirty)).all()
+        np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+
+    @pytest.mark.parametrize(
+        "lengths, block_pages",
+        [([1, 15, 16, 17, 128, 129, 1023, 1024], 8),
+         ([1, 15, 16, 17, 128, 129, 1023, 1024], 3),
+         ([250, 1, 1, 1, 1, 1, 1, 1], 8),
+         ([5, 1, 1, 1, 1, 1, 1, 1], 8),
+         ([700, 650, 540, 580, 1, 1, 1, 1], 16)],
+        ids=["regimes-8", "regimes-3", "chat", "short", "docs-16"],
+    )
+    def test_positions_read_are_the_pages_copied(self, lengths, block_pages):
+        # the account (the span's kv_tokens_read) against the pages the
+        # schedule hands the pipeline: a buffer's page is copied where it
+        # changes, and once at the walk's start
+        from tensorframes_tpu.ops import attention
+
+        ps, rows = 16, 64
+        lengths = np.asarray(lengths, np.int32)
+        table = np.arange(8 * rows, dtype=np.int32).reshape(8, rows)
+        steps = 8 * -(-rows // block_pages)
+        slot, trip, fetch, total = attention._decode_schedule(
+            jnp.asarray(table), jnp.asarray(lengths), ps, block_pages, steps
+        )
+        fetch = np.asarray(fetch).reshape(steps, block_pages)
+        copies = 1 + (np.diff(fetch, axis=0) != 0).sum(axis=0)
+        copied = set(fetch.reshape(-1).tolist())
+        live = {
+            table[s, p] for s, n in enumerate(lengths)
+            for p in range(-(-int(n) // ps))
+        }
+        assert copied <= live  # nothing past a slot's end
+        account = attention.decode_read_positions(
+            lengths, rows, ps, 0, block_pages=block_pages
+        )
+        assert account == int(copies.sum()) * ps
+        assert int(total) == sum(
+            max(1, -(-int(-(-n // ps)) // block_pages)) for n in lengths
+        )
+
+    def test_block_pages_follow_the_pages_bytes(self):
+        from tensorframes_tpu.ops import attention
+
+        # gpt2-xl: pages of 16 x 1,600 float32, 100 KB, tables of 64
+        assert attention.decode_block_pages(16 * 1600 * 4, 64) == 8
+        assert attention.decode_block_pages(16 * 512 * 2, 1048) == 64
+        # never wider than the table, at least one page
+        assert attention.decode_block_pages(64, 12) == 12
+        assert attention.decode_block_pages(1 << 30, 64) == 1
 
 
 class TestPagedInputValidation:
@@ -307,18 +489,18 @@ class TestPrefixCacheUnit:
 
 
 class TestEngineFusedDecode:
-    """The fused kernel wired into the decode step: stream parity."""
+    """The ragged kernel wired into the decode step: stream parity."""
 
-    def test_fused_streams_match_gather_and_solo(self, lm):
+    def test_fused_streams_match_gather_and_solo(self, lm, gather_read):
         rng = np.random.default_rng(3)
         prompts = _prompts(rng, [5, 9, 3, 17])
         outs = {}
         for impl in ("gather", "fused"):
-            eng = GenerationEngine(
-                lm, max_slots=4, page_size=4, max_seq_len=48,
-                attention_impl=impl,
-            )
-            outs[impl] = eng.generate(prompts, 8)
+            with gather_read() if impl == "gather" else contextlib.nullcontext():
+                eng = GenerationEngine(
+                    lm, max_slots=4, page_size=4, max_seq_len=48,
+                )
+                outs[impl] = eng.generate(prompts, 8)
             assert eng.num_step_programs <= 2
         for p, g, f in zip(prompts, outs["gather"], outs["fused"]):
             solo = _solo(lm, p, 8)
@@ -330,24 +512,23 @@ class TestEngineFusedDecode:
         prompts = _prompts(rng, [6, 11])
         eng = GenerationEngine(
             lm_gqa, max_slots=2, page_size=4, max_seq_len=48,
-            attention_impl="fused",
         )
         for p, o in zip(prompts, eng.generate(prompts, 8)):
             assert np.array_equal(o, _solo(lm_gqa, p, 8))
 
-    def test_bad_impl_rejected(self, lm):
-        with pytest.raises(ValueError, match="gather.*fused"):
-            GenerationEngine(lm, attention_impl="magic")
+    def test_a_slot_walks_several_steps(self, lm, monkeypatch):
+        # a fast-memory budget of three pages: two pages a step, so a
+        # 37-token slot's ten pages take five steps and an idle slot one
+        from tensorframes_tpu.ops import attention
 
-    def test_config_default_applies(self, lm):
-        old = get_config().serve_attention_impl
-        set_config(serve_attention_impl="fused")
-        try:
-            eng = GenerationEngine(lm, max_slots=2, page_size=4,
-                                   max_seq_len=48)
-            assert eng.attention_impl == "fused"
-        finally:
-            set_config(serve_attention_impl=old)
+        eng = GenerationEngine(lm, max_slots=3, page_size=4, max_seq_len=48)
+        page = 4 * eng.pool.k.shape[-1] * 4
+        monkeypatch.setattr(attention, "_DECODE_STEP_BYTES", 3 * page)
+        assert attention.decode_block_pages(page, eng._max_pages) == 2
+        rng = np.random.default_rng(8)
+        prompts = _prompts(rng, [37, 2])
+        for p, o in zip(prompts, eng.generate(prompts, 6)):
+            assert np.array_equal(o, _solo(lm, p, 6))
 
 
 class TestChunkedPrefill:

@@ -317,18 +317,32 @@ class TestFusedUnderTP:
     def test_fused_read_matches_solo(self, lm):
         """The ragged paged-attention kernel (interpret mode on CPU) is
         head-batched, so its local-head walk shards like the gather:
-        streams match the solo FUSED engine byte-for-byte."""
+        streams match the solo engine byte-for-byte."""
         prompts = _prompts(7, (11, 19))
         solo = GenerationEngine(
             lm, max_slots=2, page_size=8, max_seq_len=64,
-            attention_impl="fused",
         )
         base = solo.generate(prompts, 8)
         eng = GenerationEngine(
-            lm, max_slots=2, page_size=8, max_seq_len=64,
-            attention_impl="fused", mesh=_mesh(2),
+            lm, max_slots=2, page_size=8, max_seq_len=64, mesh=_mesh(2),
         )
         for a, b in zip(base, eng.generate(prompts, 8)):
+            np.testing.assert_array_equal(a, b)
+
+    def test_gather_oracle_under_tp_matches_solo(self, lm, gather_read):
+        """The gather oracle through the engine's hook shards the same
+        way: the tp=2 engine's streams are the solo kernel engine's."""
+        prompts = _prompts(9, (5, 23))
+        base = GenerationEngine(
+            lm, max_slots=2, page_size=8, max_seq_len=64,
+        ).generate(prompts, 8)
+        with gather_read():
+            eng = GenerationEngine(
+                lm, max_slots=2, page_size=8, max_seq_len=64,
+                mesh=_mesh(2),
+            )
+            got = eng.generate(prompts, 8)
+        for a, b in zip(base, got):
             np.testing.assert_array_equal(a, b)
 
 

@@ -128,10 +128,11 @@ def test_step_loop_emits_leaf_phase_spans_in_order(lm, sink):
         assert abs(b["t_mono"] - (a["t_mono"] + a["dur_s"])) < 5e-5, (a, b)
 
 
-def test_phase_span_attrs(lm, sink):
-    eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
-    h = eng.submit([1, 2, 3, 4, 5], 4)
-    eng.run_until_idle()
+def test_phase_span_attrs(lm, sink, gather_read):
+    with gather_read():  # the gather oracle's account of its read
+        eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
+        h = eng.submit([1, 2, 3, 4, 5], 4)
+        eng.run_until_idle()
     by = {}
     for e in sink.events():
         by.setdefault(e["name"], []).append(e)
@@ -163,6 +164,35 @@ def test_phase_span_attrs(lm, sink):
     (last,) = by["serve.deliver"]
     assert last["attrs"] == {"tokens": 1, "finished": 1}
     assert list(h.result(timeout=5)) == list(h)
+
+
+def test_decode_step_counts_the_pages_the_kernel_copies(lm, sink):
+    # the ragged kernel copies a slot's pages up to its end and an idle
+    # slot's one (the trash page): 6 to 8 keys in two pages of 4 beside
+    # one idle page. Its step here is the whole 8-row table (the pages
+    # are small), and the six page buffers no slot fills are copied
+    # once each at the walk's start: (2 + 1 + 6) pages of 4
+    from tensorframes_tpu.ops.attention import (
+        decode_block_pages,
+        decode_read_positions,
+    )
+
+    eng = GenerationEngine(lm, max_slots=2, page_size=4, max_seq_len=32)
+    eng.submit([1, 2, 3, 4, 5], 4)
+    eng.run_until_idle()
+    steps = [
+        e["attrs"] for e in sink.events() if e["name"] == "serve.decode_step"
+    ]
+    page = 4 * eng.pool.k.shape[-1] * eng.pool.k.dtype.itemsize
+    assert decode_block_pages(page, eng._max_pages) == 8
+    assert [a["kv_tokens_live"] for a in steps] == [6, 7, 8]
+    assert [a["kv_tokens_read"] for a in steps] == [36, 36, 36]
+    assert steps[0]["kv_read_amplification"] == pytest.approx(36 / 6)
+    # one function makes the kernel's schedule's count and the span's
+    assert decode_read_positions([6, 1], eng._max_pages, 4, page) == 36
+    assert decode_read_positions(
+        [6, 1], eng._max_pages, 4, page, block_pages=2
+    ) == 12
 
 
 def test_host_gap_lies_inside_the_wall_between_two_decode_steps(lm, sink):
